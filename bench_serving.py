@@ -435,6 +435,10 @@ def main(argv=None) -> int:
 
     import jax
 
+    from distributed_ml_pytorch_tpu.runtime import startup
+
+    startup.enable_compile_cache()
+    log(f"bench_serving: {startup.device_summary()}")
     r = (run_fleet(args) if args.engines >= 2 or args.autoscale
          else run_single(args))
     wall, total = r["wall"], r["total_tokens"]

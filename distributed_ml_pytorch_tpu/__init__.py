@@ -19,81 +19,6 @@ parameter-server trainer; see ``SURVEY.md``), re-designed TPU-first:
 Public API re-exports the contractual symbols recovered in SURVEY.md §2.3.
 """
 
-import jax as _jax
-
-#: True when this runtime predates the graduated jax.shard_map (and its
-#: varying-manual-axes type system). Code whose GRADIENTS depend on
-#: transpose-time psum insertion for replicated operands (parallel/pipeline)
-#: consults this to pin check_rep=False and insert those psums explicitly —
-#: the old checker's false positives otherwise make strict-vs-loose (and so
-#: the gradient math) depend on which body happens to trace.
-LEGACY_SHARD_MAP = not hasattr(_jax, "shard_map")
-
-if LEGACY_SHARD_MAP:
-    # jax-version compatibility: shard_map graduated out of jax.experimental
-    # after this runtime's jax; the framework is written against the new
-    # spelling, so install it where older runtimes lack it (keyword surface
-    # — mesh/in_specs/out_specs — is identical). check_rep stays ON by
-    # default — it drives the transpose-time psum insertion that makes
-    # gradients of replicated operands correct (round 3; DESIGN.md §4) —
-    # but the experimental checker has false positives the graduated one
-    # fixed (e.g. it cannot prove an optax update of psum-med grads is
-    # replicated), so a callable whose TRACE fails the replication check is
-    # rebuilt once with check_rep=False and remembered.
-    import functools
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    @functools.wraps(_shard_map)
-    def _shard_map_compat(f, **kwargs):
-        if "check_rep" in kwargs or "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma", kwargs.get("check_rep"))
-            return _shard_map(f, **kwargs)
-        strict = _shard_map(f, **kwargs, check_rep=True)
-        mode = {}
-
-        def loose():
-            # built once and cached: a fresh function object per call
-            # would miss jax's trace caches (keyed on identity) and
-            # retrace eager callers every iteration
-            if "loose" not in mode:
-                import warnings
-
-                warnings.warn(
-                    "shard_map compat: replication check disabled for "
-                    f"{getattr(f, '__name__', f)!r} after the old checker "
-                    "rejected it — if this body relies on transpose-time "
-                    "psum insertion for replicated operands, verify its "
-                    "gradients against an unsharded reference",
-                    stacklevel=3,
-                )
-                mode["loose"] = _shard_map(f, **kwargs, check_rep=False)
-            return mode["loose"]
-
-        def dispatch(*args, **kw):
-            if "loose" in mode:
-                return loose()(*args, **kw)
-            try:
-                return strict(*args, **kw)
-            except (ValueError, NotImplementedError) as e:
-                # checker false positives only: unprovable replication
-                # (ValueError) or a primitive with no replication rule,
-                # e.g. pallas_call (NotImplementedError) — anything else
-                # is a real error and propagates
-                if "replicat" not in str(e):
-                    raise
-                return loose()(*args, **kw)
-
-        return dispatch
-
-    _jax.shard_map = _shard_map_compat
-
-if not hasattr(_jax.lax, "pcast"):
-    # pcast/pvary markers belong to the varying-manual-axes type system of
-    # newer jax; the experimental shard_map used above has no vma tracking
-    # (check_rep defaults off), so the marker is correctly an identity here
-    _jax.lax.pcast = lambda x, *args, **kwargs: x
-
 from distributed_ml_pytorch_tpu.version import __version__
 from distributed_ml_pytorch_tpu.utils.serialization import (
     ravel_model_params,

@@ -125,11 +125,7 @@ def _head_update_pallas(W, h2, logits, lse, labels, gscale, alpha):
                                memory_space=pltpu.VMEM),
         scratch_shapes=[pltpu.VMEM((d, BLOCK_V), jnp.float32)],
         input_output_aliases={1: 0},  # update W in place when donated
-        # jax-version compatibility: the params class was renamed from
-        # TPUCompilerParams to CompilerParams after this runtime's jax
-        compiler_params=getattr(
-            pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-        )(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=_interpret(),

@@ -21,9 +21,7 @@ how to lease, place, kill-detect and restart:
 - :class:`MpmdLocal` — the same numerics loopback in one thread (no
   transports): the exactness oracle. Because every stage compiles
   standalone, its gradients are the plain-AD gradients of the reference
-  model — this is the step that burned down the legacy shard_map
-  pipeline-gradient xfails in ``tests/test_pipeline.py`` (the old runtime's
-  transpose semantics never enter the program).
+  model (``tests/test_pipeline.py`` holds the shard_map schedules to it).
 - :class:`MpmdStage` — one stage as a fleet member: a serve loop over a
   :class:`~.messaging.Transport` (so ReliableTransport / chaos / weather
   wrap it unchanged), a ``CoordClient`` lease, per-``(step, microbatch)``

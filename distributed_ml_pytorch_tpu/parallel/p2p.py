@@ -87,7 +87,9 @@ def run_demo(n_devices: int = 2) -> np.ndarray:
 
 
 if __name__ == "__main__":
-    from distributed_ml_pytorch_tpu.runtime.mesh import ensure_min_devices
+    from distributed_ml_pytorch_tpu.runtime.mesh import require_devices
+    from distributed_ml_pytorch_tpu.runtime.startup import announce_devices
 
-    ensure_min_devices(2)  # virtual CPU devices when the host has one chip
+    announce_devices("p2p demo")
+    require_devices(2, "the p2p demo")
     run_demo(2)

@@ -217,73 +217,29 @@ def _wrap_pp_step(grad_fn, tx, mesh, stage_axis, data_axis=None,
     data-invariant) and are divided by the data-axis size into the mean —
     do NOT replace the divide with a pmean (identity on the summed tree;
     measured to leave grads exactly 2x at dp=2). Params stay
-    ``P(stage, ...)`` (replicated over data).
-
-    Legacy-runtime note (``LEGACY_SHARD_MAP``): the auto-psum above is
-    transpose-time insertion, which the OLD shard_map performs only under
-    ``check_rep=True`` — and its checker false-positives on the composite
-    bodies, so the compat shim silently falls back to ``check_rep=False``
-    for SOME pipeline steps and not others, making the gradient math depend
-    on which body happens to trace (measured: dp×pp grads came out
-    per-row, never reduced over data). On legacy runtimes every pipeline
-    step therefore PINS ``check_rep=False`` and inserts the reductions
-    EXPLICITLY — one psum per mesh axis a grad leaf's spec does not
-    mention, the set the transpose rule reduces over — so all pipeline
-    configurations share ONE gradient semantics, and the dp×pp composites
-    are exactly consistent with pure pp
-    (tests/test_pipeline.py::test_dp_pp_composite_matches_pure_pp). Two
-    residues remain on legacy runtimes, both pre-existing at the growth
-    seed and xfail-tracked in the tests: pipeline grads deviate slightly
-    from the SINGLE-STAGE reference (the old transpose machinery, strict
-    or loose, is not the graduated vma semantics), and the model_axis
-    (Megatron) composites deviate per layer. Losses are exact everywhere —
-    ``__graft_entry__.dryrun_multichip`` asserts them."""
-    from distributed_ml_pytorch_tpu import LEGACY_SHARD_MAP
-
-    axis_names = tuple(mesh.shape.keys())
-
-    def _unmentioned(spec: P):
-        named = set()
-        for part in spec:
-            if part is None:
-                continue
-            named |= set(part) if isinstance(part, (tuple, list)) else {part}
-        return tuple(a for a in axis_names if a not in named)
+    ``P(stage, ...)`` (replicated over data)."""
 
     def step(state: TrainState, tokens_mb, targets_mb):
         param_specs = pp_param_specs(state.params, stage_axis, model_axis)
 
         def fn(params, t, y):
             loss, grads = grad_fn(params, t, y)
-            if LEGACY_SHARD_MAP:
-                grads = jax.tree.map(
-                    lambda s, g: (
-                        jax.lax.psum(g, _unmentioned(s))
-                        if _unmentioned(s) else g
-                    ),
-                    param_specs, grads,
-                    is_leaf=lambda x: isinstance(x, P),
-                )
             if data_axis is not None:
-                # on modern runtimes params enter data-INVARIANT, so AD has
-                # already psum'd their cotangents over the data axis (a
-                # pmean here would be an identity on the summed tree —
-                # measured to leave grads exactly 2x at dp=2); on legacy the
-                # explicit psums above produce the same summed tree. Divide
-                # into the mean either way.
+                # params enter data-INVARIANT, so AD has already psum'd
+                # their cotangents over the data axis (a pmean here would be
+                # an identity on the summed tree — measured to leave grads
+                # exactly 2x at dp=2). Divide into the mean.
                 grads = jax.tree.map(
                     lambda g: g / int(mesh.shape[data_axis]), grads)
                 loss = jax.lax.pmean(loss, data_axis)
             return loss, grads
 
         batch_spec = P(None, data_axis) if data_axis is not None else P()
-        sm_kwargs = {"check_rep": False} if LEGACY_SHARD_MAP else {}
         loss, grads = jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=(param_specs, batch_spec, batch_spec),
             out_specs=(P(), param_specs),
-            **sm_kwargs,
         )(state.params, tokens_mb, targets_mb)
         updates, opt_state = tx.update(grads, state.opt_state, state.params)
         params = optax.apply_updates(state.params, updates)

@@ -202,6 +202,18 @@ def train_data_parallel(
         args, state, len(x_train) // per_proc_batch
     )
     state, sharded_step, scan_fn, suffix = strategy(model, tx, mesh, state)
+    # say where the arrays live: a multi-chip run is only that if the batch
+    # really is split over distinct devices (the batch's is the sharding
+    # shard_batch gives every step's input)
+    leaf = jax.tree.leaves(state.params)[0]
+    batch = NamedSharding(mesh, P("data"))
+    shape = (global_batch,) + x_train.shape[1:]
+    ids = lambda sharding: sorted(d.id for d in sharding.device_set)
+    print("{}: {} devices {}; params {} on {}; batch {} {} split as {} on {}"
+          .format(label, mesh.devices.flat[0].platform,
+                  [d.id for d in mesh.devices.flat], leaf.sharding.spec,
+                  ids(leaf.sharding), shape, batch.spec,
+                  batch.shard_shape(shape), ids(batch)))
     eval_step = make_eval_fn(model)
     logger = MetricsLogger(getattr(args, "log_dir", "log"))
 

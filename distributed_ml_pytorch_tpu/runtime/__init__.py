@@ -2,7 +2,7 @@ from distributed_ml_pytorch_tpu.runtime.mesh import (
     initialize_distributed,
     data_mesh,
     make_mesh,
-    simulate_cpu_devices,
+    force_cpu_devices,
     local_device_count,
     process_rank,
     world_size,
@@ -12,7 +12,7 @@ __all__ = [
     "initialize_distributed",
     "data_mesh",
     "make_mesh",
-    "simulate_cpu_devices",
+    "force_cpu_devices",
     "local_device_count",
     "process_rank",
     "world_size",

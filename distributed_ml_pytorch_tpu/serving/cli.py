@@ -388,6 +388,9 @@ def main(argv=None) -> int:
     try:
         return _main(args, parser)
     finally:
+        from distributed_ml_pytorch_tpu.runtime import startup
+
+        startup.report_compile_cache("serving engine")
         # observability plane (ISSUE 12): one registry snapshot at exit
         if getattr(args, "metrics_dump", ""):
             from distributed_ml_pytorch_tpu.coord.cli import dump_metrics
@@ -397,6 +400,10 @@ def main(argv=None) -> int:
 
 def _main(args, parser) -> int:
     print(args)
+    from distributed_ml_pytorch_tpu.runtime import startup
+
+    startup.enable_compile_cache()
+    startup.announce_devices("serving engine")
     if args.fleet:
         return _main_fleet(args, parser)
     engine = _build_engine(args, parser)

@@ -271,6 +271,9 @@ class ServingFrontend:
         self._routes_lock = threading.Lock()
         self._route_ids = itertools.count(1 << 32)
         self.reaped = 0  # requests cancelled for client silence
+        #: client rank -> when its last frame (of any kind) arrived; written
+        #: by the pump thread, read by the sweep
+        self._client_seen: Dict[int, float] = {}
         self._stop = threading.Event()
         self._listener = threading.Thread(target=self._pump, daemon=True)
         self._listener.start()
@@ -327,6 +330,12 @@ class ServingFrontend:
     def _handle(self, sender: int, code: MessageCode,
                 payload: np.ndarray) -> None:
         now = time.monotonic()
+        # any frame from a client is proof of life for ALL its requests: a
+        # client reading one stream acks only that one, and with several
+        # submitted up front the rest must not be reaped as abandoned while
+        # it gets to them (at real model widths a cold compile alone
+        # outlasts client_deadline)
+        self._client_seen[sender] = now
         if code in (MessageCode.SubmitRequest, MessageCode.SubmitRequestV2):
             self._on_submit(sender, code, payload, now, arrived=now)
         elif code == MessageCode.CancelRequest and payload.size >= 1:
@@ -335,7 +344,6 @@ class ServingFrontend:
                 key = self._by_client.get((sender, rid))
                 route = self._routes.get(key) if key is not None else None
             if route is not None:
-                route.last_active = now
                 self._cancel_route(key, route)
         elif code in (MessageCode.StreamAck, MessageCode.ResumeStream) \
                 and payload.size >= 2:
@@ -355,7 +363,6 @@ class ServingFrontend:
                     self._send_to(sender, MessageCode.ServeReject,
                                   np.asarray([rid], np.float32))
                 return
-            route.last_active = now
             if code == MessageCode.ResumeStream:
                 # snapshot under the lock: the engine thread (or a fleet
                 # migration) may be appending concurrently
@@ -603,8 +610,9 @@ class ServingFrontend:
             if route.done:
                 if now - route.done_at > self.done_ttl:
                     self._drop_route(key)
-            elif not route.reaping and (
-                    now - route.last_active > self.client_deadline):
+            elif not route.reaping and now - max(
+                    route.last_active, self._client_seen.get(route.rank, 0.0)
+            ) > self.client_deadline:
                 route.reaping = True  # count + cancel once per request
                 self.reaped += 1
                 self._cancel_route(key, route)  # eviction frees the slot/

@@ -5,8 +5,9 @@ adds the TPU-era flags (``--backend``, ``--model``, ``--mode``, data options).
 Flag-mapping notes:
 
 - ``--cuda`` (reference: move model to GPU) → alias for ``--backend=tpu``:
-  "put compute on the accelerator". On this hardware that is the TPU chip,
-  and it is also the default, so the flag is accepted for script parity.
+  "put compute on the accelerator". Asking for the chip and not getting it
+  (no TPU, or ``JAX_PLATFORMS=cpu`` inherited from the shell) is an error,
+  never a CPU run under a TPU name.
 - ``--rank``/``--world-size``/``--master``/``--port`` configure either the
   async-PS control plane (TCP star, ``utils/messaging.py``) or multi-host
   JAX (``runtime/mesh.py``), replacing MASTER_ADDR/MASTER_PORT + gloo
@@ -67,7 +68,9 @@ def build_parser() -> argparse.ArgumentParser:
                    help="port on master node to communicate with")
     # --- TPU-era extensions ---
     p.add_argument("--backend", type=str, default="auto", choices=["auto", "tpu", "cpu"],
-                   help="compute backend (auto = jax default platform)")
+                   help="compute backend: auto = jax default platform; tpu = "
+                        "fail unless the run is on a TPU; cpu = force the "
+                        "CPU platform")
     p.add_argument("--mode", type=str, default="ps",
                    choices=["ps", "sync", "local-sgd", "fsdp"],
                    help="distributed strategy: async parameter server (reference core), "
@@ -265,13 +268,25 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def _apply_backend(args) -> None:
+    """Act on ``--backend`` before the first computation, turn on the compile
+    cache, and say where the run is."""
+    from distributed_ml_pytorch_tpu.runtime import startup
+
     if args.cuda and args.backend == "auto":
         args.backend = "tpu"
     if args.backend == "cpu":
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
         from distributed_ml_pytorch_tpu.runtime.mesh import force_cpu_devices
 
         force_cpu_devices(int(os.environ.get("DMT_CPU_DEVICES", "1")))
+    startup.enable_compile_cache()
+    if args.backend == "tpu":
+        startup.require_tpu("--backend tpu")
+    if args.mode == "ps" and not args.no_distributed:
+        role = "ps server" if args.server or args.rank == 0 else "ps worker"
+        args.role = f"{role} rank {args.rank}"
+    else:
+        args.role = "trainer"
+    startup.announce_devices(args.role)
 
 
 def main(argv=None) -> int:
@@ -279,6 +294,10 @@ def main(argv=None) -> int:
     try:
         return _main(args)
     finally:
+        if hasattr(args, "role"):  # start-up got as far as naming the run
+            from distributed_ml_pytorch_tpu.runtime import startup
+
+            startup.report_compile_cache(args.role)
         # observability plane (ISSUE 12): whatever the run registered or
         # attached (reliable-transport counters via make_transport, any
         # component providers) is dumped in one JSON snapshot
@@ -290,7 +309,11 @@ def main(argv=None) -> int:
 
 def _main(args) -> int:
     print(args)
-    _apply_backend(args)
+    try:
+        _apply_backend(args)
+    except RuntimeError as e:
+        print(f"error: {e}", file=sys.stderr)
+        return 2
 
     import jax
 

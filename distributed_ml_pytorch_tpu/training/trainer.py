@@ -319,8 +319,7 @@ def make_scan_train_step(model, tx: optax.GradientTransformation) -> Callable:
     processes K *distinct* microbatches with exactly the same per-step update
     (and dropout stream) as :func:`make_train_step` dispatched K times — the
     equivalence is tested — but pays the host→device round-trip once per K
-    steps instead of per step. On a tunneled/latency-bound device this is an
-    order of magnitude in throughput; there is no reference counterpart
+    steps instead of per step. There is no reference counterpart
     (its hot loop is Python per step, ``example/main.py:59-91``).
     """
 

@@ -48,10 +48,20 @@ PEAK_FLOPS: dict[str, dict[str, float]] = {
 
 def device_peak_flops(device=None, dtype: str = "bf16") -> Optional[float]:
     """Peak FLOP/s for ``device`` (default: first visible device) at
-    ``dtype``, or None when the device kind / dtype has no table entry
-    (CPU hosts, unknown generations)."""
+    ``dtype``. ``None`` only for a host CPU, which has no MXU peak to
+    utilise; a TPU whose ``device_kind`` or dtype the table does not hold
+    raises — a missing row must not make MFU quietly disappear."""
     device = device if device is not None else jax.devices()[0]
-    return PEAK_FLOPS.get(device.device_kind, {}).get(dtype)
+    if device.platform == "cpu":
+        return None
+    try:
+        return PEAK_FLOPS[device.device_kind][dtype]
+    except KeyError:
+        raise KeyError(
+            f"no {dtype} peak FLOP/s for device_kind {device.device_kind!r} "
+            f"in utils/flops.PEAK_FLOPS (known: {sorted(PEAK_FLOPS)}); add "
+            "the row, with its source, before reporting utilisation"
+        ) from None
 
 
 def compiled_flops(jitted, *args, **kwargs) -> Optional[float]:
@@ -74,10 +84,6 @@ def compiled_flops(jitted, *args, **kwargs) -> Optional[float]:
         return None
     if not analysis:
         return None
-    if isinstance(analysis, (list, tuple)):
-        # jax-version compatibility: older runtimes return one dict per
-        # computation instead of a flat dict
-        analysis = analysis[0] if analysis and analysis[0] else {}
     flops = analysis.get("flops")
     return float(flops) if flops and flops > 0 else None
 

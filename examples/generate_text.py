@@ -88,6 +88,10 @@ def main(argv=None) -> int:
 
     from distributed_ml_pytorch_tpu.models import TransformerLM
     from distributed_ml_pytorch_tpu.models.generate import generate, generate_tp
+    from distributed_ml_pytorch_tpu.runtime import startup
+
+    startup.enable_compile_cache()
+    startup.announce_devices("generate_text")
 
     total = args.prompt_len + args.new_tokens
     lm = TransformerLM(
@@ -174,10 +178,11 @@ def main(argv=None) -> int:
 
     n_generated = args.batch * args.new_tokens
     print(f"decode ({mode}): {n_generated} tokens in {dt:.2f}s "
-          f"(compile included) on {jax.devices()[0].platform}")
+          f"(compile included)")
     for b in range(args.batch):
         print(f"[{b}] prompt : {' '.join(map(str, out[b, :args.prompt_len]))}")
         print(f"[{b}] sampled: {' '.join(map(str, out[b, args.prompt_len:]))}")
+    startup.report_compile_cache("generate_text")
     return 0
 
 

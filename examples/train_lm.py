@@ -21,8 +21,8 @@ via ``--ckpt-dir``/``--ckpt-every``/``--resume`` (orbax, sharding-aware:
 states restore directly into the mode's device layout).
 
 On one host, meshes come up on whatever devices exist (use
-``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8``
-for the virtual-mesh simulation); on a pod, run under
+``JAX_PLATFORMS=cpu JAX_NUM_CPU_DEVICES=8`` for the virtual-mesh
+simulation); on a pod, run under
 ``runtime.initialize_distributed`` and the same code scales.
 """
 
@@ -123,6 +123,15 @@ def _make_chunked_step(step):
 
 
 def main(argv=None) -> int:
+    run(argv)
+    return 0
+
+
+def run(argv=None) -> dict:
+    """Parse ``argv``, train, and return what a caller may want to inspect:
+    ``losses`` (one per dispatch), the jitted ``step`` with the final
+    ``state`` and the ``batch`` it ran on (so the compiled program can be
+    looked at), and ``setup_s`` — the first dispatch, compilation included."""
     parser = build_parser()
     args = parser.parse_args(argv)
     if args.steps < 1:
@@ -148,6 +157,10 @@ def main(argv=None) -> int:
         create_lm_train_state,
         next_token_targets,
     )
+    from distributed_ml_pytorch_tpu.runtime import startup
+
+    startup.enable_compile_cache()
+    startup.announce_devices("train_lm")
 
     lm = TransformerLM(
         vocab_size=args.vocab, d_model=args.d_model, n_heads=args.n_heads,
@@ -388,25 +401,31 @@ def main(argv=None) -> int:
     )
     n_disp = args.steps // k
     t0 = time.perf_counter()
-    loss = None
+    setup_s = 0.0
+    losses = []
     for i in range(n_disp):
         state, loss = step(state, *batch)
+        losses.append(loss)
         if i == 0:
             jax.block_until_ready(loss)
+            setup_s = time.perf_counter() - t0
+            print(f"  first dispatch (compilation included) {setup_s:.1f}s")
             t0 = time.perf_counter()  # exclude compile from the rate
         if ckpt is not None:
             ckpt.save(start_step + (i + 1) * k, state)
         if i % max(1, n_disp // 5) == 0:
             print(f"  step {i * k:4d}  loss {_scalar_loss(loss):.4f}")
-    final = _scalar_loss(loss)
+    losses = [_scalar_loss(l) for l in losses]
     dt = time.perf_counter() - t0
     rate = (n_disp - 1) * k * args.batch * args.seq / dt if n_disp > 1 else 0.0
-    print(f"final loss {final:.4f}; ~{rate:.0f} tokens/s "
+    print(f"final loss {losses[-1]:.4f}; ~{rate:.0f} tokens/s "
           f"(naive wall-clock, see bench_all.py for the differenced method)")
     if ckpt is not None:
         ckpt.save(start_step + args.steps, state, force=True)
         ckpt.close()
-    return 0
+    startup.report_compile_cache("train_lm")
+    return {"losses": losses, "setup_s": setup_s, "step": step,
+            "state": state, "batch": batch}
 
 
 if __name__ == "__main__":

@@ -671,6 +671,48 @@ def test_serving_silent_client_is_reaped_and_state_freed(lm_and_params):
             t.close()
 
 
+def test_serving_client_reading_one_stream_keeps_its_others_alive(
+        lm_and_params):
+    """Liveness belongs to the client, not to each request: a client that
+    submitted two requests and is acking only the first (it reads streams
+    one after the other, as ``serving.cli --demo`` does) must not have the
+    second reaped as abandoned."""
+    from distributed_ml_pytorch_tpu.serving.engine import ServingEngine
+    from distributed_ml_pytorch_tpu.serving.frontend import (
+        ServingFrontend,
+        encode_submit,
+    )
+    from distributed_ml_pytorch_tpu.utils.messaging import (
+        MessageCode as MC,
+    )
+
+    model, params = lm_and_params
+    engine = ServingEngine(model, params, slots=2, cache_size=64,
+                           decode_block=4, prefill_bucket=8)
+    world = InProcessTransport.create_world(2)
+    frontend = ServingFrontend(engine, world[0], client_deadline=0.3)
+    try:
+        for rid in (1, 2):
+            world[1].send(MC.SubmitRequest,
+                          encode_submit(rid, np.arange(4), 40), dst=0)
+        deadline = time.monotonic() + 5
+        while len(frontend._routes) < 2 and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert len(frontend._routes) == 2
+        time.sleep(0.2)
+        world[1].send(MC.StreamAck, np.asarray([1, 0], np.float32), dst=0)
+        time.sleep(0.2)  # request 2 itself: 0.4s of silence > the deadline
+        frontend._sweep(time.monotonic())
+        assert frontend.reaped == 0
+        time.sleep(0.4)  # now the CLIENT is silent past the deadline
+        frontend._sweep(time.monotonic())
+        assert frontend.reaped == 2
+    finally:
+        frontend.stop()
+        for t in world.values():
+            t.close()
+
+
 def test_serving_reconnect_and_resume_by_request_id(lm_and_params):
     """A client that consumed part of a stream and went away (reconnect)
     reattaches by request id and receives exactly the remainder."""

@@ -10,9 +10,24 @@ import pytest
 from distributed_ml_pytorch_tpu.utils.flops import (
     check_flops_agreement,
     compiled_flops,
+    device_peak_flops,
     flash_attention_train_flops,
     lm_train_flops_6nd,
 )
+
+
+def test_unknown_tpu_kind_is_an_error_not_a_missing_mfu():
+    """A TPU the peak table does not hold must raise (MFU used to vanish
+    quietly); only a host CPU has no peak."""
+    from types import SimpleNamespace
+
+    v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert device_peak_flops(v5e) == 197e12
+    assert device_peak_flops(jax.devices()[0]) is None  # the CPU test mesh
+    with pytest.raises(KeyError, match="TPU v99"):
+        device_peak_flops(SimpleNamespace(platform="tpu", device_kind="TPU v99"))
+    with pytest.raises(KeyError, match="fp8"):
+        device_peak_flops(v5e, dtype="fp8")
 
 
 def test_flash_flops_fused_vs_split_ratio():

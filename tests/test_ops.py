@@ -1,9 +1,8 @@
 """Kernel tests: Pallas flat-axpy + flash attention vs. naive references.
 
-Pallas kernels run in interpreter mode on the CPU test mesh (Mosaic only
-compiles on real TPU); the wrappers auto-select that, and
-``force_pallas_interpret`` drives the flat-update kernel's Pallas path
-explicitly.
+Pallas kernels run in interpreter mode on the CPU test mesh, always by
+explicit request (``interpret=True`` / ``force_pallas_interpret``): Mosaic
+only compiles for a real TPU, and no wrapper picks interpret mode on its own.
 """
 
 import jax
@@ -57,7 +56,8 @@ def _qkv(b=2, h=2, sq=256, sk=256, d=64, seed=0):
 def test_flash_attention_matches_reference(causal):
     q, k, v = _qkv()
     want = attention_reference(q, k, v, causal=causal)
-    got = flash_attention(q, k, v, causal=causal, block_q=128, block_k=128)
+    got = flash_attention(q, k, v, causal=causal, block_q=128, block_k=128,
+                          interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=1e-4)
 
 
@@ -137,7 +137,7 @@ def test_flash_attention_gradients_match_reference(causal, bwd_impl):
     def f(q, k, v):
         return flash_attention(q, k, v, causal=causal, block_q=128,
                                block_k=128, block_q_bwd=256, block_k_bwd=128,
-                               bwd_impl=bwd_impl).sum()
+                               bwd_impl=bwd_impl, interpret=True).sum()
 
     def r(q, k, v):
         return attention_reference(q, k, v, causal=causal).sum()
@@ -160,7 +160,7 @@ def test_flash_attention_lse_matches_reference(causal):
     q, k, v = (jnp.asarray(rng.normal(size=(b, h, s, d)), jnp.float32)
                for _ in range(3))
     out, lse = flash_attention_lse(q, k, v, causal=causal,
-                                   block_q=128, block_k=128)
+                                   block_q=128, block_k=128, interpret=True)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * d**-0.5
     if causal:
         mask = jnp.tril(jnp.ones((s, s), bool))
@@ -187,7 +187,8 @@ def test_flash_attention_lse_cotangent_reaches_inputs(bwd_impl):
 
     def f(q, k, v):
         out, lse = flash_attention_lse(q, k, v, causal=True, block_q=128,
-                                       block_k=128, bwd_impl=bwd_impl)
+                                       block_k=128, bwd_impl=bwd_impl,
+                                       interpret=True)
         return jnp.sum(out**2) + jnp.sum(jnp.sin(lse))
 
     def r(q, k, v):
@@ -220,10 +221,10 @@ def test_flash_bwd_impl_auto_selects_split_at_extreme_length(monkeypatch):
     rng = np.random.default_rng(0)
     q, k, v = (jnp.asarray(rng.normal(size=(1, 1, 256, 64)), jnp.float32)
                for _ in range(3))
-    A.flash_attention(q, k, v, causal=True)
+    A.flash_attention(q, k, v, causal=True, interpret=True)
     assert chosen[-1] == "fused"
     monkeypatch.setattr(A, "FUSED_BWD_PARTIALS_CAP", 1)  # force the cap
-    A.flash_attention(q, k, v, causal=True)
+    A.flash_attention(q, k, v, causal=True, interpret=True)
     assert chosen[-1] == "split"
 
 
@@ -336,7 +337,7 @@ def test_flash_attention_default_blocks_adapt_to_sequence():
     rng = np.random.default_rng(7)
     q, k, v = (jnp.asarray(rng.normal(size=(1, 1, 1536, 8)), jnp.float32)
                for _ in range(3))
-    got = flash_attention(q, k, v, causal=True)
+    got = flash_attention(q, k, v, causal=True, interpret=True)
     want = attention_reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=2e-4)

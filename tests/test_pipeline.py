@@ -14,48 +14,6 @@ from distributed_ml_pytorch_tpu.parallel.pipeline import (
     microbatch,
 )
 from distributed_ml_pytorch_tpu.parallel.seq_parallel import next_token_targets
-from distributed_ml_pytorch_tpu import LEGACY_SHARD_MAP
-
-#: ISSUE 3 satellite tracking note: on runtimes with the OLD
-#: experimental shard_map (jax <= 0.4.x), the model-axis pipeline
-#: composites trace only under the compat shim's check_rep=False fallback,
-#: which skips transpose-time psum insertion INSIDE the tp block's
-#: collective chain — the forward (loss) is exact (sharded_init made the
-#: multi-axis-mesh inits value-identical, and __graft_entry__'s
-#: dryrun_multichip asserts dp×pp×tp loss == pure-pp to 1e-4), but
-#: param-level gradient parity deviates per layer. The dp-only composite
-#: is FIXED by the explicit reductions in pipeline._wrap_pp_step; the
-#: model-axis fix needs the graduated shard_map's vma transpose rules,
-#: i.e. a jax upgrade. strict=True: this is a deterministic deviation —
-#: if it starts passing, the runtime changed and the mark must go.
-#: (ISSUE 10 status: these tp-block xfails are the ONLY legacy pipeline
-#: xfails left — the pp-gradient ones were burned down by the MPMD
-#: per-stage-compiled step, see the note below — and they stay because
-#: the MPMD plane does not yet run a tensor-parallel stage forward.)
-legacy_tp_grads_xfail = pytest.mark.xfail(
-    LEGACY_SHARD_MAP, strict=True,
-    reason="legacy shard_map check_rep=False fallback skips transpose-time "
-           "psums inside the model-axis (Megatron) block — gradient parity "
-           "needs the graduated shard_map (see comment above)")
-
-#: ISSUE 10 burn-down note: the former ``legacy_pp_grads_xfail`` entries
-#: (pipeline-vs-single-stage and 1f1b-vs-gpipe GRADIENT parity, both
-#: pre-existing at the growth seed) are GONE: the MPMD pipeline plane
-#: (``parallel/mpmd.py``) compiles every stage STANDALONE — plain jit +
-#: per-stage vjp, no shard_map — so those capabilities now hold exactly on
-#: every runtime and are asserted un-xfailed below via the MPMD step.
-#: The SHARD_MAP versions of the same comparisons keep running where their
-#: gradient semantics are defined (the graduated shard_map); on legacy
-#: runtimes they are skipped with this tracking note — the deviation is
-#: the old runtime's transpose machinery, not this repo's math, and the
-#: exact path there is the MPMD plane. Only the tp-block xfail above
-#: remains genuinely pre-existing.
-legacy_shard_map_grads_skip = pytest.mark.skipif(
-    LEGACY_SHARD_MAP,
-    reason="legacy shard_map pipeline-gradient deviation vs the unsharded "
-           "reference (pre-existing at the seed; loss parity holds) — the "
-           "exact multi-stage path on this runtime is the MPMD plane, "
-           "asserted by the un-skipped tests below and tests/test_mpmd.py")
 
 
 def cfg4():
@@ -91,9 +49,7 @@ def run_steps(n_stages, n_micro, n_steps=2):
 
 def test_pipeline_matches_single_stage():
     """The 4-stage pipeline equals the single-stage reference — loss AND
-    updated params — via the MPMD per-stage-compiled step, which holds
-    exactly on every runtime (ISSUE 10 burned down the legacy xfail; see
-    the tracking note above)."""
+    updated params — via the MPMD per-stage-compiled step."""
     from distributed_ml_pytorch_tpu.parallel.mpmd import MpmdLocal
 
     ref_losses, ref_params = run_steps(n_stages=1, n_micro=1)
@@ -108,10 +64,8 @@ def test_pipeline_matches_single_stage():
                                    atol=1e-6)
 
 
-@legacy_shard_map_grads_skip
 def test_shard_map_pipeline_matches_single_stage():
-    """The shard_map schedule's version of the same parity, where its
-    gradient semantics are defined (graduated shard_map runtimes)."""
+    """The shard_map schedule's version of the same parity."""
     ref_losses, ref_params = run_steps(n_stages=1, n_micro=1)
     pp_losses, pp_params = run_steps(n_stages=4, n_micro=4)
     np.testing.assert_allclose(pp_losses, ref_losses, rtol=2e-5)
@@ -295,8 +249,7 @@ def test_1f1b_schedule_timetable_properties():
 def test_1f1b_matches_gpipe_loss_and_grads():
     """The 1F1B and GPipe execution orders compute the same function:
     identical loss and identical parameter updates. Asserted via the MPMD
-    per-stage-compiled step — exact on every runtime (ISSUE 10 burned
-    down the legacy xfail; the shard_map comparison keeps its own test
+    per-stage-compiled step (the shard_map comparison keeps its own test
     below) — with the per-microbatch work depth-first (bounded
     activations) vs all-forwards-then-backwards."""
     from distributed_ml_pytorch_tpu.parallel.mpmd import MpmdLocal
@@ -313,12 +266,10 @@ def test_1f1b_matches_gpipe_loss_and_grads():
                                    rtol=1e-6, atol=1e-8)
 
 
-@legacy_shard_map_grads_skip
 def test_shard_map_1f1b_matches_gpipe_loss_and_grads():
     """schedule='1f1b' computes the same function as GPipe on the
     shard_map plane: identical loss and identical parameter updates (the
-    hand-built backward against AD) — where the legacy transpose
-    semantics don't interfere."""
+    hand-built backward against AD)."""
     cfg = PipelineLMConfig(
         vocab_size=64, d_model=32, n_heads=4, n_layers=8, d_ff=64, max_len=128
     )
@@ -400,7 +351,6 @@ def test_dp_pp_composite_matches_pure_pp(sched, kw):
 
 
 @pytest.mark.slow  # two compiled worlds per case
-@legacy_tp_grads_xfail
 @pytest.mark.parametrize("sched,kw", [
     ("gpipe", {}), ("interleaved", {"virtual_stages": 2}), ("1f1b", {}),
 ])
@@ -439,7 +389,6 @@ def test_pp_tp_composite_matches_pure_pp(sched, kw):
 
 
 @pytest.mark.slow
-@legacy_tp_grads_xfail
 def test_dp_pp_tp_2x2x2_matches_pure_pp():
     """The full composite: dp x pp x tp on a (data=2, stage=2, model=2)
     mesh — the canonical deep-LM 3-D layout — must match pure pp on the

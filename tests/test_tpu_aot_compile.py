@@ -1,0 +1,220 @@
+"""Every Pallas kernel in ``ops/`` compiles for a TPU v5e — checked with no chip.
+
+libtpu can describe a v5e topology to JAX on a machine that has none, and
+``jit(f).lower(<shapes placed on those devices>).compile()`` then runs Mosaic
+and XLA's TPU backend ahead of time. So "the kernel compiles" stays true on
+every tier-1 run instead of being learned on the next chip run: a block shape
+off the (8, 128) tiling, a scoped-VMEM overflow or an op Mosaic does not lower
+fails here. Execution (that the compiled kernels give the right numbers) is
+``chip_smoke.py``'s part.
+
+``jax.default_backend`` is patched to say ``"tpu"`` so the repo's own
+dispatch takes the branch it takes on the chip; every program must show
+``tpu_custom_call`` in its compiled text. Kernel-only programs compile in
+seconds; the whole-step programs are behind the ``slow`` marker.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+CUSTOM_CALL = 'custom_call_target="tpu_custom_call"'
+
+#: the three AlexNet conv outputs that feed a relu -> 2x2 pool tail, per image
+ALEXNET_TAILS = [(8, 8, 64), (4, 4, 192), (2, 2, 256)]
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or one that cannot describe a v5e
+        pytest.skip(f"libtpu cannot give a v5e topology here: {e!r}")
+    assert len(topo.devices) == 4 and topo.devices[0].platform == "tpu"
+    return topo.devices
+
+
+@pytest.fixture
+def chip_dispatch(monkeypatch):
+    """The repo's ``jax.default_backend() == "tpu"`` branches, with no chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def on(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def assert_kernels(text: str, at_least: int = 1) -> None:
+    n = text.count(CUSTOM_CALL)
+    assert n >= at_least, f"{n} tpu_custom_call(s) in the compiled text"
+
+
+def test_flat_axpy_compiles(v5e, chip_dispatch):
+    from distributed_ml_pytorch_tpu.ops import flat_axpy
+
+    one = SingleDeviceSharding(v5e[0])
+    vec = on(one, (2_472_320,))  # raveled AlexNet, padded to 128 lanes
+    assert_kernels(compiled_text(lambda y, x: flat_axpy(y, x, -0.008), vec, vec))
+
+
+@pytest.mark.parametrize("batch", [64, 256, 1024])
+@pytest.mark.parametrize("tail", ALEXNET_TAILS, ids=lambda t: "x".join(map(str, t)))
+def test_conv_epilogues_compile_forward_and_gradient(v5e, chip_dispatch,
+                                                     batch, tail):
+    from distributed_ml_pytorch_tpu.ops.fused_conv import bias_relu, relu_pool2
+
+    one = SingleDeviceSharding(v5e[0])
+    x = on(one, (batch, *tail))
+    b = on(one, (tail[-1],))
+
+    def both(op):
+        def f(x, b):
+            y, pull = jax.vjp(op, x, b)
+            return (y,) + pull(y)
+        return f
+
+    # forward + backward kernel each
+    assert_kernels(compiled_text(both(relu_pool2), x, b), 2)
+    assert_kernels(compiled_text(both(bias_relu), x, b), 2)
+
+
+@pytest.mark.parametrize("shape", [(8, 12, 2048, 64), (1, 12, 8192, 64),
+                                   (1, 16, 4096, 128)],
+                         ids=lambda s: "b{}h{}s{}d{}".format(*s))
+@pytest.mark.parametrize("bwd_impl", ["fused", "split"])
+def test_flash_attention_compiles_forward_and_gradient(v5e, shape, bwd_impl):
+    from distributed_ml_pytorch_tpu.ops.attention import flash_attention
+
+    one = SingleDeviceSharding(v5e[0])
+    q = on(one, shape, jnp.bfloat16)
+
+    def f(q, k, v):
+        out, pull = jax.vjp(
+            lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                            bwd_impl=bwd_impl), q, k, v)
+        return (out,) + pull(out)
+
+    # forward + one fused backward kernel, or + the dQ and dK/dV pair
+    assert_kernels(compiled_text(f, q, q, q), 2 if bwd_impl == "fused" else 3)
+
+
+def test_fused_head_update_kernel_compiles(v5e, chip_dispatch):
+    """``head_update_sgd(use_kernel=True)`` at GPT-2-small b8 x S2048."""
+    from distributed_ml_pytorch_tpu.ops.fused_head import head_update_sgd
+
+    one = SingleDeviceSharding(v5e[0])
+    n, d, vocab = 8 * 2048, 768, 50304
+    text = compiled_text(
+        lambda W, h, lg, lse, lab, gs: head_update_sgd(
+            W, h, lg, lse, lab, gs, 0.05, use_kernel=True),
+        on(one, (d, vocab)), on(one, (n, d), jnp.bfloat16),
+        on(one, (n, vocab), jnp.bfloat16), on(one, (n,)),
+        on(one, (n,), jnp.int32), on(one, (n,)))
+    assert_kernels(text)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_decode_attention_kernel_compiles(v5e, quant):
+    from distributed_ml_pytorch_tpu.ops.decode_attention import (
+        decode_attention_step,
+    )
+
+    one = SingleDeviceSharding(v5e[0])
+    b, h, c, t, d = 8, 12, 1024, 16, 64
+    step = on(one, (b, h, 1, d), jnp.bfloat16)
+    ring = on(one, (b, h, t, d), jnp.bfloat16)
+    big = on(one, (b, h, c, d), jnp.int8 if quant else jnp.bfloat16)
+    scalar = on(one, (), jnp.int32)
+    args = [step, step, step, big, big, ring, ring, scalar, scalar]
+    if quant:
+        args += [on(one, (b, h, c)), on(one, (b, h, c))]
+    assert_kernels(compiled_text(decode_attention_step, *args))
+
+
+# ---------------------------------------------------------- whole programs
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("accum", [False, True], ids=["plain", "microbatch256"])
+def test_alexnet_fused_epilogue_scan_step_compiles(v5e, chip_dispatch, accum):
+    """``bench.py``'s large legs: the b1024 ``fused_epilogue=True`` scan."""
+    from distributed_ml_pytorch_tpu.models import AlexNet
+    from distributed_ml_pytorch_tpu.training.trainer import (
+        create_train_state,
+        make_scan_accum_train_step,
+        make_scan_train_step,
+    )
+
+    one = SingleDeviceSharding(v5e[0])
+    model = AlexNet(num_classes=10, fused_epilogue=True)
+    # the init runs for real, here on the CPU: the unfused model has the
+    # same parameter tree and no kernel to refuse
+    state, tx = create_train_state(AlexNet(num_classes=10), jax.random.key(0),
+                                   lr=0.008, sample_shape=(1, 32, 32, 3))
+    step = (make_scan_accum_train_step(model, tx, 256,
+                                       effective_update_batch=64)
+            if accum else make_scan_train_step(model, tx))
+    abstract = jax.tree.map(lambda x: on(one, x.shape, x.dtype), state)
+    text = step.lower(
+        abstract, on(one, (4, 1024, 32, 32, 3)),
+        on(one, (4, 1024), jnp.int32),
+        jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=one),
+    ).compile().as_text()
+    assert_kernels(text, 6)  # three relu->pool tails, forward and backward
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("batch,seq", [(8, 2048), (1, 8192)])
+def test_gpt2_small_train_step_compiles(v5e, chip_dispatch, batch, seq):
+    """``examples.train_lm --mode single`` at GPT-2-small width: 12 layers of
+    flash attention, forward and backward."""
+    import optax
+
+    from distributed_ml_pytorch_tpu.models import TransformerLM
+    from distributed_ml_pytorch_tpu.parallel.fsdp import (
+        _state_shardings,
+        make_fsdp_lm_train_step,
+    )
+    from distributed_ml_pytorch_tpu.training.trainer import TrainState
+
+    mesh = Mesh(np.array(v5e[:1]), ("data",))
+    lm = TransformerLM(vocab_size=50304, d_model=768, n_heads=12, n_layers=12,
+                       d_ff=3072, max_len=seq, dtype=jnp.bfloat16,
+                       pos_encoding="rope")
+    tx = optax.sgd(0.05)
+    shapes = jax.eval_shape(
+        lambda key: TrainState.create(
+            lm.init(key, jnp.zeros((1, 8), jnp.int32))["params"], tx),
+        jax.random.key(0))
+    shardings = _state_shardings(mesh, shapes, "data")
+    step = make_fsdp_lm_train_step(lm, tx, mesh, shardings)
+    state = jax.tree.map(lambda x, s: on(s, x.shape, x.dtype), shapes, shardings)
+    tokens = on(NamedSharding(mesh, P("data", None)), (batch, seq), jnp.int32)
+    assert_kernels(step.lower(state, tokens, tokens).compile().as_text(), 24)
+
+
+@pytest.mark.slow
+def test_ring_flash_attention_compiles_on_four_chips(v5e, chip_dispatch):
+    """``parallel/ring.py``'s flash ring over the 2x2 topology, forward and
+    gradient — the branch no CPU mesh ever takes."""
+    from distributed_ml_pytorch_tpu.parallel.ring import make_ring_attention
+
+    mesh = Mesh(np.array(v5e), ("seq",))
+    ring = make_ring_attention(mesh, "seq", causal=True)  # impl: the default
+    q = on(NamedSharding(mesh, P(None, None, "seq", None)),
+           (2, 12, 4 * 1024, 64), jnp.bfloat16)
+
+    def f(q, k, v):
+        out, pull = jax.vjp(ring, q, k, v)
+        return (out,) + pull(out)
+
+    assert_kernels(compiled_text(f, q, q, q), 2)

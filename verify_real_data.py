@@ -72,6 +72,10 @@ def main() -> int:
     assert not is_synth and len(x) == 50000
 
     from bench_all import bench_steps_to_accuracy, log
+    from distributed_ml_pytorch_tpu.runtime import startup
+
+    startup.enable_compile_cache()
+    log(f"verify_real_data: {startup.device_summary()}")
 
     # one pass, both frameworks, full 2000-step stream; every target's
     # crossing derives from the recorded curves
