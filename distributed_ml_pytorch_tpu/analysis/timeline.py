@@ -44,7 +44,6 @@ KNOWN_PLANES: Dict[str, tuple] = {
     "mpmd": ("compute", "wait-act", "wait-grad", "wire-blocked", "ckpt",
              "idle"),
     "ps": ("apply", "wal", "idle"),
-    "serving": ("prefill", "decode", "idle"),
     "wire": ("wire-blocked",),
     "coord": (),
 }

@@ -57,6 +57,7 @@ from distributed_ml_pytorch_tpu.models.generate import (
     sample_tokens_dynamic,
     split_cache,
 )
+from distributed_ml_pytorch_tpu.utils.tracing import span
 
 
 def find_cache_leaf(tree, name: str):
@@ -276,13 +277,17 @@ class SlotKVPool:
         """Advance every slot by one ``decode_block``-token block; returns
         the sampled tokens ``[slots, decode_block]`` (host array — the
         fetch is the block's device sync point)."""
-        self.cache, toks = _decode_block_jit(
-            self.dec, self.params, self.cache,
-            jnp.asarray(tok, jnp.int32), jnp.asarray(n_gen, jnp.int32),
-            jnp.asarray(seeds, jnp.uint32), jnp.asarray(temps, jnp.float32),
-            jnp.asarray(top_ks, jnp.int32), jnp.asarray(top_ps, jnp.float32),
-            jnp.asarray(active, bool))
-        return np.asarray(toks)
+        with span("serve.decode.dispatch"):  # enqueues the program
+            self.cache, toks = _decode_block_jit(
+                self.dec, self.params, self.cache,
+                jnp.asarray(tok, jnp.int32), jnp.asarray(n_gen, jnp.int32),
+                jnp.asarray(seeds, jnp.uint32),
+                jnp.asarray(temps, jnp.float32),
+                jnp.asarray(top_ks, jnp.int32),
+                jnp.asarray(top_ps, jnp.float32),
+                jnp.asarray(active, bool))
+        with span("serve.decode.fetch"):  # waits for the device
+            return np.asarray(toks)
 
     def reset_slots(self, slot_indices) -> None:
         """Mark the given slots empty (cursor/ring_base back to 0)."""

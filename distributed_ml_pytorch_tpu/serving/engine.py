@@ -19,11 +19,23 @@ restarts. Backpressure is explicit: ``submit`` raises
 :class:`QueueFullError` once ``max_queue`` requests are waiting, which the
 transport frontend maps to a reject frame (``serving/frontend.py``).
 
-SLO observability rides ``utils/metrics.py``/``utils/tracing.py``: TTFT
-and TPOT samples summarized by ``latency_summary`` percentiles, decode
-block latency through a ``StepTimer``, queue depth and slot occupancy
-sampled every scheduling round. Tokens stream at block granularity —
-per-token latency is the block time divided by the block's tokens.
+SLO observability rides ``utils/metrics.py``/``utils/tracing.py``: TTFT,
+TPOT and queue-wait samples summarized by ``latency_summary`` percentiles,
+decode block latency through a ``StepTimer``, queue depth and slot
+occupancy sampled every scheduling round, and the host time BETWEEN two
+decode blocks with the phase that held the longest one (``slo_summary()``:
+where a host stall shows). Tokens stream at block granularity — per-token
+latency is the block time divided by the block's tokens.
+
+Tracing: every round writes ``serve.*`` spans through
+``utils/tracing.span`` (``serve.step`` > ``serve.prefill`` (one a request,
+with ``request_id`` and ``queue_wait_us``), ``serve.decode`` >
+``serve.decode.dispatch|fetch``, ``serve.emit``) into whatever profile is
+being taken, on the device trace's clock, and costs an object construction
+each when none is. Each is read by a per-layer metric of the benchmark
+(``benchmarks/program_trace.py``). The flight recorder
+(``utils/obs.SpanRecorder``) is for the distributed planes (coordinator,
+pipeline stages, the wire, the fleet router), not for the engine.
 
 Determinism contract: with ``temperature=0`` (or any fixed sampling params
 + seed) a request's output is the same regardless of arrival order or what
@@ -47,7 +59,12 @@ import numpy as np
 from distributed_ml_pytorch_tpu.models.generate import DECODE_BLOCK
 from distributed_ml_pytorch_tpu.serving.cache import SlotKVPool
 from distributed_ml_pytorch_tpu.utils.metrics import latency_summary
-from distributed_ml_pytorch_tpu.utils.tracing import StepTimer
+from distributed_ml_pytorch_tpu.utils.tracing import StepTimer, span
+
+
+#: what the host can be doing between the end of one decode block's fetch
+#: and the next block's dispatch
+_GAP_PHASES = ("evict", "admit", "emit", "outside_step")
 
 
 class QueueFullError(RuntimeError):
@@ -134,18 +151,13 @@ class ServingEngine:
                  cache_size: int = 256, decode_block: int = DECODE_BLOCK,
                  kv_quant: bool = False, max_queue: int = 64,
                  prefill_bucket: int = 16,
-                 on_tokens: Optional[Callable] = None,
-                 recorder=None):
+                 on_tokens: Optional[Callable] = None):
         self.pool = SlotKVPool(
             model, params, slots=slots, cache_size=cache_size,
             decode_block=decode_block, kv_quant=kv_quant)
         self.max_queue = int(max_queue)
         self.prefill_bucket = max(1, int(prefill_bucket))
         self.on_tokens = on_tokens
-        #: optional flight recorder (``utils/obs.SpanRecorder``, ISSUE 12):
-        #: queue/prefill/decode spans per request correlation id — the
-        #: serving plane's side of the fleet timeline. Observational only.
-        self.recorder = recorder
         self._lock = threading.Lock()
         self._queue: Deque[Request] = collections.deque()
         self._ids = itertools.count()
@@ -167,6 +179,13 @@ class ServingEngine:
         self._queue_depths: collections.deque = collections.deque(maxlen=65536)
         self._occupancy: collections.deque = collections.deque(maxlen=65536)
         self._block_timer = StepTimer(skip=1)
+        self._queue_wait: List[float] = []
+        # host seconds from a decode block's fetch to the next dispatch
+        # while a slot is active, the open interval's phases, its last mark
+        self._between: collections.deque = collections.deque(maxlen=65536)
+        self._between_max: Tuple[float, Optional[str]] = (0.0, None)
+        self._gap: Optional[dict] = None
+        self._t_mark = 0.0
         self._completed = 0
         self._cancelled = 0
         self._rejected = 0
@@ -223,15 +242,9 @@ class ServingEngine:
             if sum(1 for r in self._queue
                    if not r.cancelled) >= self.max_queue:
                 self._rejected += 1
-                if self.recorder is not None:
-                    self.recorder.event("queue-reject", corr=req.corr,
-                                        id=req.request_id)
                 raise QueueFullError(
                     f"queue at max_queue={self.max_queue}; retry later")
             self._queue.append(req)
-        if self.recorder is not None:
-            self.recorder.event("submit", corr=req.corr, id=req.request_id,
-                                prompt_len=int(prompt.size))
         return req
 
     def _bucket_len(self, prompt_len: int) -> int:
@@ -270,20 +283,29 @@ class ServingEngine:
     def step(self) -> bool:
         """One scheduling round: evict → admit → decode one block. Returns
         False when there was nothing to do (caller may idle-sleep)."""
-        worked = self._evict()
-        worked = self._admit() or worked
-        active = [r is not None for r in self._slot_req]
-        if worked or any(active):
-            # sample scheduler health only on rounds that do work — a
-            # serve_forever loop idles at ~500 rounds/s and would both
-            # grow these lists without bound and dilute the occupancy
-            # stats with idle zeros (the deques bound the busy case too)
-            with self._lock:
-                self._queue_depths.append(len(self._queue))
-            self._occupancy.append(sum(active) / len(active))
-        if any(active):
-            self._decode(np.asarray(active, bool))
-            worked = True
+        with self._lock:
+            queued = len(self._queue)
+        if not queued and not any(r is not None for r in self._slot_req):
+            return False  # an idle loop polls 500 times a second: no span
+        self._mark("outside_step")
+        with span("serve.step"):
+            worked = self._evict()
+            self._mark("evict")
+            worked = self._admit() or worked
+            active = [r is not None for r in self._slot_req]
+            if worked or any(active):
+                # sample scheduler health only on rounds that do work — a
+                # serve_forever loop idles at ~500 rounds/s and would both
+                # grow these lists without bound and dilute the occupancy
+                # stats with idle zeros (the deques bound the busy case too)
+                with self._lock:
+                    self._queue_depths.append(len(self._queue))
+                self._occupancy.append(sum(active) / len(active))
+            if any(active):
+                self._decode(np.asarray(active, bool))
+                worked = True
+            else:
+                self._gap = None  # no slot waits for the host: nothing to time
         return worked
 
     def run_until_idle(self, max_rounds: int = 10_000) -> None:
@@ -322,70 +344,86 @@ class ServingEngine:
                     break
                 req = self._queue.popleft()
             slot = free.pop(0)
-            p = int(req.prompt.size)
-            bucket = self._bucket_len(p)
-            padded = np.zeros(bucket, np.int32)
-            padded[:p] = req.prompt
-            sp = req.sampling
-            # claim the slot BEFORE the admission dispatch: between the
-            # queue pop above and this point the request is in neither the
-            # queue count nor the slot count, and a fleet router sampling
-            # pressure() cross-thread would see a falsely idle engine and
-            # stack new work onto it (prefill dispatch is a ~ms window)
-            req.active_at_admit = sum(
-                r is not None for r in self._slot_req)
-            req.slot = slot  # with it, "slot is None" == waiting, exactly
-            self._slot_req[slot] = req
-            rec = self.recorder
-            t0 = time.monotonic_ns() if rec is not None else 0
-            tok0 = self.pool.admit(
-                slot, padded, p, seed=sp.seed, temperature=sp.temperature,
-                top_k=sp.top_k, top_p=sp.top_p, gen_offset=req.gen_offset)
+            # the wait in the queue ends here and holds nothing of the
+            # prefill: stamped BEFORE the dispatch
             req.t_admit = time.perf_counter()
-            if rec is not None:
-                # queue wait ended here; the prefill span carries the
-                # request's correlation id through slot admission
-                rec.record("prefill", "prefill", t0, time.monotonic_ns(),
-                           corr=req.corr,
-                           meta={"id": req.request_id, "slot": slot,
-                                 "bucket": bucket})
-            self._tok[slot] = tok0
-            # the per-slot sampling clock continues the request's OWN
-            # schedule: a resumed request's next draw is fold_in(key,
-            # gen_offset + 1), exactly what its first life would have drawn
-            self._n_gen[slot] = req.gen_offset + 1
-            self._seeds[slot] = np.uint32(sp.seed)
-            self._temps[slot] = sp.temperature
-            self._top_ks[slot] = sp.top_k
-            self._top_ps[slot] = sp.top_p
-            self._emit(req, [tok0])
-            admitted = True
-            if req.done:  # max_new_tokens == 1, or the first token was eos
-                self._finish(req)
-                self._slot_req[slot] = None
-                self.pool.reset_slots([slot])  # same sweep _evict gives others
-                free.insert(0, slot)
+            wait = req.t_admit - req.t_submit
+            self._queue_wait.append(wait)
+            with span("serve.prefill", request_id=req.request_id,
+                      queue_wait_us=int(wait * 1e6)):
+                p = int(req.prompt.size)
+                bucket = self._bucket_len(p)
+                padded = np.zeros(bucket, np.int32)
+                padded[:p] = req.prompt
+                sp = req.sampling
+                # claim the slot BEFORE the admission dispatch: between the
+                # queue pop above and this point the request is in neither
+                # the queue count nor the slot count, and a fleet router
+                # sampling pressure() cross-thread would see a falsely idle
+                # engine and stack new work onto it (prefill dispatch is a
+                # ~ms window)
+                req.active_at_admit = sum(
+                    r is not None for r in self._slot_req)
+                req.slot = slot  # with it, "slot is None" == waiting, exactly
+                self._slot_req[slot] = req
+                tok0 = self.pool.admit(
+                    slot, padded, p, seed=sp.seed,
+                    temperature=sp.temperature, top_k=sp.top_k,
+                    top_p=sp.top_p, gen_offset=req.gen_offset)
+                self._tok[slot] = tok0
+                # the per-slot sampling clock continues the request's OWN
+                # schedule: a resumed request's next draw is fold_in(key,
+                # gen_offset + 1), exactly what its first life would have
+                # drawn
+                self._n_gen[slot] = req.gen_offset + 1
+                self._seeds[slot] = np.uint32(sp.seed)
+                self._temps[slot] = sp.temperature
+                self._top_ks[slot] = sp.top_k
+                self._top_ps[slot] = sp.top_p
+                self._emit(req, [tok0])
+                admitted = True
+                if req.done:  # max_new_tokens == 1, or the first token was eos
+                    self._finish(req)
+                    self._slot_req[slot] = None
+                    self.pool.reset_slots([slot])  # same sweep _evict gives others
+                    free.insert(0, slot)
         return admitted
 
     def _decode(self, active: np.ndarray) -> None:
-        rec = self.recorder
-        t0 = time.monotonic_ns() if rec is not None else 0
-        self._block_timer.start()
-        toks = self.pool.decode_block_step(
-            self._tok, self._n_gen, self._seeds, self._temps,
-            self._top_ks, self._top_ps, active)  # [S, T] host array (syncs)
-        self._block_timer.tick()
-        if rec is not None:
-            rec.record("decode-block", "decode", t0, time.monotonic_ns(),
-                       corr=0, meta={"active": int(active.sum())})
+        self._mark("admit")
+        if self._gap is not None:
+            # the slots stood still from the last block's fetch to here
+            gap = sum(self._gap.values())
+            self._between.append(gap)
+            if gap > self._between_max[0]:
+                self._between_max = (gap, max(self._gap, key=self._gap.get))
+        with span("serve.decode"):
+            self._block_timer.start()
+            toks = self.pool.decode_block_step(
+                self._tok, self._n_gen, self._seeds, self._temps,
+                self._top_ks, self._top_ps, active)  # [S, T] host array (syncs)
+            self._block_timer.tick()
+        # the device has nothing queued from here to the next dispatch
+        self._t_mark = time.perf_counter()
+        self._gap = dict.fromkeys(_GAP_PHASES, 0.0)
         T = toks.shape[1]
-        for slot, req in enumerate(self._slot_req):
-            if req is None:
-                continue
-            self._tok[slot] = toks[slot, -1]
-            self._n_gen[slot] += T  # sampling-step clock, even past finish
-            remaining = req.max_new_tokens - len(req.tokens)
-            self._emit(req, toks[slot, :remaining].tolist())
+        with span("serve.emit"):  # on_tokens callbacks included
+            for slot, req in enumerate(self._slot_req):
+                if req is None:
+                    continue
+                self._tok[slot] = toks[slot, -1]
+                self._n_gen[slot] += T  # sampling-step clock, even past finish
+                remaining = req.max_new_tokens - len(req.tokens)
+                self._emit(req, toks[slot, :remaining].tolist())
+        self._mark("emit")
+
+    def _mark(self, phase: str) -> None:
+        """Charge the host time since the last mark to ``phase`` of the open
+        between-blocks interval (none open: only move the mark)."""
+        now = time.perf_counter()
+        if self._gap is not None:
+            self._gap[phase] += now - self._t_mark
+        self._t_mark = now
 
     def _emit(self, req: Request, new_tokens: List[int]) -> None:
         """Append ``new_tokens`` to a request's stream (truncating at eos),
@@ -457,6 +495,9 @@ class ServingEngine:
         self._tpot.clear()
         self._queue_depths.clear()
         self._occupancy.clear()
+        self._queue_wait.clear()
+        self._between.clear()
+        self._between_max = (0.0, None)
         self._block_timer.reset_stats()
         self._completed = 0
         self._cancelled = 0
@@ -467,12 +508,21 @@ class ServingEngine:
         far, plus scheduler health (queue depth, occupancy, rejects)."""
         to_ms = lambda xs: [x * 1e3 for x in xs]
         depths = self._queue_depths or [0]
+        between = latency_summary(to_ms(self._between))
+        if between is not None:
+            between["max_phase"] = self._between_max[1]
         return {
             "completed": self._completed,
             "cancelled": self._cancelled,
             "rejected": self._rejected,
             "ttft_ms": latency_summary(to_ms(self._ttft)),
             "tpot_ms": latency_summary(to_ms(self._tpot)),
+            # wait in the queue alone (submit to admission, no prefill in it)
+            "queue_wait_ms": latency_summary(to_ms(self._queue_wait)),
+            # host time between two decode blocks while a slot is active,
+            # and the phase (evict, admit, emit, outside_step) that held
+            # most of the longest one: where a host stall shows
+            "between_blocks_ms": between,
             "decode_block": self._block_timer.summary(),
             "queue_depth": {"mean": float(np.mean(depths)),
                             "max": int(np.max(depths))},

@@ -16,6 +16,10 @@ ships it as a first-class utility:
   state.
 - :func:`annotate_step` — ``jax.profiler.StepTraceAnnotation`` passthrough so
   per-step markers line up in the trace viewer.
+- :func:`span` — ``jax.profiler.TraceAnnotation`` passthrough: the package's
+  one span call. A span lands in whatever profile is being taken (a
+  ``TraceWindow``, ``jax.profiler.start_trace``, the profiler server), on the
+  clock the device's events are on, and nowhere when none is.
 """
 
 from __future__ import annotations
@@ -194,3 +198,17 @@ def annotate_step(name: str, step: int):
     import jax
 
     return jax.profiler.StepTraceAnnotation(name, step_num=step)
+
+
+def span(name: str, **attrs):
+    """A host span in the profiler's own trace, as a context manager.
+
+    Names are lower case with dots for nesting (``serve.prefill.dispatch``);
+    ``attrs`` are whole numbers (microseconds end in ``_us``) and come back
+    as the event's stats. A span's cause is the span that encloses it on its
+    thread; spans of one request share ``request_id``. There is no switch:
+    with no profile being taken this is one object construction.
+    """
+    import jax
+
+    return jax.profiler.TraceAnnotation(name, **attrs)
