@@ -100,6 +100,14 @@ class MultiHeadAttention(nn.Module):
     #: batch 32 vs 46 us for BOTH attention reads at the HBM roofline);
     #: buffering appends in a ring the scan can copy cheaply and merging
     #: once per block amortizes the big-cache write to ~1 copy / T steps.
+    #: The append itself is a select over the ring's T rows, not a
+    #: dynamic_update_slice: under ``SlotKVPool``'s vmap over slots the
+    #: offset is a per-slot vector, a dynamic_update_slice with a batched
+    #: index is a scatter, and the TPU compiler runs that scatter as a
+    #: sequential loop over the slots (32 iterations of eight small kernels
+    #: for each of K and V in every layer of every step). A select has no
+    #: index operand, so it stays one dense pass over the ring, batched or
+    #: not, and leaves the same bytes there.
     decode_block: int = 0
     #: store the big decode cache as int8 with per-(batch, head, position)
     #: f32 scales (``quantize_kv``) — HALVES THE CACHE'S HBM FOOTPRINT
@@ -324,8 +332,11 @@ class MultiHeadAttention(nn.Module):
                          ring_v.value, preferred_element_type=jnp.float32)
             + probs[..., self.cache_size + T:].astype(jnp.float32) * v
         )
-        ring_k.value = jax.lax.dynamic_update_slice(ring_k.value, k, (0, 0, t, 0))
-        ring_v.value = jax.lax.dynamic_update_slice(ring_v.value, v, (0, 0, t, 0))
+        # append by select, not dynamic_update_slice (see ``decode_block``);
+        # t is in 0..T-1 inside a block, so exactly one row is replaced
+        here = (jnp.arange(T) == t)[None, None, :, None]
+        ring_k.value = jnp.where(here, k, ring_k.value)
+        ring_v.value = jnp.where(here, v, ring_v.value)
         cursor.value = idx + 1
         return out.astype(q.dtype)
 

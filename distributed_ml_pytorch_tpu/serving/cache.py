@@ -12,7 +12,10 @@ existing ring-buffered blocked decode module over the slot axis**:
   leading ``(slots, ...)`` axis;
 - under ``jax.vmap`` the per-layer ``cursor``/``ring_base`` scalars become
   per-slot vectors — which is precisely the per-slot live-length tracking a
-  heterogeneous batch needs, with zero changes to the attention module;
+  heterogeneous batch needs. The attention module needed one change for
+  it: a single-token step appends its K/V row to the ring with a select
+  over the ring's rows, because ``dynamic_update_slice`` at a per-slot
+  offset is a scatter, which the TPU compiler runs as a loop over slots;
 - decode steps write each slot's ring; once per block the rings merge into
   the big caches at PER-SLOT offsets (``merge_ring_caches`` vmapped with a
   traced ``live``), and ``ring_base`` advances — the same amortization

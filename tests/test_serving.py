@@ -313,3 +313,119 @@ def test_sample_tokens_dynamic_heterogeneous_rows():
             logits[row][None], keys[row][None], temps[row][None],
             ks[row][None], ps[row][None])[0]
         assert int(batched[row]) == int(alone)
+
+
+# --- the ring append (a select, not a per-slot scatter) ----------------------
+
+def _equations(jaxpr):
+    """Every equation of ``jaxpr``, those of its sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+def _scatter_operands(jaxpr):
+    """Shape of the operand each scatter in ``jaxpr`` writes into."""
+    return [eqn.invars[0].aval.shape for eqn in _equations(jaxpr)
+            if eqn.primitive.name.startswith("scatter")]
+
+
+def test_decode_scan_appends_without_scatter(lm_and_params):
+    """A ``dynamic_update_slice`` at a per-slot offset under the pool's
+    ``vmap`` is a scatter, and the TPU compiler runs a scatter as a
+    sequential loop over the slots: two such loops a layer in every decode
+    step. The step's ring append is a select, so the scanned step scatters
+    into no cache leaf; the once-a-block merge, outside the scan, and the
+    sampler's mask over the logits are other mechanisms and stay."""
+    from distributed_ml_pytorch_tpu.serving.cache import _decode_block_jit
+
+    # what the walk has to catch: a batched offset turns the write into a scatter
+    ring = jnp.zeros((3, 1, 4, 4, 8))
+    batched = jax.make_jaxpr(jax.vmap(
+        lambda r, k, t: jax.lax.dynamic_update_slice(r, k, (0, 0, t, 0))))(
+            ring, jnp.ones((3, 1, 4, 1, 8)), jnp.arange(3))
+    assert _scatter_operands(batched.jaxpr) == [ring.shape]
+
+    pool = make_engine(lm_and_params).pool
+    S = pool.slots
+    jaxpr = jax.make_jaxpr(_decode_block_jit, static_argnums=(0,))(
+        pool.dec, pool.params, pool.cache,
+        jnp.zeros(S, jnp.int32), jnp.zeros(S, jnp.int32),
+        jnp.zeros(S, jnp.uint32), jnp.zeros(S, jnp.float32),
+        jnp.zeros(S, jnp.int32), jnp.ones(S, jnp.float32),
+        jnp.ones(S, bool)).jaxpr
+    scans = [eqn for eqn in _equations(jaxpr) if eqn.primitive.name == "scan"]
+    assert len(scans) == 1, "the decode block is one scan over its steps"
+    (step,) = jax.core.jaxprs_in_params(scans[0].params)
+    assert any(eqn.primitive.name == "dot_general" for eqn in _equations(step))
+    assert [shape for shape in _scatter_operands(step)
+            if shape != (S, VOCAB)] == []
+
+
+RING_T = 16
+
+
+@pytest.mark.parametrize("lanes", [1, 4])
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["bf16", "kv_quant"])
+@pytest.mark.parametrize("t", [0, 7, RING_T - 1])
+def test_ring_append_matches_dynamic_update_slice(t, kv_quant, lanes):
+    """One single-token apply leaves in each lane's ring exactly what
+    ``dynamic_update_slice`` of its K/V row at its own offset gives, bit
+    for bit, alone and under ``vmap`` with a different ``t`` in every lane."""
+    from flax import linen as nn
+
+    from distributed_ml_pytorch_tpu.models.transformer import (
+        MultiHeadAttention,
+    )
+
+    d_model, heads, T = 32, 4, RING_T
+    attn = MultiHeadAttention(
+        d_model, heads, dtype=jnp.bfloat16, decode=True, cache_size=64,
+        decode_block=T, kv_quant=kv_quant)
+    rng = np.random.default_rng(100 * t + 10 * kv_quant + lanes)
+    variables = attn.init(jax.random.key(1), jnp.zeros((1, 1, d_model)))
+    params = variables["params"]
+    ts = [(t + 5 * lane) % T for lane in range(lanes)]  # all different
+    base = 8  # a prompt of 8 rows sits in the big cache
+
+    def lane_cache(t_lane):
+        cache = dict(variables["cache"])
+        for name in ("ring_k", "ring_v"):  # stale rows of the last block
+            cache[name] = jnp.asarray(
+                rng.normal(size=cache[name].shape), jnp.bfloat16)
+        cache["ring_base"] = jnp.asarray(base, jnp.int32)
+        cache["cursor"] = jnp.asarray(base + t_lane, jnp.int32)
+        return cache
+
+    caches = [lane_cache(t_lane) for t_lane in ts]
+    xs = [jnp.asarray(rng.normal(size=(1, 1, d_model)), jnp.float32)
+          for _ in ts]
+
+    def apply(cache, x):
+        _, mutated = attn.apply(
+            {"params": params, "cache": cache}, x, mutable=["cache"])
+        return mutated["cache"]
+
+    if lanes == 1:
+        got = [apply(caches[0], xs[0])]
+    else:
+        stacked = jax.vmap(apply)(
+            jax.tree.map(lambda *leaves: jnp.stack(leaves), *caches),
+            jnp.stack(xs))
+        got = [jax.tree.map(lambda leaf: leaf[i], stacked)
+               for i in range(lanes)]
+
+    def row(name, x):  # the lane's K or V row, as the module computes it
+        dense = nn.Dense(d_model, use_bias=False, dtype=jnp.bfloat16)
+        y = dense.apply({"params": params[name]}, x)
+        return y.reshape(1, 1, heads, d_model // heads).transpose(0, 2, 1, 3)
+
+    for t_lane, before, x, after in zip(ts, caches, xs, got):
+        for ring, proj in (("ring_k", "k"), ("ring_v", "v")):
+            want = jax.lax.dynamic_update_slice(
+                before[ring], row(proj, x), (0, 0, t_lane, 0))
+            assert after[ring].dtype == jnp.bfloat16
+            np.testing.assert_array_equal(
+                np.asarray(after[ring]), np.asarray(want))
+        assert int(after["cursor"]) == base + t_lane + 1
