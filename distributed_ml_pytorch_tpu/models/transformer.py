@@ -127,6 +127,12 @@ class MultiHeadAttention(nn.Module):
     #: models/generate.py fuses trained q/k/v kernels on the fly
     #: (_fuse_qkv_params), so checkpoints stay in the unfused layout.
     fused_qkv: bool = False
+    #: RMSNorm (learned scale, modules ``q_norm`` and ``k_norm``) over the
+    #: whole q and k projections before they are split into heads: the QK-norm
+    #: of ``models/hybrid.py``'s full-attention layers. Cached keys are stored
+    #: normed.
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
 
     @nn.compact
     def __call__(self, x, positions=None):
@@ -134,13 +140,19 @@ class MultiHeadAttention(nn.Module):
         head_dim = self.d_model // self.n_heads
         proj = lambda name: nn.Dense(self.d_model, use_bias=False, dtype=self.dtype, name=name)
         split = lambda t: t.reshape(b, s, self.n_heads, head_dim).transpose(0, 2, 1, 3)
+        # the QK-norm goes between projection and split; without it ``normed``
+        # is the identity and the program is what it was before the option
+        normed = lambda name, t: t
+        if self.qk_norm:
+            normed = lambda name, t: t if name == "v" else nn.RMSNorm(
+                epsilon=self.norm_eps, dtype=self.dtype, name=f"{name}_norm")(t)
         if self.fused_qkv:
             qkv = nn.Dense(3 * self.d_model, use_bias=False, dtype=self.dtype,
                            name="qkv")(x)
-            q, k, v = (split(qkv[..., i * self.d_model:(i + 1) * self.d_model])
-                       for i in range(3))
+            q, k, v = (split(normed(n, qkv[..., i * self.d_model:(i + 1) * self.d_model]))
+                       for i, n in enumerate("qkv"))
         else:
-            q, k, v = (split(proj(n)(x)) for n in ("q", "k", "v"))
+            q, k, v = (split(normed(n, proj(n)(x))) for n in ("q", "k", "v"))
         if self.rope:
             if positions is None:
                 raise ValueError("rope=True needs the tokens' global positions")

@@ -1,4 +1,4 @@
-"""Slot-based KV cache pool — the serving data plane.
+"""Slot-based cache pool — the serving data plane.
 
 ``models/generate.py`` decodes ONE prompt batch: every sequence starts
 together, shares one cursor, and the whole cache dies with the call. A
@@ -34,6 +34,22 @@ past the prompt, but causal masking keeps real logits exact, the cursor is
 rewound to the true length, and the ``key_pos < ring_base`` mask hides the
 garbage until decode merges overwrite it.
 
+**What a slot holds.** Whatever the model's ``"cache"`` collection declares
+for one sequence. For ``TransformerLM`` that is K/V rows (big cache and ring)
+and two cursors a layer. A model with recurrent layers (``models/hybrid.py``)
+adds leaves that are not K/V: a linear-attention layer's ``state`` (float32,
+fixed size however long the sequence is) and ``conv_tail``. Nothing here
+branches on the model's kind; the leaves are told apart by name:
+``split_cache`` puts everything that is not a big K/V cache with the small
+leaves the decode scan carries (right for a state: every step rewrites it
+whole), the lane scatter and ``slot_kv`` take every leaf, and a freed slot's
+state is simply overwritten by the next occupant's fresh lane. The padded
+prefill is the one place a recurrence needs help: it has no mask to hide
+padding behind, so ``_admit_jit`` writes the prompt's true length into every
+cache leaf called ``prefill_len`` before the prefill (a tree without that leaf,
+``TransformerLM``'s, is untouched and compiles to what it did), and the mixer
+that declared the leaf keeps padded positions out of its state.
+
 Exactness contract (CPU): a request decoded through the pool picks
 token-for-token what a standalone ``generate()`` picks for the same
 ``(params, prompt, rng)`` — the attention math is the same module, the
@@ -61,6 +77,10 @@ from distributed_ml_pytorch_tpu.models.generate import (
     split_cache,
 )
 from distributed_ml_pytorch_tpu.utils.tracing import span
+
+
+#: the cache leaves that are K/V rows (big caches, rings, int8 scales)
+_KV_LEAVES = ("cached_k", "cached_v", "ring_k", "ring_v", "scale_k", "scale_v")
 
 
 def find_cache_leaf(tree, name: str):
@@ -113,6 +133,9 @@ def _admit_jit(dec, params, pool, slot, prompt, real_len, seed,
     token-identical even for sampled requests. A fresh admission passes 0.
     """
     lane = jax.tree.map(lambda x: jnp.zeros(x.shape[1:], x.dtype), pool)
+    # a recurrent mixer has no mask to hide the padding behind: it declares
+    # ``prefill_len`` and counts only that many positions into its state
+    lane = replace_cache_leaves(lane, {"prefill_len": real_len})
     bucket = prompt.shape[1]
     positions = jnp.arange(bucket)[None, :]
     logits, mutated = dec.apply(
@@ -138,8 +161,10 @@ def _admit_jit(dec, params, pool, slot, prompt, real_len, seed,
 @partial(jax.jit, donate_argnums=(0,))
 def _reset_slots_jit(pool, mask):
     """Zero the cursor/ring_base of every slot where ``mask`` is True: the
-    freed slot's cache contents become invisible (``key_pos < ring_base``)
-    and its live length reads 0 until the next admission overwrites it."""
+    freed slot's K/V rows become invisible (``key_pos < ring_base``) and its
+    live length reads 0 until the next admission overwrites it. A recurrent
+    state stays as it is: nothing reads a free slot's, and an admission
+    replaces the whole lane."""
 
     def walk(tree):
         out = {}
@@ -207,8 +232,11 @@ def _decode_block_jit(dec, params, pool, tok, n_gen, seeds,
 
 
 class SlotKVPool:
-    """Fixed-capacity pool of ``slots`` independent KV cache slots, each of
-    total length ``cache_size``, over the blocked decode module.
+    """Fixed-capacity pool of ``slots`` independent cache slots over the
+    blocked decode module. A slot holds one sequence's whole state: up to
+    ``cache_size`` K/V rows in every attention layer and, for a model with
+    recurrent layers, each such layer's fixed-size state beside them
+    (:meth:`slot_bytes`).
 
     The pool is the compiled data plane; the scheduler
     (``serving/engine.py``) owns which slot belongs to which request. All
@@ -299,11 +327,12 @@ class SlotKVPool:
         self.cache = _reset_slots_jit(self.cache, jnp.asarray(mask))
 
     def slot_kv(self, slot: int) -> np.ndarray:
-        """One slot's KV lane as a flat float32 vector (every floating
-        cache leaf's row for ``slot``, concatenated in tree order) — the
-        body a migration handoff ships on the ``KvMigrate`` wire (ISSUE
-        18). With ``kv_quant`` the leaves are already the int8+scale
-        recipe; the float32 view is the wire's common currency either way."""
+        """One slot's lane as a flat float32 vector (every floating cache
+        leaf's row for ``slot``, K/V and recurrent state alike, concatenated
+        in tree order) — the body a migration handoff ships on the
+        ``KvMigrate`` wire (ISSUE 18). With ``kv_quant`` the leaves are
+        already the int8+scale recipe; the float32 view is the wire's common
+        currency either way."""
         parts = [
             np.asarray(leaf[slot], np.float32).ravel()
             for leaf in jax.tree.leaves(self.cache)
@@ -311,6 +340,20 @@ class SlotKVPool:
         if not parts:
             return np.zeros(0, np.float32)
         return np.concatenate(parts)
+
+    def slot_bytes(self) -> dict:
+        """Bytes one slot holds, from the cache tree's leaves:
+        ``kv_bytes_per_slot`` (big caches, rings, int8 scales) and
+        ``state_bytes_per_slot`` (every other floating leaf: a recurrent
+        layer's state and convolution tail; 0 for an attention-only model)."""
+        kv = state = 0
+        for path, leaf in jax.tree_util.tree_leaves_with_path(self.cache):
+            size = leaf.dtype.itemsize * int(np.prod(leaf.shape[1:]))
+            if path[-1].key in _KV_LEAVES:
+                kv += size
+            elif jnp.issubdtype(leaf.dtype, jnp.floating):
+                state += size
+        return {"kv_bytes_per_slot": kv, "state_bytes_per_slot": state}
 
     def live_lengths(self) -> np.ndarray:
         """Per-slot live sequence length (prompt + generated), from the
@@ -325,8 +368,10 @@ class SlotKVPool:
 
     def capacity_needed(self, prompt_len: int, bucket_len: int,
                         max_new_tokens: int) -> int:
-        """Cache rows the request can touch: the padded prefill writes up to
-        ``bucket_len``, and block-granular decode writes merges from the
-        true prompt length through the rounded-up tail block."""
+        """K/V rows the request can touch in each attention layer: the padded
+        prefill writes up to ``bucket_len``, and block-granular decode writes
+        merges from the true prompt length through the rounded-up tail block.
+        Recurrent layers bound nothing: their state has one size whatever the
+        sequence's length."""
         decoded = self.blocks_needed(max_new_tokens) * self.decode_block
         return max(bucket_len, prompt_len + decoded)

@@ -9,6 +9,11 @@ over a messaging transport::
     # restore trained params (examples/train_lm.py checkpoint)
     python -m distributed_ml_pytorch_tpu.serving.cli --ckpt-dir /tmp/lm ...
 
+    # a model from a published configuration file (a Hugging Face
+    # ``config.json``; ``model_type`` ``olmo_hybrid`` builds
+    # ``models/hybrid.HybridLM``), seeded random weights
+    python -m distributed_ml_pytorch_tpu.serving.cli --model-config config.json --demo 4
+
     # self-contained demo: an in-process client drives N mixed
     # greedy/sampled requests through the full frontend path, prints the
     # SLO summary, exits (what the CLI tests run)
@@ -45,6 +50,10 @@ def build_parser() -> argparse.ArgumentParser:
                    choices=["float32", "bfloat16"])
     p.add_argument("--pos-encoding", default="learned",
                    choices=["learned", "rope"])
+    p.add_argument("--model-config", type=str, default="", metavar="PATH",
+                   help="build the model from a published config.json "
+                        "(model_type olmo_hybrid -> models/hybrid.HybridLM) "
+                        "instead of the size flags above; --dtype applies")
     p.add_argument("--ckpt-dir", type=str, default="",
                    help="restore params from an examples/train_lm.py orbax "
                         "checkpoint (default: fresh random init)")
@@ -128,6 +137,14 @@ def _build_model(args, parser):
 
     from distributed_ml_pytorch_tpu.models import TransformerLM
 
+    dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
+    if args.model_config:
+        if args.ckpt_dir:
+            parser.error("--model-config builds seeded weights; it takes no --ckpt-dir")
+        lm = _model_from_config(args.model_config, dtype, parser)
+        params = lm.init(
+            jax.random.key(args.seed), jnp.zeros((1, 8), jnp.int32))["params"]
+        return lm, params
     if args.d_model % args.n_heads:
         parser.error(f"--d-model {args.d_model} must divide by --n-heads "
                      f"{args.n_heads}")
@@ -135,8 +152,7 @@ def _build_model(args, parser):
         vocab_size=args.vocab, d_model=args.d_model, n_heads=args.n_heads,
         n_layers=args.n_layers, d_ff=args.d_ff,
         max_len=args.max_len or max(args.cache_size, 256),
-        dtype=jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32,
-        pos_encoding=args.pos_encoding,
+        dtype=dtype, pos_encoding=args.pos_encoding,
     )
     if not args.ckpt_dir:
         params = lm.init(
@@ -162,6 +178,22 @@ def _build_model(args, parser):
             params = state.params
             print(f"restored params from step {step} of {args.ckpt_dir}")
     return lm, params
+
+
+def _model_from_config(path: str, dtype, parser):
+    """The model class a published ``config.json`` names by ``model_type``."""
+    import json
+
+    from distributed_ml_pytorch_tpu.models.hybrid import HybridLM
+
+    builders = {"olmo_hybrid": HybridLM.from_config}
+    with open(path) as fh:
+        cfg = json.load(fh)
+    kind = cfg.get("model_type")
+    if kind not in builders:
+        parser.error(f"--model-config: model_type {kind!r} is not one of "
+                     f"{sorted(builders)}")
+    return builders[kind](cfg, dtype=dtype)
 
 
 def _make_engine(lm, params, args):

@@ -29,7 +29,8 @@ latency is the block time divided by the block's tokens.
 
 Tracing: every round writes ``serve.*`` spans through
 ``utils/tracing.span`` (``serve.step`` > ``serve.prefill`` (one a request,
-with ``request_id`` and ``queue_wait_us``), ``serve.decode`` >
+with ``request_id``, ``queue_wait_us``, ``prompt_tokens`` and
+``bucket_tokens``), ``serve.decode`` >
 ``serve.decode.dispatch|fetch``, ``serve.emit``) into whatever profile is
 being taken, on the device trace's clock, and costs an object construction
 each when none is. Each is read by a per-layer metric of the benchmark
@@ -139,7 +140,11 @@ def _round_up(n: int, multiple: int) -> int:
 
 
 class ServingEngine:
-    """Slot-based continuous-batching engine over one ``TransformerLM``.
+    """Slot-based continuous-batching engine over one LM (``TransformerLM``
+    or ``models/hybrid.HybridLM``). A slot holds one request's whole state
+    for as long as it decodes: K/V rows in the attention layers and, in a
+    model that has them, each recurrent layer's fixed-size state; the schedule
+    is the same for both.
 
     ``on_tokens(request, new_tokens, done)`` is invoked from the scheduling
     thread every time a request's stream advances (admission's first token,
@@ -189,6 +194,8 @@ class ServingEngine:
         self._completed = 0
         self._cancelled = 0
         self._rejected = 0
+        # prompt tokens admitted, and bucket positions the padding added
+        self._prefill_tokens = {"real": 0, "padded": 0}
 
     # ------------------------------------------------------------- submit
     def submit(self, prompt, max_new_tokens: int, *,
@@ -217,11 +224,14 @@ class ServingEngine:
         need = self.pool.capacity_needed(int(prompt.size), bucket,
                                          int(max_new_tokens))
         if need > self.pool.cache_size:
+            recurrent = self.pool.slot_bytes()["state_bytes_per_slot"] > 0
             raise ValueError(
                 f"request needs {need} cache rows (prompt {prompt.size} "
                 f"-> bucket {bucket}, {max_new_tokens} new tokens in "
                 f"{self.pool.decode_block}-token blocks) but slots hold "
-                f"{self.pool.cache_size}")
+                f"{self.pool.cache_size}"
+                + (" in each full-attention layer (the recurrent layers' "
+                   "state sets no bound)" if recurrent else ""))
         from distributed_ml_pytorch_tpu.utils import obs
 
         req = Request(
@@ -349,10 +359,11 @@ class ServingEngine:
             req.t_admit = time.perf_counter()
             wait = req.t_admit - req.t_submit
             self._queue_wait.append(wait)
+            p = int(req.prompt.size)
+            bucket = self._bucket_len(p)
             with span("serve.prefill", request_id=req.request_id,
-                      queue_wait_us=int(wait * 1e6)):
-                p = int(req.prompt.size)
-                bucket = self._bucket_len(p)
+                      queue_wait_us=int(wait * 1e6), prompt_tokens=p,
+                      bucket_tokens=bucket):
                 padded = np.zeros(bucket, np.int32)
                 padded[:p] = req.prompt
                 sp = req.sampling
@@ -371,6 +382,8 @@ class ServingEngine:
                     temperature=sp.temperature, top_k=sp.top_k,
                     top_p=sp.top_p, gen_offset=req.gen_offset)
                 self._tok[slot] = tok0
+                self._prefill_tokens["real"] += p
+                self._prefill_tokens["padded"] += bucket - p
                 # the per-slot sampling clock continues the request's OWN
                 # schedule: a resumed request's next draw is fold_in(key,
                 # gen_offset + 1), exactly what its first life would have
@@ -502,6 +515,7 @@ class ServingEngine:
         self._completed = 0
         self._cancelled = 0
         self._rejected = 0
+        self._prefill_tokens = {"real": 0, "padded": 0}
 
     def slo_summary(self) -> dict:
         """Percentile SLO report (milliseconds) over everything completed so
@@ -527,4 +541,8 @@ class ServingEngine:
             "queue_depth": {"mean": float(np.mean(depths)),
                             "max": int(np.max(depths))},
             "slot_occupancy": float(np.mean(self._occupancy or [0.0])),
+            # prompt tokens admitted, and bucket positions their padding added
+            "prefill_tokens": dict(self._prefill_tokens),
+            # what one slot holds: K/V rows, and recurrent state beside them
+            "pool": self.pool.slot_bytes(),
         }

@@ -85,8 +85,12 @@ def test_a_requests_prefill_carries_its_id_and_its_wait_in_the_queue(traced):
     prefills = {s[4]["request_id"]: s for s in spans if s[0] == "serve.prefill"}
     assert set(prefills) == {r.request_id for r in requests}
     for r in requests:
-        assert set(prefills[r.request_id][4]) == {"request_id", "queue_wait_us"}
-        assert prefills[r.request_id][4]["queue_wait_us"] == int((r.t_admit - r.t_submit) * 1e6)
+        attrs = prefills[r.request_id][4]
+        assert set(attrs) == {"request_id", "queue_wait_us", "prompt_tokens", "bucket_tokens"}
+        assert attrs["queue_wait_us"] == int((r.t_admit - r.t_submit) * 1e6)
+        # what the admission padded: the prompt's length and its bucket's
+        assert attrs["prompt_tokens"] == r.prompt.size <= attrs["bucket_tokens"]
+        assert attrs["bucket_tokens"] == _engine._bucket_len(r.prompt.size)
         assert r.t_submit <= r.t_admit <= r.t_first_token <= r.t_done
     # five requests into two slots: the later ones waited for a slot, through
     # at least one decode block

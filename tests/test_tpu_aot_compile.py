@@ -140,6 +140,34 @@ def test_decode_attention_kernel_compiles(v5e, quant):
     assert_kernels(compiled_text(decode_attention_step, *args))
 
 
+@pytest.mark.parametrize("form", ["chunked", "step"])
+def test_gated_delta_rule_compiles_at_the_published_sizes(v5e, form):
+    """``ops/gated_delta.py`` is ``jax.numpy`` (no kernel to find in the
+    text): what is held is that XLA's TPU backend takes both forms at
+    Olmo-Hybrid-7B's sizes (30 heads, key 96, value 192): the chunkwise
+    prefill of a 1,024-token bucket with its batched inverse by doubling, and
+    one decode step over 32 slots."""
+    from distributed_ml_pytorch_tpu.ops.gated_delta import (
+        gated_delta_chunked,
+        gated_delta_step,
+    )
+
+    one = SingleDeviceSharding(v5e[0])
+    h, dk, dv = 30, 96, 192
+    if form == "chunked":
+        b, t = 1, 1024
+        args = [on(one, (b, t, h, dk)), on(one, (b, t, h, dk)), on(one, (b, t, h, dv)),
+                on(one, (b, t, h)), on(one, (b, t, h)), on(one, (b, h, dv, dk)),
+                on(one, (), jnp.int32)]
+        compiled = jax.jit(gated_delta_chunked).lower(*args).compile()
+    else:
+        s = 32
+        args = [on(one, (s, h, dk)), on(one, (s, h, dk)), on(one, (s, h, dv)),
+                on(one, (s, h)), on(one, (s, h)), on(one, (s, h, dv, dk))]
+        compiled = jax.jit(gated_delta_step).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 # ---------------------------------------------------------- whole programs
 
 
