@@ -93,12 +93,22 @@ def sample_tokens(
     return jax.random.categorical(rng, logits, axis=-1)
 
 
+def sampled_and_filtered_rows(temperature, top_k, top_p, live):
+    """``(sampled, filtered)`` row masks of a batch, numpy or jax arrays
+    alike: the ``live`` rows with a temperature, and those of them that ask
+    for top-k or top-p. THE statement of what ``sample_tokens_dynamic``'s
+    conditional goes by; the serving engine counts its blocks by the same."""
+    sampled = live & (temperature > 0.0)
+    return sampled, sampled & ((top_k > 0) | (top_p < 1.0))
+
+
 def sample_tokens_dynamic(
     logits: jnp.ndarray,
     rngs: jax.Array,
     temperature: jnp.ndarray,
     top_k: jnp.ndarray,
     top_p: jnp.ndarray,
+    live: jnp.ndarray,
 ) -> jnp.ndarray:
     """Per-row sampling over ``[B, vocab]`` logits with PER-ROW params.
 
@@ -111,13 +121,42 @@ def sample_tokens_dynamic(
     (tested), because the masking math mirrors it op-for-op and a
     categorical draw over ``[vocab]`` consumes the same random bits as one
     over ``[1, vocab]``. ``temperature <= 0`` rows are greedy argmax.
+
+    **What a call costs follows the batch**, by one ``lax.switch`` on a
+    scalar computed from the traced parameters, taken outside the per-row
+    ``vmap`` (under ``vmap`` a conditional is a select and every side runs):
+
+    0. no row is sampled: an argmax over the logits and nothing else;
+    1. some row is sampled, none of those asks for a filter (``top_k > 0``
+       or ``top_p < 1``): besides, the scaling and one categorical draw a
+       row (random bits for every logit);
+    2. a sampled row asks for a filter: besides, a descending sort, a
+       softmax and a cumulative sum over the whole ``[B, vocab]`` plane,
+       for every row of the batch.
+
+    Each tier returns for every row what tier 2 returns (tested bit for bit
+    on CPU): a filter that is off leaves the scaled logits untouched, and a
+    greedy row takes its argmax by the final select. ``live`` (``[B]`` bool)
+    names the rows whose token somebody reads; the others' parameters do not
+    raise the tier (``sampled_and_filtered_rows``), so a free slot that still
+    holds an evicted request's top-p does not make the pool sort.
     """
     vocab = logits.shape[-1]
     neg = jnp.asarray(jnp.finfo(logits.dtype).min, logits.dtype)
+    temperature = jnp.asarray(temperature, jnp.float32)
+    top_k = jnp.asarray(top_k, jnp.int32)
+    top_p = jnp.asarray(top_p, jnp.float32)
 
-    def one(lg, key, t, k, p):
+    def scale(lg, t):
+        return lg / jnp.where(t > 0.0, t, 1.0).astype(lg.dtype)
+
+    def draw(lg, key, t):
+        sampled = jax.random.categorical(key, scale(lg, t), axis=-1)
+        return jnp.where(t > 0.0, sampled, jnp.argmax(lg, axis=-1))
+
+    def filter_and_draw(lg, key, t, k, p):
         greedy = jnp.argmax(lg, axis=-1)
-        scaled = lg / jnp.where(t > 0.0, t, 1.0).astype(lg.dtype)
+        scaled = scale(lg, t)
         # ONE descending sort serves both filters (same as sample_tokens);
         # the filters gate on their own params so off rows pass through
         sort_desc = jnp.sort(scaled, axis=-1)[::-1]
@@ -135,12 +174,17 @@ def sample_tokens_dynamic(
         sampled = jax.random.categorical(key, scaled, axis=-1)
         return jnp.where(t > 0.0, sampled, greedy)
 
-    return jax.vmap(one)(
-        logits, rngs,
-        jnp.asarray(temperature, jnp.float32),
-        jnp.asarray(top_k, jnp.int32),
-        jnp.asarray(top_p, jnp.float32),
-    )
+    with jax.named_scope("sample"):  # one path in a device trace
+        sampled_rows, filtered_rows = sampled_and_filtered_rows(
+            temperature, top_k, top_p, live)
+        tier = (jnp.any(sampled_rows).astype(jnp.int32)
+                + jnp.any(filtered_rows).astype(jnp.int32))
+        return jax.lax.switch(tier, (
+            lambda: jnp.argmax(logits, axis=-1),
+            lambda: jax.vmap(draw)(logits, rngs, temperature),
+            lambda: jax.vmap(filter_and_draw)(
+                logits, rngs, temperature, top_k, top_p),
+        ))
 
 
 def _fuse_qkv_params(params, name: str = ""):

@@ -150,7 +150,8 @@ def _admit_jit(dec, params, pool, slot, prompt, real_len, seed,
     keys = jax.vmap(
         lambda s: jax.random.fold_in(jax.random.key(s), gen_offset))(seed[None])
     tok0 = sample_tokens_dynamic(
-        last[None], keys, temperature[None], top_k[None], top_p[None])[0]
+        last[None], keys, temperature[None], top_k[None], top_p[None],
+        jnp.ones((1,), bool))[0]
     pool = jax.tree.map(
         lambda P, L: jax.lax.dynamic_update_slice(
             P, L[None], (slot,) + (0,) * L.ndim),
@@ -194,7 +195,9 @@ def _decode_block_jit(dec, params, pool, tok, n_gen, seeds,
     re-zeroed on exit so their cursors never creep toward the cache edge.
     Token ``g`` of a request is sampled with ``fold_in(key(seed), g)`` —
     the same per-step key schedule ``generate()`` uses, which is what makes
-    engine output bit-match a standalone ``generate`` call on CPU.
+    engine output bit-match a standalone ``generate`` call on CPU. The
+    sampler does what the ``active`` rows' parameters ask for and no more
+    (``sample_tokens_dynamic``): a free slot's stale parameters cost nothing.
     """
     T = dec.decode_block
     big, small = split_cache(pool)
@@ -215,7 +218,7 @@ def _decode_block_jit(dec, params, pool, tok, n_gen, seeds,
         keys = jax.vmap(
             lambda s, i: jax.random.fold_in(jax.random.key(s), i))(seeds, g)
         nxt = sample_tokens_dynamic(
-            logits, keys, temps, top_ks, top_ps).astype(jnp.int32)
+            logits, keys, temps, top_ks, top_ps, active).astype(jnp.int32)
         return (small, nxt, g + 1), nxt
 
     (small, _, _), toks = jax.lax.scan(
@@ -242,7 +245,13 @@ class SlotKVPool:
     (``serving/engine.py``) owns which slot belongs to which request. All
     per-request sampling state (seed/temperature/top-k/top-p) is traced, so
     one compiled block program serves any mix of greedy and sampled
-    requests.
+    requests. What a step of it pays for sampling follows the mix, by a
+    conditional inside the program (``sample_tokens_dynamic``): an argmax
+    while every active slot is greedy; random bits for every logit of every
+    slot once one active slot has a temperature; a sort, a softmax and a
+    cumulative sum over ``slots x vocab`` besides once one of those asks for
+    top-k or top-p. One sampled request costs every slot that tier for as
+    long as it is active, and nothing after its eviction.
     """
 
     def __init__(self, model, params, *, slots: int, cache_size: int,

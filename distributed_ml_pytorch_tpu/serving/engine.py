@@ -57,7 +57,10 @@ from typing import Callable, Deque, List, Optional, Tuple
 
 import numpy as np
 
-from distributed_ml_pytorch_tpu.models.generate import DECODE_BLOCK
+from distributed_ml_pytorch_tpu.models.generate import (
+    DECODE_BLOCK,
+    sampled_and_filtered_rows,
+)
 from distributed_ml_pytorch_tpu.serving.cache import SlotKVPool
 from distributed_ml_pytorch_tpu.utils.metrics import latency_summary
 from distributed_ml_pytorch_tpu.utils.tracing import StepTimer, span
@@ -196,6 +199,10 @@ class ServingEngine:
         self._rejected = 0
         # prompt tokens admitted, and bucket positions the padding added
         self._prefill_tokens = {"real": 0, "padded": 0}
+        # decode blocks dispatched, and those whose active rows made the
+        # sampler draw (a temperature) or sort (top-k / top-p besides)
+        self._sampler_blocks = {
+            "blocks": 0, "sampled_blocks": 0, "filtered_blocks": 0}
 
     # ------------------------------------------------------------- submit
     def submit(self, prompt, max_new_tokens: int, *,
@@ -410,6 +417,13 @@ class ServingEngine:
             self._between.append(gap)
             if gap > self._between_max[0]:
                 self._between_max = (gap, max(self._gap, key=self._gap.get))
+        # the tier the compiled sampler takes for this block, by the
+        # sampler's own predicate over the active rows' parameters
+        sampled, filtered = sampled_and_filtered_rows(
+            self._temps, self._top_ks, self._top_ps, active)
+        self._sampler_blocks["blocks"] += 1
+        self._sampler_blocks["sampled_blocks"] += bool(sampled.any())
+        self._sampler_blocks["filtered_blocks"] += bool(filtered.any())
         with span("serve.decode"):
             self._block_timer.start()
             toks = self.pool.decode_block_step(
@@ -516,6 +530,8 @@ class ServingEngine:
         self._cancelled = 0
         self._rejected = 0
         self._prefill_tokens = {"real": 0, "padded": 0}
+        self._sampler_blocks = {
+            "blocks": 0, "sampled_blocks": 0, "filtered_blocks": 0}
 
     def slo_summary(self) -> dict:
         """Percentile SLO report (milliseconds) over everything completed so
@@ -543,6 +559,10 @@ class ServingEngine:
             "slot_occupancy": float(np.mean(self._occupancy or [0.0])),
             # prompt tokens admitted, and bucket positions their padding added
             "prefill_tokens": dict(self._prefill_tokens),
+            # decode blocks, those in which an active request was sampled
+            # (the sampler drew random bits), and those of them in which one
+            # asked for top-k or top-p (it sorted the vocabulary besides)
+            "sampler": dict(self._sampler_blocks),
             # what one slot holds: K/V rows, and recurrent state beside them
             "pool": self.pool.slot_bytes(),
         }

@@ -276,26 +276,71 @@ def test_live_lengths_track_slot_progress(lm_and_params):
     assert eng.pool.live_lengths().max() == 0  # everything evicted + reset
 
 
-def test_sample_tokens_dynamic_matches_scalar_rowwise():
+#: batches of (temperature, top_k, top_p) rows, one for each amount of work
+#: the sampler's conditional can choose and for the mixes between them
+SAMPLER_BATCHES = {
+    "all_greedy": [(0.0, 0, 1.0), (0.0, 5, 1.0), (-1.0, 0, 0.5), (0.0, 3, 0.7)],
+    "temperature_only": [(1.0, 0, 1.0), (0.7, 0, 1.0), (1.3, 0, 1.0)],
+    "top_k_only": [(0.7, 5, 1.0), (0.5, 1, 1.0), (1.1, VOCAB + 10, 1.0)],
+    "top_p_only": [(0.7, 0, 0.9), (1.0, 0, 0.5), (0.9, 0, 0.0)],
+    "mixed_with_greedy": [(0.0, 0, 1.0), (0.8, 0, 1.0), (0.0, 4, 0.6),
+                          (1.2, 0, 0.8), (0.6, 3, 1.0)],
+    "k_and_p_together": [(1.3, 8, 0.85), (0.9, VOCAB + 10, 0.5), (0.6, 3, 0.7)],
+    "temperature_beside_greedy": [(0.0, 0, 1.0), (0.9, 0, 1.0), (0.0, 7, 0.3)],
+}
+
+
+@pytest.mark.parametrize("batch", list(SAMPLER_BATCHES))
+def test_sample_tokens_dynamic_matches_scalar_rowwise(batch):
     """The traced-params sampler must agree bit-for-bit with sample_tokens
     for every configuration a request can carry (greedy, temp-only, top-k,
-    top-p, combined) — this equivalence is what lets one compiled block
-    program serve heterogeneous sampling params."""
+    top-p, combined), in whatever batch it sits — this equivalence is what
+    lets one compiled block program serve heterogeneous sampling params,
+    and it must hold in each tier of the sampler's conditional: the batch as
+    a whole decides how much of the work runs, never what a row gets."""
+    rows = SAMPLER_BATCHES[batch]
+    n = len(rows)
     logits = jnp.asarray(
-        np.random.default_rng(0).normal(size=(5, VOCAB)) * 2.0, jnp.float32)
-    configs = [
-        (0.0, 0, 1.0), (1.0, 0, 1.0), (0.7, 5, 1.0), (0.7, 0, 0.9),
-        (1.3, 8, 0.85), (0.5, 1, 1.0), (0.9, VOCAB + 10, 0.5),
-    ]
-    for i, (t, k, p) in enumerate(configs):
-        key = jax.random.key(100 + i)
-        for row in range(logits.shape[0]):
-            want = sample_tokens(
-                logits[row][None], key, temperature=t, top_k=k, top_p=p)[0]
-            got = sample_tokens_dynamic(
-                logits[row][None], key[None],
-                jnp.asarray([t]), jnp.asarray([k]), jnp.asarray([p]))[0]
-            assert int(got) == int(want), (t, k, p, row)
+        np.random.default_rng(len(batch)).normal(size=(n, VOCAB)) * 2.0,
+        jnp.float32)
+    keys = jax.vmap(jax.random.key)(jnp.arange(100, 100 + n, dtype=jnp.uint32))
+    temps, ks, ps = (jnp.asarray(col) for col in zip(*rows))
+    got = sample_tokens_dynamic(logits, keys, temps, ks, ps, jnp.ones(n, bool))
+    assert got.shape == (n,) and got.dtype == jnp.int32
+    for row, (t, k, p) in enumerate(rows):
+        want = sample_tokens(
+            logits[row][None], keys[row], temperature=t, top_k=k, top_p=p)[0]
+        assert int(got[row]) == int(want), (batch, row, t, k, p)
+        alone = sample_tokens_dynamic(
+            logits[row][None], keys[row][None], temps[row][None],
+            ks[row][None], ps[row][None], jnp.ones(1, bool))[0]
+        assert int(alone) == int(want), (batch, row, t, k, p)
+
+
+def test_sampler_tier_follows_the_rows_somebody_reads(monkeypatch):
+    """The conditional's index is 0 while no live row has a temperature, 1
+    while none of those asks for top-k or top-p, else 2. ``live`` names the
+    rows whose token is read: a dead row's stale top-p must not make the
+    batch sort, and a live row's token does not depend on ``live`` at all."""
+    taken, switch = [], jax.lax.switch
+    monkeypatch.setattr(  # eager calls: the index is a concrete scalar
+        jax.lax, "switch",
+        lambda index, *rest: taken.append(int(index)) or switch(index, *rest))
+    rng = np.random.default_rng(7)
+    logits = jnp.asarray(rng.normal(size=(3, VOCAB)), jnp.float32)
+    keys = jax.vmap(jax.random.key)(jnp.arange(3, dtype=jnp.uint32))
+    temps, ks, ps = (jnp.asarray([0.0, 0.9, 0.7]), jnp.asarray([0, 0, 4]),
+                     jnp.asarray([1.0, 1.0, 0.6]))
+    every = sample_tokens_dynamic(logits, keys, temps, ks, ps, jnp.ones(3, bool))
+    assert taken == [2]
+    for live, tier in (([True, True, True], 2), ([False, False, True], 2),
+                       ([True, True, False], 1), ([True, False, False], 0),
+                       ([False, False, False], 0)):
+        got = sample_tokens_dynamic(
+            logits, keys, temps, ks, ps, jnp.asarray(live))
+        assert taken[-1] == tier, live
+        assert [int(got[r]) for r in range(3) if live[r]] == [
+            int(every[r]) for r in range(3) if live[r]], live
 
 
 def test_sample_tokens_dynamic_heterogeneous_rows():
@@ -307,12 +352,85 @@ def test_sample_tokens_dynamic_heterogeneous_rows():
     temps = jnp.asarray([0.0, 0.8, 1.2, 0.6])
     ks = jnp.asarray([0, 5, 0, 3])
     ps = jnp.asarray([1.0, 1.0, 0.8, 0.7])
-    batched = sample_tokens_dynamic(logits, keys, temps, ks, ps)
+    batched = sample_tokens_dynamic(logits, keys, temps, ks, ps, jnp.ones(4, bool))
     for row in range(4):
         alone = sample_tokens_dynamic(
             logits[row][None], keys[row][None], temps[row][None],
-            ks[row][None], ps[row][None])[0]
+            ks[row][None], ps[row][None], jnp.ones(1, bool))[0]
         assert int(batched[row]) == int(alone)
+
+
+# --- the sampler does what the ACTIVE rows ask for ----------------------------
+
+def test_free_slots_stale_sampling_params_cost_and_change_nothing(lm_and_params):
+    """The engine never clears a slot's temperature / top-k / top-p at
+    eviction. The decode block masks the sampler's tier by ``active``, so
+    what a free slot still holds is not read: the block returns for every
+    slot, the free ones' garbage included, what it returns with those
+    parameters cleared (a free row is sampled only if the tier was raised).
+    The same parameters on an ACTIVE slot do change that slot's tokens."""
+    def block(stale, active):
+        eng = make_engine(lm_and_params)
+        eng.submit(np.arange(1, 6), 30)
+        eng.step()  # slot 0 is live and one block in; slots 1, 2 never used
+        temps, ks, ps = eng._temps.copy(), eng._top_ks.copy(), eng._top_ps.copy()
+        if stale:
+            temps[1:], ks[2], ps[1] = (5.0, 3.0), 3, 0.5
+        return eng.pool.decode_block_step(
+            eng._tok, eng._n_gen, eng._seeds, temps, ks, ps,
+            np.asarray(active, bool))
+
+    only_first = [True, False, False]
+    clean = block(False, only_first)
+    np.testing.assert_array_equal(block(True, only_first), clean)
+    everyone = block(True, [True, True, True])
+    np.testing.assert_array_equal(everyone[0], clean[0])
+    assert (everyone[1:] != block(False, [True, True, True])[1:]).any()
+
+
+def test_sampler_counter_and_greedy_tokens_beside_a_top_p_request(lm_and_params):
+    """``slo_summary()["sampler"]`` counts the decode blocks whose ACTIVE
+    requests made the sampler draw, and filter: nonzero while a top-p
+    request is live, and unchanged over the all-greedy blocks after its
+    eviction though its slot's parameters are still in the engine's mirror.
+    A greedy request's tokens are the same alone and beside it."""
+    model, params = lm_and_params
+    prompt = prompts_rng(5).integers(0, VOCAB, size=6)
+    alone = make_engine(lm_and_params)
+    want = alone.submit(prompt, 30)
+    alone.run_until_idle()
+    assert alone.slo_summary()["sampler"] == {
+        "blocks": 8, "sampled_blocks": 0, "filtered_blocks": 0}
+
+    eng = make_engine(lm_and_params)
+    greedy = eng.submit(prompt, 30)
+    sampled = eng.submit(prompts_rng(6).integers(0, VOCAB, size=7), 6,
+                         temperature=0.8, top_p=0.7, seed=3)
+    while eng._slot_req[1] is not sampled:
+        eng.step()
+    while eng._slot_req[1] is sampled:  # ... until its eviction
+        eng.step()
+    # six tokens are an admission and two blocks of four; the round that
+    # evicted it went on to decode the first all-greedy block
+    after = eng.slo_summary()["sampler"]
+    assert after == {"blocks": 3, "sampled_blocks": 2, "filtered_blocks": 2}
+    assert eng._top_ps[1] < 1.0 and eng._temps[1] > 0.0  # stale, in a free slot
+    eng.run_until_idle()
+    end = eng.slo_summary()["sampler"]
+    assert end["blocks"] > after["blocks"]
+    assert end["filtered_blocks"] == after["filtered_blocks"]
+    assert end["sampled_blocks"] == after["sampled_blocks"]
+    assert greedy.tokens == want.tokens
+    assert sampled.tokens == ref_tokens(
+        model, params, sampled.prompt, 6, temperature=0.8, top_p=0.7,
+        rng=jax.random.key(3))
+
+    # a temperature alone draws and does not filter
+    eng.reset_metrics()
+    eng.submit(prompt, 5, temperature=0.9, seed=1)
+    eng.run_until_idle()
+    assert eng.slo_summary()["sampler"] == {
+        "blocks": 1, "sampled_blocks": 1, "filtered_blocks": 0}
 
 
 # --- the ring append (a select, not a per-slot scatter) ----------------------
@@ -337,9 +455,8 @@ def test_decode_scan_appends_without_scatter(lm_and_params):
     sequential loop over the slots: two such loops a layer in every decode
     step. The step's ring append is a select, so the scanned step scatters
     into no cache leaf; the once-a-block merge, outside the scan, and the
-    sampler's mask over the logits are other mechanisms and stay."""
-    from distributed_ml_pytorch_tpu.serving.cache import _decode_block_jit
-
+    sampler's mask over the logits, inside the one branch of its conditional
+    that filters, are other mechanisms and stay."""
     # what the walk has to catch: a batched offset turns the write into a scatter
     ring = jnp.zeros((3, 1, 4, 4, 8))
     batched = jax.make_jaxpr(jax.vmap(
@@ -348,6 +465,17 @@ def test_decode_scan_appends_without_scatter(lm_and_params):
     assert _scatter_operands(batched.jaxpr) == [ring.shape]
 
     pool = make_engine(lm_and_params).pool
+    step = _scanned_step(pool)
+    assert any(eqn.primitive.name == "dot_general" for eqn in _equations(step))
+    *_, filtered = _sampler_branches(step)
+    assert _scatter_operands(filtered) == [(pool.slots, VOCAB)]
+    assert len(_scatter_operands(step)) == 1  # that one, and no other
+
+
+def _scanned_step(pool):
+    """The jaxpr of one step of ``_decode_block_jit``'s scan for ``pool``."""
+    from distributed_ml_pytorch_tpu.serving.cache import _decode_block_jit
+
     S = pool.slots
     jaxpr = jax.make_jaxpr(_decode_block_jit, static_argnums=(0,))(
         pool.dec, pool.params, pool.cache,
@@ -358,9 +486,40 @@ def test_decode_scan_appends_without_scatter(lm_and_params):
     scans = [eqn for eqn in _equations(jaxpr) if eqn.primitive.name == "scan"]
     assert len(scans) == 1, "the decode block is one scan over its steps"
     (step,) = jax.core.jaxprs_in_params(scans[0].params)
-    assert any(eqn.primitive.name == "dot_general" for eqn in _equations(step))
-    assert [shape for shape in _scatter_operands(step)
-            if shape != (S, VOCAB)] == []
+    return step
+
+
+def _sampler_branches(step):
+    """The branches of the scanned step's one conditional, the sampler's, in
+    the order of its index: all greedy, a draw, a filter and a draw."""
+    conds = [eqn for eqn in _equations(step) if eqn.primitive.name == "cond"]
+    assert len(conds) == 1, "the step's one conditional is the sampler's"
+    return [branch.jaxpr for branch in conds[0].params["branches"]]
+
+
+def _count(jaxpr, *primitives):
+    return sum(eqn.primitive.name in primitives for eqn in _equations(jaxpr))
+
+
+def test_decode_step_sorts_and_draws_only_in_the_branches_that_need_to(
+        lm_and_params):
+    """A decode step of an all-greedy pool pays for an argmax: the sort, the
+    cumulative sum and the random bits each sit inside a branch of ONE
+    conditional outside the per-row ``vmap`` (under ``vmap`` a conditional
+    is a select and every side runs), and the branch an all-greedy batch
+    takes holds none of them."""
+    step = _scanned_step(make_engine(lm_and_params).pool)
+    greedy, drawn, filtered = _sampler_branches(step)
+    costly = ("sort", "cumsum", "random_bits")
+    assert _count(greedy, *costly) == 0
+    assert _count(greedy, "argmax") == 1
+    assert _count(drawn, "sort", "cumsum") == 0
+    assert _count(drawn, "random_bits") == 1
+    assert (_count(filtered, "sort"), _count(filtered, "cumsum"),
+            _count(filtered, "random_bits")) == (1, 1, 1)
+    # and nowhere else in the step
+    assert _count(step, *costly) == _count(drawn, *costly) + _count(
+        filtered, *costly)
 
 
 RING_T = 16
