@@ -68,12 +68,6 @@ serve-fleet-demo:
 bench:
 	$(PY) bench.py
 
-bench-serving:
-	$(PY) bench_serving.py
-
-bench-all:
-	$(PY) bench_all.py
-
 # the quickest proof that the system still starts on the chip (needs a TPU;
 # exits non-zero without one) — trainer, DownPour world, LM, engine
 chip-smoke:
@@ -86,11 +80,6 @@ chip-smoke:
 # on a machine with the chip: python bench.py --gate
 bench-gate:
 	$(PY) bench.py --gate --json tests/data/bench_gate_smoke.json
-
-# conv-epilogue cost ladder (fused Pallas kernels vs the unfused XLA
-# chain, per AlexNet tail shape) — the compute-plane microbench phase
-bench-compute:
-	$(PY) bench_all.py --only compute_microbench
 
 # seeded fault-injection suite (utils/chaos.py + the reliability layer):
 # deterministic drop/dup/corrupt/partition/crash scenarios on the PS and
@@ -146,10 +135,6 @@ health:
 health-demo:
 	$(PY) -m distributed_ml_pytorch_tpu.coord.cli --health
 
-# health-plane bench phase: reject rate, nack round-trip, rollback MTTR
-bench-health:
-	$(PY) bench_all.py --only health
-
 # MPMD pipeline-plane suite (ISSUE 10): stages as fleet members — per-stage
 # compiled programs over the reliable wire, coordinator StagePlacement,
 # stage kill -> lease-expiry detection -> checkpoint restart with
@@ -163,20 +148,13 @@ mpmd:
 mpmd-demo:
 	$(PY) -m distributed_ml_pytorch_tpu.coord.cli --mpmd
 
-# MPMD bench phase: steady-state pipeline throughput, bubble fraction,
-# and stage-kill MTTR before/during/after a restart; also leaves the
-# fleet's flight-recorder dumps behind (analyze them with `make timeline`)
-bench-mpmd:
-	$(PY) bench_all.py --only mpmd
-
 # timeline analyzer (ISSUE 12): merge a run's flight-recorder dumps and
 # attribute each stage's wall clock (compute / wait-act / wait-grad /
 # wire-blocked / ckpt) plus the wire's share (retransmits, credit-block,
-# ack frames). Default dir = the newest bench-mpmd run's obs dumps; point
-# it anywhere with: make timeline TIMELINE_DIR=path/to/obs
-TIMELINE_DIR ?= $(shell ls -td "$${TMPDIR:-/tmp}"/bench_mpmd_*/obs 2>/dev/null | head -1)
+# ack frames), and fail when a stage's exclusive states do not sum to its
+# wall clock: make timeline TIMELINE_DIR=path/to/obs
 timeline:
-	@test -n "$(TIMELINE_DIR)" || (echo "no dump dir found — run 'make bench-mpmd' first or pass TIMELINE_DIR=<dir>"; exit 1)
+	@test -n "$(TIMELINE_DIR)" || (echo "pass TIMELINE_DIR=<dir of flight_*.jsonl dumps>"; exit 1)
 	$(PY) -m distributed_ml_pytorch_tpu.analysis timeline $(TIMELINE_DIR)
 
 # multi-tenant scheduler suite (ISSUE 16, coord/sched.py + coord/tenants.py):
@@ -192,11 +170,6 @@ sched:
 sched-demo:
 	$(PY) -m distributed_ml_pytorch_tpu.coord.cli --sched-demo
 
-# scheduler bench phase: preempt/resume MTTR + aggregate goodput (shared
-# FleetScheduler vs two statically partitioned half-fleets)
-bench-sched:
-	$(PY) bench_all.py --only sched
-
 # control-plane durability suite (ISSUE 17, coord/coordinator.py): the
 # coordinator's own WAL+checkpoint restart, monotonic epoch fencing of
 # every outbound control frame, the restart grace window, the coordfail
@@ -205,11 +178,6 @@ bench-sched:
 # with nobody evicted and the parked member resumed bit-identically)
 coordfail:
 	$(PY) -m pytest tests/ -q -m coordfail
-
-# control-plane durability bench phase: kill-the-coordinator MTTR, durable
-# restore time, and steps/tokens lost to the outage (zero = fail-open held)
-bench-coordfail:
-	$(PY) bench_all.py --only coordfail
 
 # adaptive-wire suite (ISSUE 7): RTT-driven retransmission, window/credit
 # backpressure, circuit breakers, and seeded network weather (latency /
@@ -226,24 +194,6 @@ netweather:
 gray:
 	$(PY) -m pytest tests/ -q -m gray
 
-# gray-failure bench phase: goodput through a 10s gray-link episode with
-# containment on vs off, plus measured detection latency (floor-gated) and
-# containment MTTR
-bench-gray:
-	$(PY) bench_all.py --only gray
-
-# wire cost ladder + reliability before/after (bench_all phases): every
-# transport layer priced raw -> reliable -> batched-ack -> WAL-deferred ->
-# chaos-wrapped, plus the ack-tax recovery measurement
-bench-wire:
-	$(PY) bench_all.py --only transport_microbench --only reliability
-
-# compressed gradient wire ladder (ISSUE 14, utils/compress.py): dense vs
-# int8 vs top-k bytes-on-wire per push + acked pushes/s against a real
-# decoding ParameterServer, plus the derived compression ratios
-bench-wire-bytes:
-	$(PY) bench_all.py --only wire_bytes
-
 # distcheck (analysis/): protocol / concurrency / tracing-hygiene static
 # analysis over the whole package — exits non-zero on any unsuppressed
 # finding that is not in the checked-in baseline. Regenerate the baseline
@@ -257,12 +207,6 @@ lint:
 # witness tests — the checks themselves run inside `make lint`
 distflow:
 	$(PY) -m pytest tests/ -q -m distflow
-
-# lint wall-clock phase: times the full distcheck pass (all checker
-# families, distflow included) and gates it against the ceiling in
-# bench_floors.json — static analysis must stay cheap enough for tier-1
-bench-lint:
-	$(PY) bench_all.py --only lint
 
 # bounded protocol model checker (ISSUE 13, analysis/distmodel.py):
 # exhaustively explores small configurations of the extracted wire
@@ -285,13 +229,6 @@ test: lint distmodel bench-gate
 test-all:
 	$(PY) -m pytest tests/ -x -q
 
-# one-command real-data verification (VERDICT r2 #6): downloads genuine
-# CIFAR-10 where egress exists, re-runs steps-to-target + torch parity on
-# it and appends the outcome to BASELINE.md; prints SKIP and exits 0 when
-# offline, so it can run unconditionally
-verify-real-data:
-	$(PY) verify_real_data.py
-
 # --- plots (reference Makefile:8-11) ---
 graph:
 	$(PY) -m distributed_ml_pytorch_tpu.graph
@@ -304,4 +241,4 @@ install:
 dist:
 	$(PY) setup.py sdist bdist_wheel
 
-.PHONY: chip-smoke first second server launch sharded single tpu gpu sync local-sgd p2p serve serve-demo serve-fleet serve-fleet-demo bench bench-serving bench-all bench-wire bench-wire-bytes bench-health bench-gate bench-compute bench-mpmd bench-sched bench-coordfail bench-gray bench-lint timeline chaos codec coord coordfail distflow drill drill-demo fleet gray health health-demo mpmd mpmd-demo netweather sched sched-demo soak lint distmodel test test-all verify-real-data graph install dist
+.PHONY: chip-smoke first second server launch sharded single tpu gpu sync local-sgd p2p serve serve-demo serve-fleet serve-fleet-demo bench bench-gate timeline chaos codec coord coordfail distflow drill drill-demo fleet gray health health-demo mpmd mpmd-demo netweather sched sched-demo soak lint distmodel test test-all graph install dist

@@ -88,8 +88,7 @@ class Rate(float):
         return f"{self.tflops:.1f} TFLOP/s, {self.mfu:.1%} MFU"
 
     def record_fields(self) -> dict:
-        """The FLOPs story as JSON record fields — the single serialization
-        used by bench.py's headline and bench_all's emit."""
+        """The FLOPs story as JSON record fields of bench.py's headline."""
         rec = {}
         if self.tflops is not None:
             rec["flops_per_step"] = self.flops_per_step
@@ -196,8 +195,8 @@ def bench_jax(batch: int = BATCH, k: int | None = None, model=None,
 
 def make_torch_alexnet():
     """The reference's CIFAR AlexNet as one torch Sequential (SURVEY.md C7) —
-    the single spec shared by the throughput baseline here and the
-    steps-to-accuracy comparison in ``bench_all.py``."""
+    the single spec shared by the throughput baseline here and the parity
+    tests (``tests/test_parity.py``)."""
     import torch.nn as tnn
 
     return tnn.Sequential(

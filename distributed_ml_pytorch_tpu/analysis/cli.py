@@ -102,8 +102,8 @@ def main(argv=None) -> int:
         help="also list findings silenced by inline suppressions")
     parser.add_argument(
         "--json", action="store_true",
-        help="machine-readable findings on stdout (CI / bench_all "
-             "consume lint results without scraping text)")
+        help="machine-readable findings on stdout (CI consumes lint "
+             "results without scraping text)")
     args = parser.parse_args(argv)
 
     root = args.root or default_root()

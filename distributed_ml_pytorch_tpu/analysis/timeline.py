@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 from typing import Dict, List, Optional
 
 #: exclusive serve-loop states the analyzer knows how to attribute, per
@@ -262,6 +263,40 @@ def analyze(dump_dir: str) -> dict:
     }
 
 
+def check_bubble_attribution(attr: dict) -> dict:
+    """Schema gate for :func:`analyze`'s ``bubble_attribution``: fractions
+    over the mpmd plane's exclusive states, summing to ~1, with
+    ``bubble_fraction`` consistent with ``1 - compute``. Raises
+    ``ValueError`` on any breach (``main`` exits non-zero on it) — a
+    malformed attribution must not be reported as a decomposition."""
+    if not isinstance(attr, dict):
+        raise ValueError(f"bubble_attribution must be a dict, got "
+                         f"{type(attr).__name__}")
+    fractions = attr.get("fractions")
+    if not isinstance(fractions, dict) or not fractions:
+        raise ValueError("bubble_attribution.fractions missing/empty")
+    states = KNOWN_PLANES["mpmd"]
+    unknown = sorted(k for k in fractions if k not in states)
+    if unknown:
+        raise ValueError(f"bubble_attribution names unknown state(s) "
+                         f"{unknown} (known: {list(states)})")
+    total = sum(float(v) for v in fractions.values())
+    if not 0.95 <= total <= 1.05:
+        raise ValueError(
+            f"bubble_attribution fractions sum to {total:.4f}, not ~1 — "
+            "the exclusive-state clock contract is broken")
+    bubble = attr.get("bubble_fraction")
+    if not isinstance(bubble, (int, float)) or not 0.0 <= bubble <= 1.0:
+        raise ValueError(f"bubble_fraction {bubble!r} not in [0, 1]")
+    if abs((1.0 - float(fractions.get("compute", 0.0))) - float(bubble)) \
+            > 1e-3:
+        raise ValueError("bubble_fraction != 1 - compute fraction")
+    stages = attr.get("stages")
+    if not isinstance(stages, int) or stages < 1:
+        raise ValueError(f"bubble_attribution.stages {stages!r} invalid")
+    return attr
+
+
 def render(report: dict) -> str:
     """Human-readable rendering of :func:`analyze`'s report."""
     lines = [
@@ -323,6 +358,12 @@ def main(argv=None) -> int:
                         help="machine-readable report on stdout")
     args = parser.parse_args(argv)
     report = analyze(args.dump_dir)
+    if report["bubble_attribution"] is not None:
+        try:
+            check_bubble_attribution(report["bubble_attribution"])
+        except ValueError as e:
+            print(f"timeline: {e}", file=sys.stderr)
+            return 1
     if args.json:
         print(json.dumps(report, indent=2, sort_keys=True))
     else:

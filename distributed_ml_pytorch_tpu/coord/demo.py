@@ -12,8 +12,7 @@ stats, and the final shard-map version.
 
 ``tests/test_coord.py`` drives it three times with identical seeds for the
 fault-free-corridor check; ``coord/cli.py --demo`` runs it once as a
-self-contained demo; ``bench_all.py elastic_phase()`` times its steady
-state before/during/after the rebalance.
+self-contained demo.
 """
 
 from __future__ import annotations

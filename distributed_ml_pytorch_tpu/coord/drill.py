@@ -32,8 +32,7 @@ byte-identically run after run (``tests/test_drill.py`` asserts it 3x).
 faulted directly: their loss-freedom must come from WAL + deferred acks,
 not from luck.
 
-``make drill`` runs the drill suite; ``bench_all.recovery_phase()`` times
-MTTR and replayed-update counts on this machinery.
+``make drill`` runs the drill suite.
 """
 
 from __future__ import annotations

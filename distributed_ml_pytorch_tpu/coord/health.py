@@ -449,7 +449,7 @@ def health_scenario(
 
 def health_demo(seed: int = 0, base_dir: Optional[str] = None) -> Dict:
     """One self-contained pass of the acceptance script
-    (``coord/cli.py --health``; ``bench_all --only health`` prices it)."""
+    (``coord/cli.py --health``)."""
     import tempfile
 
     base = base_dir or tempfile.mkdtemp(prefix="health_")

@@ -762,9 +762,9 @@ def _resolve_flash_config(q, k, causal, block_q, block_k,
     if block_k is None:
         block_k = _default(sk, "sk")
     # backward defaults via flash_bwd_block_choice (square at short
-    # sequences, (·, 2048) key blocks at sk >= 4096 — see its docstring);
-    # an explicit forward block is the fallback for lengths no candidate
-    # divides — it divides by definition
+    # sequences, (·, 2048) key blocks at sk == 8192 exactly — see its
+    # docstring); an explicit forward block is the fallback for lengths no
+    # candidate divides — it divides by definition
     if block_q_bwd is None or block_k_bwd is None:
         bwd_default = flash_bwd_block_choice(sq, sk)
         if block_q_bwd is None:

@@ -1,5 +1,4 @@
-"""The lm_head backward+update, restructured — and the measured record of
-why the JAX-level restructure beats the Pallas kernel here.
+"""The lm_head backward+update, restructured at the JAX level.
 
 Why (BASELINE.md #6, VERDICT r4 #2): round 4 recorded the lm_head SGD
 update at "~89 GB/s behind an XLA dW-transpose fold". Round-5 profiling
@@ -16,8 +15,8 @@ fusion into an equally slow producer + a 672 µs clean axpy (so a
 layout-MATCHED plain update streams at ~690 GB/s — the "89 GB/s update"
 was always the fused matmul's epilogue, not an axpy).
 
-What actually wins — :func:`make_fused_head_sgd_step`, a JAX-level
-restructure with the same semantics as the AD step (tested):
+What wins — :func:`make_fused_head_sgd_step`, with the same semantics as
+the AD step (tested):
 
 - the head CE is written out by hand so ONE logsumexp serves the loss,
   the dh backward, and the dW fusion (optax's CE plus an explicit lse
@@ -31,9 +30,9 @@ Measured net (device-true, with the long-seq flash backward blocking of
 ``ops/attention.flash_bwd_block_choice``): GPT-2-small b1×S8192
 121.57 → 119.11 ms/step (67,385 → 68,778 tok/s, 43.6 → 44.5% MFU).
 
-The Pallas kernel (``use_kernel=True``) remains in-tree as the measured
-record: it is VPU-epilogue-bound and interferes with neighboring flash
-kernels — the numbers are in :func:`head_update_sgd`'s docstring.
+A Pallas dW+update kernel was tried in its place and removed in PR 32: it
+was bound by its VPU epilogue (4.50 ms in-program against 4.40) and slowed
+the neighbouring flash-attention kernels by ~7%.
 """
 
 from __future__ import annotations
@@ -43,98 +42,9 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-import optax
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-from distributed_ml_pytorch_tpu.ops.fused_update import _interpret
-
-#: row (token) block and column (vocab) block of one kernel step. VMEM at
-#: (1024, 512): logits 1 MB + h 1.5 MB + acc 1.5 MB + W 2×1.5 MB ≈ 7 MB
-#: with double buffering — comfortably inside the ~16 MB VMEM.
-BLOCK_N = 1024
-BLOCK_V = 512
 
 
-def _head_update_kernel(alpha_ref, w_ref, h_ref, logits_ref, lse_ref,
-                        labels_ref, gscale_ref, out_ref, acc_ref, *, nv, ns, v):
-    j, s = pl.program_id(0), pl.program_id(1)
-
-    @pl.when(s == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    # dlogits for this (row, col) tile: (softmax(logits) − onehot) · gscale.
-    # The epilogue is the kernel's bound (the MXU dot per tile is ~4 µs;
-    # seven VPU ops per element over N·V elements is ~3 ms/step), so it is
-    # trimmed: exp2 in log2 space (the VPU's native exponential — the flash
-    # kernel uses the same trick) on f32, one fused scale, bf16 result for
-    # the MXU — matching XLA's own bf16 dW dot arithmetic.
-    logits = logits_ref[:].astype(jnp.float32)
-    log2e = 1.4426950408889634
-    p = jnp.exp2(logits * log2e - (lse_ref[0, :] * log2e)[:, None])
-    col = j * BLOCK_V + jax.lax.broadcasted_iota(jnp.int32, p.shape, 1)
-    onehot = (labels_ref[0, :][:, None] == col).astype(jnp.float32)
-    dl = (p - onehot) * gscale_ref[0, :][:, None]
-    # a ragged final vocab block reads garbage logits out of bounds: p is
-    # then garbage (NOT zero — exp of junk), so mask by true column index
-    dl = jnp.where(col < v, dl, 0.0)
-
-    # ht is the PRE-TRANSPOSED (D, N) activations: the contraction runs in
-    # the MXU's native (d, k) x (k, v) orientation. Contracting h's row dim
-    # directly (h as (N, D)) measured 4.77 ms/exec at GPT-2 S=8192 — the
-    # one cheap device transpose (~25 MB) removes that penalty.
-    acc_ref[:] += jax.lax.dot_general(
-        h_ref[:], dl.astype(h_ref.dtype),
-        (((1,), (0,)), ((), ())),  # (D, BN) x (BN, BV) -> (D, BV)
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(s == ns - 1)
-    def _finalize():
-        out_ref[:] = w_ref[:] + alpha_ref[0, 0] * acc_ref[:]
-
-
-def _head_update_pallas(W, h2, logits, lse, labels, gscale, alpha):
-    n, d = h2.shape
-    v = W.shape[1]
-    nv, ns = pl.cdiv(v, BLOCK_V), n // BLOCK_N
-    alpha2 = jnp.asarray(alpha, jnp.float32).reshape(1, 1)
-    ht = h2.T  # (D, N): one 25 MB pass, puts the MXU contraction in its
-    #            native orientation (vs 67%-of-peak untransposed, measured)
-    return pl.pallas_call(
-        partial(_head_update_kernel, nv=nv, ns=ns, v=v),
-        out_shape=jax.ShapeDtypeStruct(W.shape, W.dtype),
-        grid=(nv, ns),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda j, s: (0, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((d, BLOCK_V), lambda j, s: (0, j),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((d, BLOCK_N), lambda j, s: (0, s),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((BLOCK_N, BLOCK_V), lambda j, s: (s, j),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, BLOCK_N), lambda j, s: (0, s),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, BLOCK_N), lambda j, s: (0, s),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, BLOCK_N), lambda j, s: (0, s),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((d, BLOCK_V), lambda j, s: (0, j),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[pltpu.VMEM((d, BLOCK_V), jnp.float32)],
-        input_output_aliases={1: 0},  # update W in place when donated
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=_interpret(),
-    )(alpha2, W, ht, logits, lse.reshape(1, n), labels.reshape(1, n),
-      gscale.reshape(1, n))
-
-
-def head_update_sgd(W, h2, logits, lse, labels, gscale, lr,
-                    use_kernel: bool = False):
+def head_update_sgd(W, h2, logits, lse, labels, gscale, lr):
     """``W − lr · hᵀ·dlogits`` without materializing dlogits — the fused
     lm_head SGD update.
 
@@ -143,38 +53,10 @@ def head_update_sgd(W, h2, logits, lse, labels, gscale, lr,
     ``lse`` (N,) f32 log-sum-exp of each logits row; ``labels`` (N,) int32;
     ``gscale`` (N,) f32 = ∂loss/∂ce per row (the loss mask / mask-sum).
 
-    The DEFAULT path is the XLA formulation: written this way (dlogits as
-    an expression feeding one ``dot_general``, update applied directly),
-    XLA compiles it to a single dW-matmul+update fusion measured at
-    4.40 ms at GPT-2-small S=8192 — faster in-program than the Pallas
-    kernel. ``use_kernel=True`` selects the Pallas kernel instead
-    (requires N % BLOCK_N == 0 on TPU): measured 4.50 ms/exec in-program
-    and 5.0 standalone — the kernel is bound by its VPU epilogue (~6 ops
-    per logits element ≈ 2.6 ms that does NOT overlap the 3.2 ms MXU
-    matmul; exp2-in-log2-space made no difference, and outlining the
-    onehot term to an XLA scatter costs 1.14 ms — measured dead ends) —
-    AND its presence reproducibly slows the program's flash-attention
-    kernels by ~7% (+4.2 ms/step at S=8192; same span count, every kernel
-    uniformly slower; order-independent). Net: the kernel loses on this
-    runtime; it is kept as the measured record and the starting point if
-    a future runtime schedules Pallas calls differently.
+    Written this way (dlogits as an expression feeding one
+    ``dot_general``, update applied directly), XLA compiles it to a single
+    dW-matmul+update fusion measured at 4.40 ms at GPT-2-small S=8192.
     """
-    n = h2.shape[0]
-    if use_kernel:
-        if n % BLOCK_N == 0 and (_interpret()
-                                 or jax.default_backend() == "tpu"):
-            return _head_update_pallas(W, h2, logits, lse, labels, gscale,
-                                       -lr)
-        # an explicit kernel request that cannot be honored must be audible
-        # — silently recording XLA numbers as kernel numbers is how a
-        # measured record goes stale
-        import warnings
-
-        warnings.warn(
-            f"use_kernel=True but the Pallas path cannot run (N={n} % "
-            f"{BLOCK_N} != 0, or backend {jax.default_backend()!r} is not "
-            "tpu and interpret mode is off) — falling back to the XLA "
-            "formulation", stacklevel=2)
     p = jnp.exp(logits.astype(jnp.float32) - lse[:, None])
     onehot = (labels[:, None] == jnp.arange(W.shape[1])[None, :])
     dl = ((p - onehot) * gscale[:, None]).astype(h2.dtype)
@@ -183,11 +65,9 @@ def head_update_sgd(W, h2, logits, lse, labels, gscale, lr,
     return W - lr * dW
 
 
-def make_fused_head_sgd_step(model, lr: float,
-                             use_kernel: bool = False) -> Callable:
-    """Jitted LM train step (plain SGD) with the restructured lm_head —
-    the measured fast path for ``bench_lm``'s recipe (module docstring has
-    the numbers and the why).
+def make_fused_head_sgd_step(model, lr: float) -> Callable:
+    """Jitted LM train step (plain SGD) with the restructured lm_head
+    (module docstring has the numbers and the why).
 
     Same semantics as the AD step over ``fsdp.lm_loss_builder`` + SGD
     (tested: loss and all updated params match to float tolerance):
@@ -196,9 +76,7 @@ def make_fused_head_sgd_step(model, lr: float,
     - head CE written out by hand (one lse for loss + backward + update);
       the dh matmul stays XLA (measured at its roofline); the loss
       definition is ``lm_loss_builder``'s (2-D logits, final masked);
-    - the dW matmul + W update run in :func:`head_update_sgd`
-      (``use_kernel`` selects the Pallas kernel — measured slower
-      in-program, see its docstring);
+    - the dW matmul + W update run in :func:`head_update_sgd`;
     - body params update by plain SGD on the vjp grads.
 
     Restricted to plain SGD by design: fusing the update into the backward
@@ -225,7 +103,7 @@ def make_fused_head_sgd_step(model, lr: float,
 
         # loss + dh via AD over h alone, with the CE written out by hand so
         # the logits and their logsumexp come back as aux — ONE lse for the
-        # loss, the dh backward, and the kernel. (Calling optax's CE and
+        # loss, the dh backward, and the update. (Calling optax's CE and
         # recomputing lse outside measured an extra 1.33 ms/step at GPT-2
         # S=8192: XLA does not CSE the two 824 MB reductions.)
         def head_loss(h2):
@@ -238,8 +116,7 @@ def make_fused_head_sgd_step(model, lr: float,
 
         (loss, (logits, lse)), dh2 = jax.value_and_grad(
             head_loss, has_aux=True)(h2)
-        W_new = head_update_sgd(W, h2, logits, lse, labels, gscale, lr,
-                                use_kernel=use_kernel)
+        W_new = head_update_sgd(W, h2, logits, lse, labels, gscale, lr)
 
         (d_body,) = body_vjp(dh2.reshape(h.shape))
         new_body = jax.tree.map(lambda p, g: p - lr * g, body_params, d_body)

@@ -1383,9 +1383,19 @@ class PushFlusher:
         self._q.join()
 
     def stop(self) -> None:
-        self.drain()
-        self._q.put(None)
+        """End the thread behind every pending push (FIFO: the sentinel
+        queues last), waiting at most ten seconds for the queue to take it
+        and ten for the thread to reach it. A send stalled on a silently
+        dead peer would otherwise hang here, or let ``finish()`` pass for
+        clean with a push stuck: it is reported on stderr."""
+        try:
+            self._q.put(None, timeout=10)
+        except queue.Full:
+            pass
         self._thread.join(timeout=10)
+        if self._thread.is_alive():
+            print("push flusher: still sending at stop() — a push is stuck "
+                  "on the wire and was not delivered", file=sys.stderr)
 
 
 class Asynchronous:

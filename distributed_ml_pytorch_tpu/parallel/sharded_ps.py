@@ -495,6 +495,11 @@ class ShardedAsynchronous:
         return tuple(links)
 
     def _mark_down(self, shard: int) -> None:
+        # two threads get here: the training thread (pull requests, rejoin
+        # sends) and the PushFlusher thread (``_push_all`` -> ``_send``).
+        # ``shard_down`` carries no lock: each touch is one GIL-atomic read
+        # or write of a list slot, and a lost race costs one repeated
+        # transition line, never a wrong state.
         if self.shard_down[shard]:
             return  # already down: no repeat transition logging
         self.shard_down[shard] = True

@@ -154,10 +154,6 @@ class SDCRule:
     per-channel counter like fault rules. Either way the draws come from
     their own seeded stream (``_SDC_NS``), so adding SDC rules never
     perturbs an existing plan's fault or weather decisions.
-
-    Note: assumes the default reliability envelope checksum; a
-    ``legacy_envelope=True`` transport pair would drop the re-stamped
-    frame (and the SDC would degrade into ordinary wire corruption).
     """
 
     src: Optional[int] = None

@@ -22,6 +22,8 @@ stateless GroupNorm instead, ``models/resnet.py``).
 
 The converter takes plain numpy-convertible tensors, so callers can feed a
 ``torch.load(...)`` state_dict without this module importing torch.
+:func:`install_flax_alexnet_init` is the other direction, for the one
+architecture the parity tests compare; it imports torch when called.
 """
 
 from __future__ import annotations
@@ -156,3 +158,28 @@ def load_torch_state_dict(
             )
         out_leaves[i] = converted
     return jax.tree_util.tree_unflatten(treedef, out_leaves)
+
+
+def install_flax_alexnet_init(tmodel, flax_params) -> None:
+    """Copy a flax AlexNet init into the torch AlexNet (the inverse of
+    :func:`load_torch_state_dict`, specialized to the reference
+    architecture): conv kernels (kH, kW, I, O) → (O, I, kH, kW), the
+    classifier (in, out) → (out, in), biases as-is. Layer order is
+    structural (conv1..conv5, classifier), so no shape-matching heuristics
+    are needed."""
+    import torch
+
+    convs = [m for m in tmodel if isinstance(m, torch.nn.Conv2d)]
+    linears = [m for m in tmodel if isinstance(m, torch.nn.Linear)]
+    names = [f"conv{i}" for i in range(1, len(convs) + 1)]
+    with torch.no_grad():
+        # np.array(copy=True): jax exports read-only buffers and
+        # torch.from_numpy warns on non-writable sources
+        as_t = lambda a: torch.from_numpy(np.array(a, np.float32, copy=True))
+        for name, m in zip(names, convs):
+            m.weight.copy_(as_t(
+                np.asarray(flax_params[name]["kernel"]).transpose(3, 2, 0, 1)))
+            m.bias.copy_(as_t(flax_params[name]["bias"]))
+        (lin,) = linears
+        lin.weight.copy_(as_t(np.asarray(flax_params["classifier"]["kernel"]).T))
+        lin.bias.copy_(as_t(flax_params["classifier"]["bias"]))

@@ -606,8 +606,7 @@ class Transport:
     in-process queue world, the Python TCP star, and the native C++ fast
     path all implement it, and the reliability/chaos/durability layers wrap
     any of them interchangeably (``make_transport`` / ``make_world`` are
-    the factories; ``bench_all.transport_microbench_phase`` prices each
-    layer of the stack).
+    the factories).
     """
 
     rank: int = 0
@@ -1083,20 +1082,6 @@ def _frame_crc(inc: int, seq: int, code: int, body, corr: int = 0) -> int:
     return zlib.crc32(mv, h) & 0xFFFFFFFF
 
 
-def _frame_crc_legacy(inc: int, seq: int, code: int, body,
-                      corr: int = 0) -> int:
-    """The pre-ISSUE-7 envelope checksum — whole-payload crc32 over a
-    ``tobytes()`` copy. Kept ONLY as the bench's honest BEFORE
-    (``ReliableTransport(legacy_envelope=True)``); nothing on a default
-    code path uses it. ``corr`` is accepted for call-site uniformity but
-    NOT covered (the before never knew it)."""
-    head = struct.pack("<III", inc & 0xFFFFFFFF, seq & 0xFFFFFFFF,
-                       code & 0xFFFFFFFF)
-    if isinstance(body, np.ndarray):
-        body = body.tobytes()
-    return zlib.crc32(body, zlib.crc32(head)) & 0xFFFFFFFF
-
-
 def _next_incarnation() -> int:
     """Second-stamped (32 bits of epoch seconds wrap in 2106 — a
     millisecond stamp would wrap every ~50 days and make a post-wrap
@@ -1247,13 +1232,7 @@ class ReliableTransport(Transport):
         breaker_cooldown: float = 0.5,
         breaker_grace: Optional[float] = None,
         jitter: float = 0.25,
-        legacy_envelope: bool = False,
     ):
-        """``legacy_envelope=True`` reproduces the pre-ISSUE-7 envelope
-        hot path — full-frame ``np.concatenate``, ``tobytes()`` copies and
-        a whole-payload crc32 — so the bench can price the adaptive wire
-        against its true BEFORE on the same rig (both ends of a link must
-        agree on the mode: the checksum algorithms differ)."""
         import random
 
         self.inner = inner
@@ -1274,7 +1253,6 @@ class ReliableTransport(Transport):
         self.breaker_grace = (
             float(breaker_grace) if breaker_grace is not None
             else self.max_backoff)
-        self.legacy_envelope = bool(legacy_envelope)
         self.jitter = float(jitter)
         self.unreliable_codes = frozenset(
             int(c) for c in unreliable_codes
@@ -1451,14 +1429,11 @@ class ReliableTransport(Transport):
                 rec.record("wire-blocked", "wire-blocked", block_t0, now_ns,
                            corr=corr, meta={"dst": dst})
         try:
-            checksum = (_frame_crc_legacy if self.legacy_envelope
-                        else _frame_crc)
-            crc = checksum(self.incarnation, seq, int(code), arr, corr)
+            crc = _frame_crc(self.incarnation, seq, int(code), arr, corr)
             header = np.asarray(
                 [*_split16(self.incarnation), *_split16(seq), *_split16(crc),
                  float(int(code)), *_split16(corr)], np.float32)
-            parts = ((np.concatenate([header, arr]),) if self.legacy_envelope
-                     else (header, arr))
+            parts = (header, arr)
         except Exception:
             with self._lock:
                 st = self._peer(dst)
@@ -1710,9 +1685,7 @@ class ReliableTransport(Transport):
                 self.stats["crc_dropped"] += 1
             return None
         body = payload[9:]
-        checksum = (_frame_crc_legacy if self.legacy_envelope
-                    else _frame_crc)
-        if checksum(inc, seq, inner_code, body, corr) != crc:
+        if _frame_crc(inc, seq, inner_code, body, corr) != crc:
             with self._lock:
                 self.stats["crc_dropped"] += 1
             return None  # corrupt: no ack, the retry delivers a clean copy

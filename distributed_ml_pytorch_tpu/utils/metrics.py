@@ -235,7 +235,7 @@ def percentile(values, q: float) -> float:
     """Linear-interpolation percentile of a 1-D sample (``q`` in [0, 100]).
 
     The serving SLO reporter's primitive (TTFT/TPOT summaries,
-    ``serving/engine.py`` and ``bench_serving.py``). A thin, loud wrapper
+    ``serving/engine.py``). A thin, loud wrapper
     over ``np.percentile``: empty samples and out-of-range ``q`` raise
     instead of returning NaN — an SLO line with a silent NaN percentile is
     worse than a crash.

@@ -419,7 +419,7 @@ def run(argv=None) -> dict:
     dt = time.perf_counter() - t0
     rate = (n_disp - 1) * k * args.batch * args.seq / dt if n_disp > 1 else 0.0
     print(f"final loss {losses[-1]:.4f}; ~{rate:.0f} tokens/s "
-          f"(naive wall-clock, see bench_all.py for the differenced method)")
+          f"(naive wall-clock; benchmarks/run.py measures)")
     if ckpt is not None:
         ckpt.save(start_step + args.steps, state, force=True)
         ckpt.close()

@@ -271,6 +271,31 @@ def test_push_flusher_overlaps_order_and_drain():
     fl.stop()
 
 
+def test_push_flusher_stop_reports_a_push_stuck_on_the_wire(
+        monkeypatch, capsys):
+    """A send stalled on a silently dead peer must not pass for a clean
+    stop: ``stop()`` returns after its bounded wait and says on stderr
+    that a push is stuck (the ten seconds are cut short here)."""
+    from distributed_ml_pytorch_tpu.parallel.async_ps import PushFlusher
+
+    picked_up, wire_dead = threading.Event(), threading.Event()
+
+    def stalled_send(arr):
+        picked_up.set()
+        wire_dead.wait(60)
+
+    fl = PushFlusher(stalled_send)
+    fl.enqueue(jnp.zeros((4,), jnp.float32))
+    assert picked_up.wait(10)
+    join = fl._thread.join
+    monkeypatch.setattr(fl._thread, "join", lambda timeout: join(0.2))
+    fl.stop()
+    assert "a push is stuck" in capsys.readouterr().err
+    wire_dead.set()  # the peer answers after all: the thread ends cleanly
+    join(10)
+    assert not fl._thread.is_alive()
+
+
 def test_push_flusher_survives_send_failure_and_still_drains():
     """A failing fetch/send must drop THAT push (degrade-never-crash, the
     _send contract) — not kill the thread and deadlock drain()/finish()."""

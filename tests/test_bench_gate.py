@@ -13,8 +13,6 @@ import os
 import subprocess
 import sys
 
-import pytest
-
 import bench
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -112,35 +110,3 @@ def test_cli_gate_exit_codes(tmp_path):
         cwd=REPO, env=env, capture_output=True, text=True)
     assert fail.returncode == 1
     assert "grad_accum_b1024" in fail.stderr
-
-
-def test_bench_all_only_ps_tpu_must_be_named_first():
-    """ps_tpu's children need the chip; a phase run before it may have left
-    the parent holding it, so the order is refused, not tried."""
-    out = subprocess.run(
-        [sys.executable, "bench_all.py", "--only", "tpu", "--only", "ps_tpu"],
-        cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
-        capture_output=True, text=True, timeout=120)
-    assert out.returncode == 2
-    assert "ps_tpu must be the first phase" in out.stderr
-    assert out.stdout.strip() == ""
-
-
-@pytest.mark.slow
-def test_bench_all_cpu_mesh_phase_runs_after_the_parent_took_a_backend():
-    """The eight-device measurements run in a child: the parent's backend
-    (one CPU device here, the TPU on a chip machine) is already up when the
-    full table gets to them, and a live backend's device count is fixed."""
-    code = ("import jax, bench_all\n"
-            "assert len(jax.devices()) == 1\n"
-            "bench_all.cpu_mesh_phase()\n"
-            "print('RECORDS', sorted(r['metric'] for r in bench_all.RESULTS))\n")
-    env = {k: v for k, v in os.environ.items() if k != "JAX_NUM_CPU_DEVICES"}
-    out = subprocess.run(
-        [sys.executable, "-c", code], cwd=REPO,
-        env=dict(env, JAX_PLATFORMS="cpu"),
-        capture_output=True, text=True, timeout=600)
-    assert out.returncode == 0, out.stderr[-2000:]
-    assert ("RECORDS ['allreduce_2way_gradient_exchange_rate', "
-            "'resnet18_8way_dp_step_throughput']") in out.stdout
-    assert '"hardware": "8 virtual cpu devices"' in out.stdout

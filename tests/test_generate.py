@@ -281,6 +281,78 @@ def test_kv_quant_cache_is_int8_with_scales():
         v.dtype == jnp.float32 and v.shape == (2, 4, 32) for v in scales)
 
 
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["bf16", "kv_quant"])
+def test_ring_decode_step_is_one_softmax_over_the_visible_keys(kv_quant):
+    """One single-token step of the ring-buffered cached attention, on a
+    cache placed mid-block (``ring_base`` 32, five ring rows written),
+    against ONE softmax over ``[big[:ring_base], ring[:t], self]`` with
+    the int8 cache dequantised here: the module's three-part arithmetic,
+    its masks and its scale folding are held to the plain definition.
+    Rows the step must not see (big cache from ``ring_base`` on, ring
+    from ``t`` on) hold values that would swamp the result."""
+    from distributed_ml_pytorch_tpu.models.transformer import (
+        MultiHeadAttention,
+        quantize_kv,
+    )
+
+    b, h, C, T, d, ring_base, t = 3, 4, 40, 16, 32, 32, 5
+    dt = jnp.float32 if kv_quant else jnp.bfloat16
+    mha = MultiHeadAttention(d_model=h * d, n_heads=h, dtype=dt, decode=True,
+                             cache_size=C, decode_block=T, kv_quant=kv_quant)
+    rng = np.random.default_rng(0)
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    x = normal(b, 1, h * d).astype(dt)
+    params = mha.init(jax.random.key(0), x)["params"]
+
+    stale_big = (jnp.arange(C) >= ring_base)[None, None, :, None]
+    stale_ring = (jnp.arange(T) >= t)[None, None, :, None]
+    big_k = jnp.where(stale_big, 50.0, normal(b, h, C, d))
+    big_v = jnp.where(stale_big, 50.0, normal(b, h, C, d))
+    ring_k = jnp.where(stale_ring, 50.0, normal(b, h, T, d)).astype(dt)
+    ring_v = jnp.where(stale_ring, 50.0, normal(b, h, T, d)).astype(dt)
+    cache = {"ring_k": ring_k, "ring_v": ring_v,
+             "cursor": jnp.asarray(ring_base + t, jnp.int32),
+             "ring_base": jnp.asarray(ring_base, jnp.int32)}
+    if kv_quant:
+        (cache["cached_k"], cache["scale_k"]) = quantize_kv(big_k)
+        (cache["cached_v"], cache["scale_v"]) = quantize_kv(big_v)
+        seen_k = cache["cached_k"] * cache["scale_k"][..., None]
+        seen_v = cache["cached_v"] * cache["scale_v"][..., None]
+    else:
+        cache["cached_k"], cache["cached_v"] = big_k.astype(dt), big_v.astype(dt)
+        seen_k, seen_v = cache["cached_k"], cache["cached_v"]
+
+    got, mutated = mha.apply({"params": params, "cache": cache}, x,
+                             mutable=["cache"])
+
+    heads = lambda name: (x @ params[name]["kernel"].astype(dt)).reshape(
+        b, 1, h, d).transpose(0, 2, 1, 3).astype(jnp.float32)
+    q, k, v = heads("q"), heads("k"), heads("v")
+    f32 = lambda a: a.astype(jnp.float32)
+    keys = jnp.concatenate(
+        [f32(seen_k)[:, :, :ring_base], f32(ring_k)[:, :, :t], k], axis=2)
+    values = jnp.concatenate(
+        [f32(seen_v)[:, :, :ring_base], f32(ring_v)[:, :, :t], v], axis=2)
+    assert keys.shape == (b, h, ring_base + t + 1, d)
+    probs = jax.nn.softmax(
+        jnp.einsum("bhsd,bhkd->bhsk", q, keys) / np.sqrt(d), axis=-1)
+    attended = jnp.einsum("bhsk,bhkd->bhsd", probs, values)
+    want = (attended.transpose(0, 2, 1, 3).reshape(b, 1, h * d).astype(dt)
+            @ params["o"]["kernel"].astype(dt))
+
+    tol = dict(rtol=1e-5, atol=1e-5) if kv_quant else dict(rtol=3e-2, atol=3e-2)
+    np.testing.assert_allclose(np.asarray(f32(got)), np.asarray(f32(want)), **tol)
+    after = mutated["cache"]
+    assert int(after["cursor"]) == ring_base + t + 1
+    assert int(after["ring_base"]) == ring_base
+    np.testing.assert_array_equal(  # the step's K lands in ring row t only
+        np.asarray(f32(after["ring_k"])),
+        np.asarray(f32(jnp.where((jnp.arange(T) == t)[None, None, :, None],
+                                 k.astype(dt), ring_k))))
+    np.testing.assert_array_equal(np.asarray(after["cached_k"]),
+                                  np.asarray(cache["cached_k"]))
+
+
 def test_tp_sharded_decode_matches_single_device():
     """Greedy TP decode on a 2x4 dp x tp mesh must be bit-identical to the
     single-device path — same compiled program, shardings propagated."""

@@ -150,3 +150,25 @@ def test_launch_world_rejects_non_worker_tpu_rank():
     for bad in (0, 3, -1):
         with pytest.raises(ValueError, match="worker rank"):
             launch_world(3, [], tpu_worker_rank=bad)
+
+
+def test_makefile_recipes_name_scripts_and_modules_that_exist():
+    """Every ``$(PY) <script>.py`` and ``$(PY) -m <module>`` recipe in the
+    Makefile resolves in the tree: a script deleted with its targets left
+    behind fails here, not at the next reader's ``make``."""
+    import importlib.util
+    import re
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "Makefile")) as fh:
+        recipes = [line for line in fh if line.startswith("\t")]
+    scripts = {m for line in recipes
+               for m in re.findall(r"\$\(PY\) ([\w./-]+\.py)\b", line)}
+    modules = {m for line in recipes
+               for m in re.findall(r"\$\(PY\) -m ([\w.]+)", line)}
+    assert {"bench.py", "chip_smoke.py"} <= scripts and len(modules) >= 8
+    missing = sorted(s for s in scripts
+                     if not os.path.isfile(os.path.join(repo, s)))
+    missing += sorted(m for m in modules
+                      if importlib.util.find_spec(m) is None)
+    assert not missing, f"Makefile recipes name what is not there: {missing}"

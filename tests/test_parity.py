@@ -95,11 +95,13 @@ def test_same_recipe_same_weights_same_trajectory():
 
 
 def test_flax_init_installs_into_torch_with_identical_forward():
-    """The flax→torch direction (``bench_all.install_flax_alexnet_init``,
-    the matched-init steps-to-target leg): installing a flax init into the
-    torch AlexNet must give the same classifier function."""
+    """The flax→torch direction (``utils.interop.install_flax_alexnet_init``):
+    installing a flax init into the torch AlexNet must give the same
+    classifier function."""
     from bench import make_torch_alexnet
-    from bench_all import install_flax_alexnet_init
+    from distributed_ml_pytorch_tpu.utils.interop import (
+        install_flax_alexnet_init,
+    )
 
     flax_model = AlexNet(num_classes=10)
     params = flax_model.init(jax.random.key(3), jnp.zeros((1, 32, 32, 3)))[

@@ -6,9 +6,6 @@ moment the suite runs somewhere with network, the real-CIFAR-10 claims
 close themselves with no code changes.
 """
 
-import subprocess
-import sys
-
 import pytest
 
 
@@ -59,27 +56,3 @@ def test_real_data_short_training_learns(real_cifar):
     _, preds = ev(state.params, jnp.asarray(xt[:2000]), jnp.asarray(yt[:2000]))
     acc = float((np.asarray(preds) == yt[:2000]).mean())
     assert acc > 0.25, f"400 real-data steps only reached {acc:.3f}"
-
-
-def test_verify_real_data_script_skips_cleanly_without_egress(tmp_path):
-    """The one-command closer must exit 0 with an explicit SKIP record when
-    the download cannot happen — runnable unconditionally in CI."""
-    import os
-    import shutil
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    # run from a scratch cwd so ./data stays empty and BASELINE.md untouched;
-    # a dead proxy makes the download fail FAST even on networked hosts, so
-    # this test deterministically exercises the skip path everywhere
-    shutil.copy(os.path.join(repo, "verify_real_data.py"), tmp_path)
-    out = subprocess.run(
-        [sys.executable, str(tmp_path / "verify_real_data.py")],
-        capture_output=True, text=True, cwd=tmp_path,
-        env={**os.environ,
-             "PYTHONPATH": repo + os.pathsep + os.environ.get("PYTHONPATH", ""),
-             "http_proxy": "http://127.0.0.1:9",
-             "https_proxy": "http://127.0.0.1:9"},
-        timeout=300,
-    )
-    assert out.returncode == 0, out.stderr
-    assert "skipped_no_egress" in out.stdout

@@ -4,8 +4,8 @@ Fixtures live under ``tests/data/timeline/`` — four dumps covering the
 contract surface: a stage with a proper attribution summary, a death dump
 with spans only (fallback summation), a TORN dump (truncated line mid-
 crash), and an unknown-plane dump that must be surfaced, not dropped.
-Also hosts the ``bench_all.check_bubble_attribution`` schema gate tests
-(the ``test_bench_gate.py``-style check for the mpmd_phase JSON field).
+Also hosts the ``timeline.check_bubble_attribution`` schema gate tests (the
+check ``timeline.main`` runs on the attribution it is about to print).
 """
 
 import json
@@ -122,7 +122,7 @@ def test_missing_dir_raises_and_empty_dir_exits_nonzero(tmp_path):
     assert cli.main(["timeline", str(empty)]) == 1
 
 
-# ------------------------- bench_all bubble_attribution schema gate ------
+# ------------------------- bubble_attribution schema gate ----------------
 
 def _good_attr():
     return {
@@ -135,10 +135,8 @@ def _good_attr():
     }
 
 
-def test_bench_bubble_attribution_schema_accepts_good_record():
-    import bench_all
-
-    assert bench_all.check_bubble_attribution(_good_attr()) == _good_attr()
+def test_bubble_attribution_schema_accepts_good_record():
+    assert timeline.check_bubble_attribution(_good_attr()) == _good_attr()
 
 
 @pytest.mark.parametrize("mutate, msg", [
@@ -149,20 +147,34 @@ def test_bench_bubble_attribution_schema_accepts_good_record():
     (lambda a: a.update(bubble_fraction=0.5), "1 - compute"),
     (lambda a: a.update(stages=0), "stages"),
 ])
-def test_bench_bubble_attribution_schema_rejects_breaches(mutate, msg):
-    import bench_all
-
+def test_bubble_attribution_schema_rejects_breaches(mutate, msg):
     attr = _good_attr()
     mutate(attr)
     with pytest.raises(ValueError, match=None) as exc:
-        bench_all.check_bubble_attribution(attr)
+        timeline.check_bubble_attribution(attr)
     assert msg.split()[0] in str(exc.value)
 
 
-def test_bench_bubble_attribution_accepts_real_analyzer_output():
-    """The analyzer's own fixture-derived record passes the bench gate
-    (the two halves of the pipeline agree on the schema)."""
-    import bench_all
-
+def test_bubble_attribution_accepts_real_analyzer_output():
+    """The analyzer's own fixture-derived record passes the gate (the two
+    halves of the pipeline agree on the schema)."""
     rep = timeline.analyze(FIXTURES)
-    bench_all.check_bubble_attribution(rep["bubble_attribution"])
+    timeline.check_bubble_attribution(rep["bubble_attribution"])
+
+
+def test_timeline_command_exits_nonzero_on_a_broken_state_clock(
+        tmp_path, capsys):
+    """A stage dump whose exclusive states overlap (fractions sum to 1.3)
+    is refused where the report is made: ``analyze`` still returns it,
+    ``main`` prints the checker's message and no decomposition."""
+    meta = {"kind": "meta", "member": "stage0", "plane": "mpmd",
+            "reason": "exit", "dropped": 0}
+    flush = {"name": "attribution", "state": "event", "t0_ns": 1, "t1_ns": 1,
+             "meta": {"wall_s": 10.0, "compute": 5.0, "wait-act": 8.0}}
+    (tmp_path / "flight_stage0.jsonl").write_text(
+        json.dumps(meta) + "\n" + json.dumps(flush) + "\n")
+    rep = timeline.analyze(str(tmp_path))
+    assert rep["bubble_attribution"]["fractions"]["wait-act"] == 0.8
+    assert timeline.main([str(tmp_path)]) == 1
+    out = capsys.readouterr()
+    assert "sum to 1.3000" in out.err and "bubble" not in out.out

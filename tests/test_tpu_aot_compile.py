@@ -107,39 +107,6 @@ def test_flash_attention_compiles_forward_and_gradient(v5e, shape, bwd_impl):
     assert_kernels(compiled_text(f, q, q, q), 2 if bwd_impl == "fused" else 3)
 
 
-def test_fused_head_update_kernel_compiles(v5e, chip_dispatch):
-    """``head_update_sgd(use_kernel=True)`` at GPT-2-small b8 x S2048."""
-    from distributed_ml_pytorch_tpu.ops.fused_head import head_update_sgd
-
-    one = SingleDeviceSharding(v5e[0])
-    n, d, vocab = 8 * 2048, 768, 50304
-    text = compiled_text(
-        lambda W, h, lg, lse, lab, gs: head_update_sgd(
-            W, h, lg, lse, lab, gs, 0.05, use_kernel=True),
-        on(one, (d, vocab)), on(one, (n, d), jnp.bfloat16),
-        on(one, (n, vocab), jnp.bfloat16), on(one, (n,)),
-        on(one, (n,), jnp.int32), on(one, (n,)))
-    assert_kernels(text)
-
-
-@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
-def test_decode_attention_kernel_compiles(v5e, quant):
-    from distributed_ml_pytorch_tpu.ops.decode_attention import (
-        decode_attention_step,
-    )
-
-    one = SingleDeviceSharding(v5e[0])
-    b, h, c, t, d = 8, 12, 1024, 16, 64
-    step = on(one, (b, h, 1, d), jnp.bfloat16)
-    ring = on(one, (b, h, t, d), jnp.bfloat16)
-    big = on(one, (b, h, c, d), jnp.int8 if quant else jnp.bfloat16)
-    scalar = on(one, (), jnp.int32)
-    args = [step, step, step, big, big, ring, ring, scalar, scalar]
-    if quant:
-        args += [on(one, (b, h, c)), on(one, (b, h, c))]
-    assert_kernels(compiled_text(decode_attention_step, *args))
-
-
 @pytest.mark.parametrize("form", ["chunked", "step"])
 def test_gated_delta_rule_compiles_at_the_published_sizes(v5e, form):
     """``ops/gated_delta.py`` is ``jax.numpy`` (no kernel to find in the
