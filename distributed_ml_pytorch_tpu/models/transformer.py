@@ -24,6 +24,8 @@ from __future__ import annotations
 
 from typing import Callable, Optional
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
@@ -75,6 +77,110 @@ def quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     scale = jnp.maximum(absmax / 127.0, 1e-8)
     q = jnp.clip(jnp.round(xf / scale[..., None]), -127, 127).astype(jnp.int8)
     return q, scale
+
+
+#: the collection through which a caller tells a decoding attention layer
+#: how many rows of its big cache anybody can need (``rows``, a scalar)
+KV_READ = "kv_read"
+
+
+def kv_read_chunk(cache_rows: int) -> int:
+    """Rows a bounded read of a ``cache_rows``-row cache takes at a time: a
+    quarter of the allocation, so the read stops at a quarter, a half, three
+    quarters or all of it."""
+    return -(-cache_rows // 4)
+
+
+def _tpu_keeps_rows_minor(rows: int, head_dim: int) -> bool:
+    """Whether this process runs on a TPU that holds a ``(..., rows,
+    head_dim)`` buffer with ``rows``, not ``head_dim``, along its 128 lanes.
+    The TPU runtime lays a buffer's last two dimensions out over tiles of
+    8 x 128 and makes the minor one whichever pads less: a head of 64 would
+    fill half of every tile's lanes, so a 1024 x 64 cache lies transposed,
+    and a head of 128 lies as written (read from the entry layouts the TPU
+    compiler assigns, PR 33). A wrong answer costs a copy of the buffer and
+    changes no result; other backends keep what the shape says."""
+    if jax.default_backend() != "tpu":
+        return False
+    pad = lambda n, tile: -(-n // tile) * tile
+    return pad(rows, 128) * pad(head_dim, 8) < pad(head_dim, 128) * pad(rows, 8)
+
+
+# jitted so that a model's layers share ONE trace of it: traced and lowered
+# inline, a layer each, it cost the host seconds at every start (thirty-six
+# copies of the loop at gpt2-large: set-up +7 s on the chip's host, PR 33)
+@partial(jax.jit, static_argnames=("dtype", "turned"))
+def bounded_cache_attention(bound, q, v, s_ring, s_self, scale, ring_base,
+                            ring_v, cache_k, cache_v, scale_k, scale_v, *,
+                            dtype, turned):
+    """A single-token step's three-part attention (big cache, ring, self) with
+    the big cache read only as far as somebody needs it. ``bound`` (a scalar
+    the caller sets, at least every ``ring_base`` it will be asked about;
+    ``SlotKVPool`` gives the longest among its active slots) bounds a loop
+    over ``kv_read_chunk`` rows at a time, so the loop's trip count is data
+    and its body is in the program once. The softmax runs online over the
+    chunks (running maximum, sum and weighted V in float32), started from the
+    ring and self terms (``s_ring``, ``s_self``), which are never empty; per
+    sequence the ``key_pos < ring_base`` mask still hides what lies between
+    its own length and the bound. Rows past the bound weigh ``exp(-inf) = 0``
+    in the whole read, so leaving them out drops no term of any sum; what
+    differs is the order of float32 additions. ``scale_k`` / ``scale_v`` are
+    the int8 cache's per-row scales, or None. ``turned`` says that the device
+    keeps the caches with their rows minor (``_tpu_keeps_rows_minor``): a loop
+    takes its operands in the layout their shape has by default, whatever the
+    buffer's own, so it is handed such caches transposed (a bitcast), or it
+    copies both, whole, every step."""
+    rows = cache_k.shape[2]
+    chunk = kv_read_chunk(rows)
+    T = ring_v.shape[2]
+    if turned:
+        cache_k, cache_v = (jnp.swapaxes(c, 2, 3) for c in (cache_k, cache_v))
+    k_product = "bhd,bhdc->bhc" if turned else "bhd,bhcd->bhc"
+    v_product = "bhc,bhdc->bhd" if turned else "bhc,bhcd->bhd"
+    # the one query position is dropped inside the loop: (b, h[, d]) carries
+    qf = q[:, :, 0].astype(jnp.float32)
+    s_rest = jnp.concatenate([s_ring, s_self[..., None]], axis=-1)[:, :, 0] / scale
+    m = jnp.max(s_rest, axis=-1)  # finite: the self term
+    p = jnp.exp(s_rest - m[..., None])
+    total = jnp.sum(p, axis=-1)
+    acc = (
+        jnp.einsum("bht,bhtd->bhd", p[..., :T].astype(dtype), ring_v,
+                   preferred_element_type=jnp.float32)
+        + p[..., T:] * v[:, :, 0]
+    )
+    # what the mask adds to a row's score, for the loop to cut chunks of
+    unseen = jnp.where(jnp.arange(rows) < ring_base, 0.0, -jnp.inf)
+    # the last chunk of an allocation that is no multiple of the chunk starts
+    # early, and its overlap with the one before must not count
+    ragged = rows % chunk != 0
+
+    def read_chunk(i, carry):
+        m, total, acc = carry
+        first = i * chunk
+        start = jnp.minimum(first, rows - chunk) if ragged else first
+        cut = lambda a, axis=2: jax.lax.dynamic_slice_in_dim(
+            a, start, chunk, axis=axis)
+        cut_kv = lambda c: cut(c, 3 if turned else 2).astype(dtype)
+        sc = jnp.einsum(k_product, qf, cut_kv(cache_k).astype(jnp.float32))
+        if scale_k is not None:
+            sc = sc * cut(scale_k)
+        sc = sc / scale + cut(unseen, 0)
+        if ragged:
+            sc = jnp.where(start + jnp.arange(chunk) >= first, sc, -jnp.inf)
+        m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
+        shrink = jnp.exp(m - m_new)
+        w = jnp.exp(sc - m_new[..., None])
+        total = total * shrink + jnp.sum(w, axis=-1)
+        if scale_v is not None:
+            w = w * cut(scale_v)
+        acc = acc * shrink[..., None] + jnp.einsum(
+            v_product, w.astype(dtype), cut_kv(cache_v),
+            preferred_element_type=jnp.float32)
+        return m_new, total, acc
+
+    _, total, acc = jax.lax.fori_loop(
+        0, (bound + chunk - 1) // chunk, read_chunk, (m, total, acc))
+    return (acc / total[..., None])[:, :, None]
 
 
 class MultiHeadAttention(nn.Module):
@@ -235,6 +341,13 @@ class MultiHeadAttention(nn.Module):
         ``ring_base`` and advance ``ring_base`` by ``decode_block`` every
         ``decode_block`` single-token steps (``models/generate.py``).
 
+        How much of the big cache a single-token step reads is the caller's
+        to say. Given nothing, it reads every row the cache it was handed
+        has (blocked ``generate()`` hands over a cache cut to the rows
+        written so far). Given ``kv_read/rows`` it reads whole chunks as far
+        as that bound and no further (``bounded_cache_attention``;
+        ``SlotKVPool``, whose sequences differ in length, gives the longest).
+
         Under ``kv_quant`` the big cache holds int8 + per-key f32 scales:
         K scales fold into the scores AFTER the int8→dtype einsum, V scales
         fold into the attention weights BEFORE theirs — both reads stream
@@ -318,12 +431,16 @@ class MultiHeadAttention(nn.Module):
             return out.astype(q.dtype)
 
         t = idx - ring_base.value  # slot in the current block, 0..T-1
-        # part 1: completed blocks, read from the big cache (strict mask —
-        # positions >= ring_base live in the ring, big-cache slots there
-        # are stale)
-        s_past = jnp.where(
-            (jnp.arange(self.cache_size) < ring_base.value)[None, None, None, :],
-            big_k_scores(q), -jnp.inf)
+        # a caller that says how far anybody's big cache is live gets a read
+        # that stops there (``bounded_cache_attention``)
+        bounded = self.has_variable(KV_READ, "rows")
+        if not bounded:
+            # part 1: completed blocks, read from the big cache (strict mask
+            # — positions >= ring_base live in the ring, big-cache slots
+            # there are stale)
+            s_past = jnp.where(
+                (jnp.arange(self.cache_size) < ring_base.value)[None, None, None, :],
+                big_k_scores(q), -jnp.inf)
         # part 2: this block's earlier tokens, read from the ring
         s_ring = jnp.einsum(
             "bhsd,bhtd->bhst", q, ring_k.value,
@@ -333,17 +450,26 @@ class MultiHeadAttention(nn.Module):
         # part 3: the fresh token attending to itself
         s_self = jnp.einsum(
             "bhsd,bhsd->bhs", q, k, preferred_element_type=jnp.float32)
-        scores = jnp.concatenate(
-            [s_past, s_ring, s_self[..., None]], axis=-1) / scale
-        probs = jax.nn.softmax(scores, axis=-1)
-        p_dt = probs.astype(self.dtype)
-        out = (
-            big_v_apply(probs[..., : self.cache_size])
-            + jnp.einsum("bhst,bhtd->bhsd",
-                         p_dt[..., self.cache_size: self.cache_size + T],
-                         ring_v.value, preferred_element_type=jnp.float32)
-            + probs[..., self.cache_size + T:].astype(jnp.float32) * v
-        )
+        if bounded:
+            out = bounded_cache_attention(
+                self.get_variable(KV_READ, "rows"), q, v, s_ring, s_self,
+                scale, ring_base.value, ring_v.value,
+                cache_k.value, cache_v.value,
+                *(None if sc is None else sc.value for sc in (scale_k, scale_v)),
+                dtype=self.dtype,
+                turned=_tpu_keeps_rows_minor(*cache_k.value.shape[2:]))
+        else:
+            scores = jnp.concatenate(
+                [s_past, s_ring, s_self[..., None]], axis=-1) / scale
+            probs = jax.nn.softmax(scores, axis=-1)
+            p_dt = probs.astype(self.dtype)
+            out = (
+                big_v_apply(probs[..., : self.cache_size])
+                + jnp.einsum("bhst,bhtd->bhsd",
+                             p_dt[..., self.cache_size: self.cache_size + T],
+                             ring_v.value, preferred_element_type=jnp.float32)
+                + probs[..., self.cache_size + T:].astype(jnp.float32) * v
+            )
         # append by select, not dynamic_update_slice (see ``decode_block``);
         # t is in 0..T-1 inside a block, so exactly one row is replaced
         here = (jnp.arange(T) == t)[None, None, :, None]

@@ -19,9 +19,22 @@ existing ring-buffered blocked decode module over the slot axis**:
 - decode steps write each slot's ring; once per block the rings merge into
   the big caches at PER-SLOT offsets (``merge_ring_caches`` vmapped with a
   traced ``live``), and ``ring_base`` advances — the same amortization
-  that removed the full-cache copies from the decode scan (DESIGN.md §5b),
-  minus the static live-prefix read (slot lengths differ, so reads cover
-  the full allocation under the ``key_pos < ring_base`` mask).
+  that removed the full-cache copies from the decode scan (DESIGN.md §5b);
+- a step reads the K/V rows the pool holds live, not the allocation: slot
+  lengths differ, so no one static length serves them all, but the longest
+  ``ring_base`` among the ACTIVE slots bounds every one of them and does not
+  change inside a block. ``_decode_block_jit`` takes that maximum once a
+  block and hands it to every attention layer as ``kv_read/rows`` (one
+  scalar for the pool, closed over by the vmapped step, so it is not
+  batched), and the layer reads its big cache a chunk at a time, in a loop
+  whose trip count is that bound (``models/transformer.
+  bounded_cache_attention``): a quarter, a half, three quarters or all of
+  ``cache_size``. The loop's body is in the program once, so the program is
+  no larger for it; the ``key_pos < ring_base`` mask hides, per slot, what
+  lies between a slot's own length and the bound. A pool of short requests
+  reads a quarter of the allocation; one slot past three quarters of
+  ``cache_size`` makes every slot's read whole again for as long as it is
+  active.
 
 Admission (prefill) runs per request on a FRESH zeroed lane cache and is
 scattered into the pool at the target slot. That freshness is what makes
@@ -52,9 +65,11 @@ that declared the leaf keeps padded positions out of its state.
 
 Exactness contract (CPU): a request decoded through the pool picks
 token-for-token what a standalone ``generate()`` picks for the same
-``(params, prompt, rng)`` — the attention math is the same module, the
-extra masked cache tail contributes exact zeros, and the sampler consumes
-the same folded keys (tested in ``tests/test_serving.py``).
+``(params, prompt, rng)`` — the attention is the same module over the same
+rows (the masked cache tail contributes exact zeros as far as it is read at
+all; the softmax over chunks adds in another order, to float32 rounding),
+and the sampler consumes the same folded keys (tested in
+``tests/test_serving.py``).
 """
 
 from __future__ import annotations
@@ -76,6 +91,7 @@ from distributed_ml_pytorch_tpu.models.generate import (
     sample_tokens_dynamic,
     split_cache,
 )
+from distributed_ml_pytorch_tpu.models.transformer import KV_READ, kv_read_chunk
 from distributed_ml_pytorch_tpu.utils.tracing import span
 
 
@@ -116,6 +132,26 @@ def replace_cache_leaves(tree, mapping):
         else:
             out[name] = val
     return out
+
+
+def kv_read_hint(cache, rows):
+    """The ``kv_read`` collection that tells every attention layer of
+    ``cache`` (a dict that holds a ``cached_k``) to read ``rows`` rows of its
+    big cache and no more."""
+    hint = {name: kv_read_hint(val, rows)
+            for name, val in cache.items() if isinstance(val, dict)}
+    hint = {name: val for name, val in hint.items() if val}
+    if "cached_k" in cache:
+        hint["rows"] = rows
+    return hint
+
+
+def kv_read_ladder(cache_size: int) -> tuple[int, ...]:
+    """The row counts a bounded read of a ``cache_size``-row cache can stop
+    at: whole chunks, and the allocation itself."""
+    chunk = kv_read_chunk(cache_size)
+    return tuple(min(n * chunk, cache_size)
+                 for n in range(1, -(-cache_size // chunk) + 1))
 
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
@@ -190,9 +226,12 @@ def _decode_block_jit(dec, params, pool, tok, n_gen, seeds,
     Mirrors ``_generate_blocked_jit``'s structure: the big caches cross the
     scan as constants (only the small ring state is carried), appends hit
     the per-layer rings, and the merge amortizes the big-cache write to
-    once per block. Slots where ``active`` is False decode garbage from a
-    zeroed state (their tokens are discarded by the scheduler) and are
-    re-zeroed on exit so their cursors never creep toward the cache edge.
+    once per block. Every step reads the big caches as far as the longest
+    ACTIVE slot reaches, in whole chunks (``kv_read_hint``; the block
+    returns the rows read beside its tokens). Slots where ``active`` is
+    False decode garbage from a zeroed state (their tokens are discarded by
+    the scheduler) and are re-zeroed on exit so their cursors never creep
+    toward the cache edge.
     Token ``g`` of a request is sampled with ``fold_in(key(seed), g)`` —
     the same per-step key schedule ``generate()`` uses, which is what makes
     engine output bit-match a standalone ``generate`` call on CPU. The
@@ -202,10 +241,22 @@ def _decode_block_jit(dec, params, pool, tok, n_gen, seeds,
     T = dec.decode_block
     big, small = split_cache(pool)
     base = find_cache_leaf(small, "ring_base")  # (S,) per-slot block start
+    # ``ring_base`` stands still until the merge below, so the longest among
+    # the active slots bounds the big-cache rows any step of this block can
+    # read (the block's own rows are in the rings): one scalar for the pool
+    live = jnp.where(active, base, 0)
+    cache_size = find_cache_leaf(big, "cached_k").shape[-2]
+    chunk = kv_read_chunk(cache_size)
+    with jax.named_scope("kv_read"):
+        longest = jnp.max(live)
+        read_rows = jnp.minimum(-(-longest // chunk) * chunk, cache_size)
+    hint = kv_read_hint(pool, longest)
 
     def lane_apply(lane_cache, tok1, pos1):
+        # ``hint`` is closed over, so under the vmap it stays one scalar and
+        # the loop it bounds one loop over batched operands
         logits, mutated = dec.apply(
-            {"params": params, "cache": lane_cache},
+            {"params": params, "cache": lane_cache, KV_READ: hint},
             tok1[None, None], pos1[None, None], mutable=["cache"],
         )
         return logits[0, -1], mutated["cache"]
@@ -224,14 +275,13 @@ def _decode_block_jit(dec, params, pool, tok, n_gen, seeds,
     (small, _, _), toks = jax.lax.scan(
         step, (small, tok, jnp.asarray(n_gen, jnp.int32)), None, length=T)
 
-    live = jnp.where(active, base, 0)
     big = jax.vmap(merge_ring_caches)(big, small, live)
     cursor = find_cache_leaf(small, "cursor")
     small = replace_cache_leaves(small, {
         "cursor": jnp.where(active, cursor, 0),
         "ring_base": jnp.where(active, base + T, 0),
     })
-    return join_cache(big, small), jnp.moveaxis(toks, 0, 1)  # [S, T]
+    return join_cache(big, small), jnp.moveaxis(toks, 0, 1), read_rows  # [S, T]
 
 
 class SlotKVPool:
@@ -239,7 +289,9 @@ class SlotKVPool:
     blocked decode module. A slot holds one sequence's whole state: up to
     ``cache_size`` K/V rows in every attention layer and, for a model with
     recurrent layers, each such layer's fixed-size state beside them
-    (:meth:`slot_bytes`).
+    (:meth:`slot_bytes`). A decode step reads, of every slot's K/V rows, as
+    many as the longest ACTIVE slot holds, rounded up to a member of
+    ``read_ladder`` (``last_read_rows``; the module docstring has the rule).
 
     The pool is the compiled data plane; the scheduler
     (``serving/engine.py``) owns which slot belongs to which request. All
@@ -284,6 +336,11 @@ class SlotKVPool:
             kv_quant=self.kv_quant))
         self.cache = jax.tree.map(
             lambda s: jnp.zeros((self.slots,) + s.shape, s.dtype), lane)
+        #: the row counts a decode step's read of the big caches can stop at
+        self.read_ladder = kv_read_ladder(self.cache_size)
+        #: and where the newest decode block's steps stopped (0: no slot was
+        #: active, or no block yet)
+        self.last_read_rows = 0
 
     def admit(self, slot: int, prompt: np.ndarray, real_len: int, *,
               seed: int = 0, temperature: float = 0.0, top_k: int = 0,
@@ -316,9 +373,11 @@ class SlotKVPool:
                           active) -> np.ndarray:
         """Advance every slot by one ``decode_block``-token block; returns
         the sampled tokens ``[slots, decode_block]`` (host array — the
-        fetch is the block's device sync point)."""
+        fetch is the block's device sync point) and leaves in
+        ``last_read_rows`` how many rows of each big cache the block's steps
+        read, as the device counted them."""
         with span("serve.decode.dispatch"):  # enqueues the program
-            self.cache, toks = _decode_block_jit(
+            self.cache, toks, read_rows = _decode_block_jit(
                 self.dec, self.params, self.cache,
                 jnp.asarray(tok, jnp.int32), jnp.asarray(n_gen, jnp.int32),
                 jnp.asarray(seeds, jnp.uint32),
@@ -327,7 +386,9 @@ class SlotKVPool:
                 jnp.asarray(top_ps, jnp.float32),
                 jnp.asarray(active, bool))
         with span("serve.decode.fetch"):  # waits for the device
-            return np.asarray(toks)
+            toks, read_rows = jax.device_get((toks, read_rows))
+        self.last_read_rows = int(read_rows)
+        return toks
 
     def reset_slots(self, slot_indices) -> None:
         """Mark the given slots empty (cursor/ring_base back to 0)."""

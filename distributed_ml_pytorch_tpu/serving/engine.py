@@ -24,8 +24,10 @@ TPOT and queue-wait samples summarized by ``latency_summary`` percentiles,
 decode block latency through a ``StepTimer``, queue depth and slot
 occupancy sampled every scheduling round, and the host time BETWEEN two
 decode blocks with the phase that held the longest one (``slo_summary()``:
-where a host stall shows). Tokens stream at block granularity — per-token
-latency is the block time divided by the block's tokens.
+where a host stall shows), and how far into the allocation the decode
+steps' reads of the K/V caches reached (``kv_read``). Tokens stream at block
+granularity — per-token latency is the block time divided by the block's
+tokens.
 
 Tracing: every round writes ``serve.*`` spans through
 ``utils/tracing.span`` (``serve.step`` > ``serve.prefill`` (one a request,
@@ -203,6 +205,9 @@ class ServingEngine:
         # sampler draw (a temperature) or sort (top-k / top-p besides)
         self._sampler_blocks = {
             "blocks": 0, "sampled_blocks": 0, "filtered_blocks": 0}
+        # decode blocks by the big-cache rows their steps read (the device's
+        # count, ``pool.last_read_rows``)
+        self._kv_read_blocks: collections.Counter = collections.Counter()
 
     # ------------------------------------------------------------- submit
     def submit(self, prompt, max_new_tokens: int, *,
@@ -430,6 +435,7 @@ class ServingEngine:
                 self._tok, self._n_gen, self._seeds, self._temps,
                 self._top_ks, self._top_ps, active)  # [S, T] host array (syncs)
             self._block_timer.tick()
+        self._kv_read_blocks[self.pool.last_read_rows] += 1
         # the device has nothing queued from here to the next dispatch
         self._t_mark = time.perf_counter()
         self._gap = dict.fromkeys(_GAP_PHASES, 0.0)
@@ -532,6 +538,7 @@ class ServingEngine:
         self._prefill_tokens = {"real": 0, "padded": 0}
         self._sampler_blocks = {
             "blocks": 0, "sampled_blocks": 0, "filtered_blocks": 0}
+        self._kv_read_blocks.clear()
 
     def slo_summary(self) -> dict:
         """Percentile SLO report (milliseconds) over everything completed so
@@ -541,6 +548,8 @@ class ServingEngine:
         between = latency_summary(to_ms(self._between))
         if between is not None:
             between["max_phase"] = self._between_max[1]
+        blocks = sum(self._kv_read_blocks.values())
+        rows_read = sum(rows * n for rows, n in self._kv_read_blocks.items())
         return {
             "completed": self._completed,
             "cancelled": self._cancelled,
@@ -563,6 +572,21 @@ class ServingEngine:
             # (the sampler drew random bits), and those of them in which one
             # asked for top-k or top-p (it sorted the vocabulary besides)
             "sampler": dict(self._sampler_blocks),
+            # what the decode steps' reads of the big caches cost against the
+            # allocation: blocks, the mean share of ``cache_size`` their steps
+            # read (the longest active slot, in whole chunks: a member of the
+            # pool's ladder), and the blocks by rows read. A mean near 1
+            # with short requests says one long one holds every slot's read
+            # up; at the first member, that a smaller ``cache_size`` or a
+            # smaller chunk would serve
+            "kv_read": {
+                "blocks": blocks,
+                "rows_share_mean": (
+                    rows_read / (blocks * self.pool.cache_size)
+                    if blocks else 0.0),
+                "by_rows": {rows: self._kv_read_blocks[rows]
+                            for rows in self.pool.read_ladder},
+            },
             # what one slot holds: K/V rows, and recurrent state beside them
             "pool": self.pool.slot_bytes(),
         }

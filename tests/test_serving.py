@@ -8,6 +8,10 @@ token-identical (CPU) to a standalone ``generate()`` with the same
 attention module, so sharing a batch must never leak between requests.
 """
 
+import hashlib
+import json
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,7 +22,17 @@ from distributed_ml_pytorch_tpu.models.generate import (
     sample_tokens,
     sample_tokens_dynamic,
 )
-from distributed_ml_pytorch_tpu.models.transformer import TransformerLM
+from distributed_ml_pytorch_tpu.models.transformer import (
+    KV_READ,
+    TransformerLM,
+    kv_read_chunk,
+)
+from distributed_ml_pytorch_tpu.serving.cache import (
+    SlotKVPool,
+    find_cache_leaf,
+    kv_read_hint,
+    kv_read_ladder,
+)
 from distributed_ml_pytorch_tpu.serving.engine import (
     QueueFullError,
     ServingEngine,
@@ -490,11 +504,17 @@ def _scanned_step(pool):
 
 
 def _sampler_branches(step):
-    """The branches of the scanned step's one conditional, the sampler's, in
-    the order of its index: all greedy, a draw, a filter and a draw."""
-    conds = [eqn for eqn in _equations(step) if eqn.primitive.name == "cond"]
-    assert len(conds) == 1, "the step's one conditional is the sampler's"
-    return [branch.jaxpr for branch in conds[0].params["branches"]]
+    """The branches of the step's sampler, in the order of its index: all
+    greedy, a draw, a filter and a draw. It is found by what it is, a
+    conditional of three branches whose first is an argmax: a step may hold
+    other conditionals."""
+    found = [
+        [branch.jaxpr for branch in eqn.params["branches"]]
+        for eqn in _equations(step) if eqn.primitive.name == "cond"
+        and len(eqn.params["branches"]) == 3]
+    found = [branches for branches in found if _count(branches[0], "argmax")]
+    assert len(found) == 1, "the step samples once"
+    return found[0]
 
 
 def _count(jaxpr, *primitives):
@@ -588,3 +608,334 @@ def test_ring_append_matches_dynamic_update_slice(t, kv_quant, lanes):
             np.testing.assert_array_equal(
                 np.asarray(after[ring]), np.asarray(want))
         assert int(after["cursor"]) == base + t_lane + 1
+
+
+# --- a decode step reads the rows the pool holds live (ISSUE 33) -------------
+
+
+READ_KINDS = ["plain", "kv_quant", "hybrid"]
+
+
+@pytest.fixture(scope="module")
+def hybrid_and_params():
+    from benchmarks.reference import olmo_hybrid as ref
+    from distributed_ml_pytorch_tpu.models.hybrid import HybridLM
+
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "tiny_olmo_hybrid_config.json")
+    with open(path) as fh:
+        config = json.load(fh)  # full-attention layers with QK-norm, float32
+    return HybridLM.from_config(config), ref.make_params(jax.random.key(1), config)
+
+
+@pytest.fixture
+def read_engine(request, lm_and_params, hybrid_and_params):
+    """``make(**kw)`` builds an engine of the requested kind (96 rows, read
+    in chunks of 24), and ``oracle(prompt, n)`` is its generate()."""
+    kind = request.param
+    model, params = hybrid_and_params if kind == "hybrid" else lm_and_params
+    quant = kind == "kv_quant"
+
+    def make(**kw):
+        kw = {"slots": 3, "cache_size": 96, "decode_block": 4,
+              "prefill_bucket": 8, "kv_quant": quant, **kw}
+        return ServingEngine(model, params, **kw)
+
+    make.oracle = lambda prompt, n: ref_tokens(
+        model, params, prompt, n, kv_quant=quant)
+    make.vocab = model.vocab_size
+    return make
+
+
+def test_the_ladder_is_whole_chunks_of_the_allocation():
+    """One rule: a chunk is a quarter of the allocation, rounded up, and a
+    read stops after whole chunks or at the allocation's end."""
+    assert kv_read_ladder(1024) == (256, 512, 768, 1024)
+    assert kv_read_ladder(1536) == (384, 768, 1152, 1536)
+    assert kv_read_ladder(96) == (24, 48, 72, 96)
+    assert kv_read_ladder(100) == (25, 50, 75, 100)
+    assert kv_read_ladder(5) == (2, 4, 5) and kv_read_ladder(1) == (1,)
+    for rows in (1, 5, 96, 100, 1024, 1536):
+        assert kv_read_ladder(rows)[0] == kv_read_chunk(rows)
+
+
+def _big_cache_rows(eqn, pool, head_dim):
+    """Rows of the big cache that a ``dot_general`` has for an operand, or
+    None when neither operand is a big cache or a piece of one."""
+    for var in eqn.invars:
+        shape = var.aval.shape
+        if (len(shape) == 5 and shape[0] == pool.slots and shape[4] == head_dim
+                and shape[3] not in (1, pool.decode_block)):  # a row, a ring
+            return shape[3]
+    return None
+
+
+@pytest.mark.parametrize("read_engine", READ_KINDS, indirect=True)
+def test_decode_step_reads_the_big_caches_in_a_loop_the_pool_bounds(
+        read_engine):
+    """Every attention layer's two products over its big cache sit in the
+    body of ONE loop a layer and read a chunk of 24 rows there; outside
+    those loops no product has a big cache, or a piece of one, for an
+    operand, so nothing reads the allocation. A loop's trip count is one
+    scalar for the pool: its condition works on scalars alone (a per-slot
+    bound under the pool's ``vmap`` would make it an ``any`` over the slots
+    with every carry a select) and compares the counter with the chunks that
+    hold the longest ACTIVE slot."""
+    pool = read_engine().pool
+    chunk = kv_read_chunk(pool.cache_size)
+    head_dim = find_cache_leaf(pool.cache, "cached_k").shape[-1]
+    attn_layers = sum(
+        "cached_k" in path[-1].key
+        for path, _ in jax.tree_util.tree_leaves_with_path(pool.cache))
+    step = _scanned_step(pool)
+    loops = [eqn for eqn in _equations(step) if eqn.primitive.name == "while"]
+    assert len(loops) == attn_layers >= 2 and chunk == 24
+    for loop in loops:
+        body, cond = loop.params["body_jaxpr"].jaxpr, loop.params["cond_jaxpr"].jaxpr
+        reads = [_big_cache_rows(e, pool, head_dim) for e in _equations(body)
+                 if e.primitive.name == "dot_general"]
+        assert reads == [chunk, chunk]  # K and V
+        assert all(v.aval.shape == () for e in cond.eqns for v in e.outvars)
+    in_loops = sum(_count(loop.params["body_jaxpr"].jaxpr, "dot_general")
+                   for loop in loops)
+    outside = [_big_cache_rows(e, pool, head_dim) for e in _equations(step)
+               if e.primitive.name == "dot_general"]
+    assert len([r for r in outside if r is not None]) == in_loops
+    assert outside.count(None) > 0  # the projections, the MLPs, the head
+
+
+def _step_logits(pool, bound=None):
+    """One decode step's logits for every slot of ``pool`` as it stands: the
+    big caches read whole (the module as every other caller runs it), or as
+    far as ``bound`` rows in whole chunks."""
+    hint = {} if bound is None else {
+        KV_READ: kv_read_hint(pool.cache, jnp.asarray(bound, jnp.int32))}
+
+    def lane(lane_cache, tok, pos):
+        logits, _ = pool.dec.apply(
+            {"params": pool.params, "cache": lane_cache, **hint},
+            tok[None, None], pos[None, None], mutable=["cache"])
+        return logits[0, -1]
+
+    toks = jnp.arange(1, pool.slots + 1, dtype=jnp.int32)
+    return np.asarray(jax.vmap(lane)(
+        pool.cache, toks, find_cache_leaf(pool.cache, "cursor")))
+
+
+@pytest.mark.parametrize("longest", [47, 48, 49], ids=["under", "at", "over"])
+@pytest.mark.parametrize("read_engine", READ_KINDS, indirect=True)
+def test_a_read_bounded_at_a_ladder_edge_gives_the_whole_reads_logits(
+        read_engine, longest):
+    """The longest active slot one row under, at, and one row over the edge
+    at 48 rows (the pool reads 48, 48 and 72): every slot's logits by that
+    read are those of the read over all 96 rows, to float32 rounding, and
+    the same token leads."""
+    pool = read_engine().pool
+    rng = prompts_rng(longest)
+    for slot, n in enumerate((longest, 7, 13)):
+        bucket = -(-n // 8) * 8
+        prompt = np.zeros(bucket, np.int32)
+        prompt[:n] = rng.integers(0, read_engine.vocab, size=n)
+        pool.admit(slot, prompt, n)
+    whole, bounded = _step_logits(pool), _step_logits(pool, longest)
+    np.testing.assert_allclose(bounded, whole, rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(bounded.argmax(-1), whole.argmax(-1))
+    assert _block(pool, [True, True, True])[1] == (48 if longest <= 48 else 72)
+
+
+@pytest.mark.parametrize("read_engine", READ_KINDS, indirect=True)
+def test_a_request_decoded_across_a_ladder_edge_matches_generate(read_engine):
+    """A prompt of 40 and 21 tokens in blocks of four starts its blocks at
+    rows 40, 44, 48, 52 and 56: three read 48 rows and two 72, and the
+    tokens are generate()'s."""
+    eng = read_engine(slots=1)
+    prompt = prompts_rng(33).integers(0, read_engine.vocab, size=40)
+    req = eng.submit(prompt, 21)
+    eng.run_until_idle()
+    assert req.tokens == read_engine.oracle(prompt, 21)
+    assert eng.slo_summary()["kv_read"] == {
+        "blocks": 5, "rows_share_mean": (3 * 48 + 2 * 72) / (5 * 96),
+        "by_rows": {24: 0, 48: 3, 72: 2, 96: 0}}
+
+
+@pytest.mark.parametrize("read_engine", READ_KINDS, indirect=True)
+def test_the_last_member_serves_a_slot_one_block_short_of_the_allocation(
+        read_engine):
+    """A request whose last block starts at ``cache_size - decode_block``:
+    the blocks past row 72 read all 96, and the tokens are generate()'s."""
+    eng = read_engine(slots=1)
+    prompt = prompts_rng(34).integers(0, read_engine.vocab, size=56)
+    req = eng.submit(prompt, 41)  # blocks start at 56, 60, ... 92
+    eng.run_until_idle()
+    assert eng.pool.last_read_rows == 96
+    assert req.tokens == read_engine.oracle(prompt, 41)
+    assert eng.slo_summary()["kv_read"]["by_rows"] == {24: 0, 48: 0, 72: 5, 96: 5}
+
+
+def test_an_allocation_that_is_no_multiple_of_the_chunk_counts_no_row_twice(
+        lm_and_params):
+    """100 rows in chunks of 25 divide; 90 rows in chunks of 23 do not, and
+    the last chunk starts at row 67 and overlaps the third: a request that
+    runs into it gets generate()'s tokens."""
+    model, params = lm_and_params
+    eng = make_engine(lm_and_params, slots=1, cache_size=90, decode_block=2)
+    assert eng.pool.read_ladder == (23, 46, 69, 90)
+    prompt = prompts_rng(35).integers(0, VOCAB, size=60)
+    req = eng.submit(prompt, 29)  # blocks start at 60, 62, ... 86
+    eng.run_until_idle()
+    assert req.tokens == ref_tokens(model, params, prompt, 29)
+    assert eng.slo_summary()["kv_read"]["by_rows"] == {23: 0, 46: 0, 69: 5, 90: 9}
+
+
+def _block(pool, active):
+    S = pool.slots
+    toks = pool.decode_block_step(
+        np.ones(S, np.int32), np.ones(S, np.int32), np.zeros(S, np.uint32),
+        np.zeros(S, np.float32), np.zeros(S, np.int32), np.ones(S, np.float32),
+        np.asarray(active, bool))
+    return toks, pool.last_read_rows
+
+
+def test_only_active_slots_raise_the_bound(lm_and_params):
+    """An inactive slot that still holds a long sequence does not raise the
+    bound, whether the engine freed it or not; a pool with no active slot
+    reads no row of any big cache and returns."""
+    pool = make_engine(lm_and_params).pool
+    long, short = np.arange(1, 61, dtype=np.int32), np.arange(1, 9, dtype=np.int32)
+    pool.admit(0, np.pad(long, (0, 4)), 60)
+    pool.admit(1, short, 8)
+    assert _block(pool, [True, True, False])[1] == 72   # 60 rows -> 3 chunks
+    assert _block(pool, [False, True, False])[1] == 24  # slot 0 not asked for
+    toks, rows = _block(pool, [False, False, False])
+    assert rows == 0 and toks.shape == (3, 4)
+    # the inactive slots were re-zeroed on exit, as always: nothing left to raise
+    assert pool.live_lengths().tolist() == [0, 0, 0]
+
+
+def test_a_long_request_admitted_between_two_blocks_is_not_cut_short(
+        lm_and_params):
+    """Block N serves a short request alone and reads 24 rows; a request of
+    60 rows is admitted before block N + 1, which reads 72 for every slot;
+    both get generate()'s tokens, and after the long one's eviction the
+    short one's blocks read fewer rows again."""
+    model, params = lm_and_params
+    eng = make_engine(lm_and_params)
+    short = eng.submit(prompts_rng(40).integers(0, VOCAB, size=5), 30)
+    eng.step()
+    assert eng.pool.last_read_rows == 24
+    long = eng.submit(prompts_rng(41).integers(0, VOCAB, size=60), 6)
+    eng.step()
+    assert eng.pool.last_read_rows == 72
+    eng.run_until_idle()
+    assert eng.pool.last_read_rows == 48  # the short one's last block: row 29
+    assert short.tokens == ref_tokens(model, params, short.prompt, 30)
+    assert long.tokens == ref_tokens(model, params, long.prompt, 6)
+
+
+def test_kv_read_counter_follows_the_requests_lengths(lm_and_params):
+    """``slo_summary()["kv_read"]``: one entry of the histogram a member of
+    the ladder, as many blocks as were dispatched, and the mean share of the
+    allocation what the requests' lengths say: a request alone with a prompt
+    of ``p`` reads, in its block ``j``, the member that holds ``p + 4 j``."""
+    eng = make_engine(lm_and_params, slots=1)
+    ladder = eng.pool.read_ladder
+    empty = eng.slo_summary()["kv_read"]
+    assert empty == {"blocks": 0, "rows_share_mean": 0.0,
+                     "by_rows": dict.fromkeys(ladder, 0)}
+    want = []
+    for p, new in ((5, 10), (70, 9)):  # short, then long, one at a time
+        eng.submit(prompts_rng(p).integers(0, VOCAB, size=p), new)
+        eng.run_until_idle()
+        blocks = -(-(new - 1) // 4)
+        want += [min(m for m in ladder if m >= p + 4 * j) for j in range(blocks)]
+    read = eng.slo_summary()["kv_read"]
+    assert want == [24, 24, 24, 72, 96]
+    assert read["blocks"] == len(want) == eng.slo_summary()["sampler"]["blocks"]
+    assert list(read["by_rows"]) == list(ladder)
+    assert read["by_rows"] == {m: want.count(m) for m in ladder}
+    assert read["rows_share_mean"] == pytest.approx(sum(want) / (len(want) * 96))
+    eng.reset_metrics()
+    assert eng.slo_summary()["kv_read"] == empty
+
+
+def test_kv_read_counter_is_the_devices_count_by_the_hosts_rule(lm_and_params):
+    """Requests that overlap: after every block the count the device returned
+    is the member that holds the longest ACTIVE slot's block start, taken
+    from the lengths the pool itself reports."""
+    eng = make_engine(lm_and_params)
+    ladder = eng.pool.read_ladder
+    rng = prompts_rng(50)
+    for p, new in ((70, 12), (6, 25), (50, 9), (30, 5), (3, 14)):
+        eng.submit(rng.integers(0, VOCAB, size=p), new)
+    seen = []
+    while eng.step():
+        active = [r is not None for r in eng._slot_req]
+        if not any(active):
+            continue
+        start = eng.pool.live_lengths()[active].max() - eng.pool.decode_block
+        assert eng.pool.last_read_rows == min(m for m in ladder if m >= start)
+        seen.append(eng.pool.last_read_rows)
+    read = eng.slo_summary()["kv_read"]
+    assert read["blocks"] == len(seen) and set(seen) == set(ladder)
+    assert read["by_rows"] == {m: seen.count(m) for m in ladder}
+
+
+# sha256 of ``Lowered.as_text()`` on the tree before the bounded read (commit
+# 495d1d4): callers that give the attention module no bound lower to the
+# program they lowered to then. A change that MEANS to alter blocked
+# generate() or the prefill replaces these with the new texts' hashes.
+PARENT_LOWERED = {
+    "generate-plain":
+        "b5754411b6fc199e9db3e432b33053e9f75b04354792df413987577b8a0e4059",
+    "generate-kv_quant":
+        "79ed734f2a4e0d55d8815b4529060149eeb0a44aa9f6a6ebe29b682c89ba56ac",
+    "generate-hybrid":
+        "d1d2743e9985ed27cbc2e4154668ca5aac07280adca238ff40a195700f90d5ad",
+    "admit-plain":
+        "69a6fa971246edce5ece8770ab6c3b350f1ddd2e5b8338d89f7a709c57a5be9e",
+    "admit-kv_quant":
+        "1eca788a9dc958a78c24a2675d728b092bde01b1958f5724a3dbf4a9b7ebb2bd",
+    "admit-hybrid":
+        "a359b0ec62f938419c816ea0ac8c55c82ede45fb7469b55b82e7b9d08d8e296a",
+}
+
+
+def _lowered_sha(program, kind, lm_and_params, hybrid_and_params):
+    from importlib import import_module
+
+    gen = import_module("distributed_ml_pytorch_tpu.models.generate")
+    from distributed_ml_pytorch_tpu.serving.cache import _admit_jit
+
+    model, params = hybrid_and_params if kind == "hybrid" else lm_and_params
+    quant = kind == "kv_quant"
+    shapes = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+    if program == "generate":
+        blocked, padded = gen.uses_block_decode(model, 5, 40)
+        assert blocked
+        cache = jax.eval_shape(lambda: gen.init_cache(
+            model, 2, padded, decode_block=gen.DECODE_BLOCK, kv_quant=quant))
+        dec = gen._decode_model(
+            model, padded, decode_block=gen.DECODE_BLOCK, kv_quant=quant)
+        lowered = gen._generate_blocked_jit.lower(
+            dec, 40, 0.0, 0, 1.0, shapes(params), cache,
+            jax.ShapeDtypeStruct((2, 5), jnp.int32),
+            jax.eval_shape(lambda: jax.random.key(0)))
+    else:
+        pool = SlotKVPool(model, params, slots=3, cache_size=96,
+                          decode_block=4, kv_quant=quant)
+        scalar = lambda dt: jax.ShapeDtypeStruct((), dt)
+        lowered = _admit_jit.lower(
+            pool.dec, shapes(pool.params), shapes(pool.cache),
+            scalar(jnp.int32), jax.ShapeDtypeStruct((1, 8), jnp.int32),
+            scalar(jnp.int32), scalar(jnp.uint32), scalar(jnp.float32),
+            scalar(jnp.int32), scalar(jnp.float32), scalar(jnp.int32))
+    return hashlib.sha256(lowered.as_text().encode()).hexdigest()
+
+
+@pytest.mark.parametrize("kind", READ_KINDS)
+@pytest.mark.parametrize("program", ["generate", "admit"])
+def test_callers_that_give_no_bound_lower_to_the_parents_text(
+        program, kind, lm_and_params, hybrid_and_params):
+    got = _lowered_sha(program, kind, lm_and_params, hybrid_and_params)
+    assert got == PARENT_LOWERED[f"{program}-{kind}"]

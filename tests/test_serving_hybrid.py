@@ -173,6 +173,12 @@ def test_serve_cli_builds_the_model_from_a_published_configuration(tmp_path, cap
     metrics = json.loads(dump.read_text())
     assert metrics["engine.pool"]["state_bytes_per_slot"] == 6 * (2 * 16 * 8 * 4 + 3 * 64 * 4)
     assert metrics["engine.prefill_tokens"]["real"] > 0
+    # the decode blocks by the K/V rows their steps read, in chunks of a
+    # quarter of the 64: the full-attention layers of this pool too
+    read = metrics["engine.kv_read"]
+    assert list(read["by_rows"]) == ["16", "32", "48", "64"]
+    assert sum(read["by_rows"].values()) == read["blocks"] > 0
+    assert 0.25 <= read["rows_share_mean"] < 1.0
 
 
 def test_serve_cli_refuses_a_model_type_it_cannot_build(tmp_path):

@@ -213,3 +213,47 @@ def test_ring_flash_attention_compiles_on_four_chips(v5e, chip_dispatch):
         return (out,) + pull(out)
 
     assert_kernels(compiled_text(f, q, q, q), 2)
+
+
+@pytest.mark.parametrize("head_dim,rows", [(64, 1024), (128, 1536)],
+                         ids=["head-64", "head-128"])
+def test_bounded_cache_read_copies_no_cache_leaf(v5e, chip_dispatch, head_dim, rows):
+    """The pool's decode block at the two serving cells' head size and cache
+    rows (32 slots, two ``TransformerLM`` layers, the rest small). The device
+    keeps a cache of 64-wide heads with its rows along the lanes, and a loop
+    takes its operands in the layout their shape has by default: handed over
+    the wrong way round, every big cache is copied whole (21 MB each here,
+    twice that padded). The compiled program holds no temporary of that
+    order, and its one conditional is the sampler's: the bounded read is a
+    loop a layer and multiplies no code."""
+    from distributed_ml_pytorch_tpu.models.generate import (
+        _decode_model,
+        _fuse_qkv_params,
+        init_cache,
+    )
+    from distributed_ml_pytorch_tpu.models.transformer import TransformerLM
+    from distributed_ml_pytorch_tpu.serving.cache import _decode_block_jit
+
+    one = SingleDeviceSharding(v5e[0])
+    heads, slots, layers = 4, 32, 2
+    lm = TransformerLM(vocab_size=512, d_model=heads * head_dim, n_heads=heads,
+                       n_layers=layers, d_ff=256, max_len=rows, dtype=jnp.bfloat16)
+    dec = _decode_model(lm, rows, decode_block=16)
+    placed = lambda tree, lead=(): jax.tree.map(
+        lambda a: on(one, lead + a.shape, a.dtype), tree)
+    params = placed(jax.eval_shape(lambda: _fuse_qkv_params(jax.tree.map(
+        lambda a: a.astype(jnp.bfloat16),
+        lm.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]))))
+    pool = placed(jax.eval_shape(
+        lambda: init_cache(lm, 1, rows, decode_block=16)), (slots,))
+    leaf = 2 * slots * heads * rows * head_dim  # one big cache, bytes
+    vec = lambda dt: on(one, (slots,), dt)
+    compiled = _decode_block_jit.lower(
+        dec, params, pool, vec(jnp.int32), vec(jnp.int32), vec(jnp.uint32),
+        vec(jnp.float32), vec(jnp.int32), vec(jnp.float32),
+        vec(jnp.bool_)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf
+    text = compiled.as_text()
+    assert text.count(" conditional(") == 1
+    # the scan, the merge's four scatter loops, and the read's one a layer
+    assert text.count(" while(") == 1 + 4 + layers
