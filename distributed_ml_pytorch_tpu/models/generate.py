@@ -251,10 +251,20 @@ DECODE_BLOCK = 16
 MAX_UNROLLED_BLOCKS = 64
 
 
+#: the collection a module writes what it counted into (an expert layer's
+#: choices per expert); a caller that makes it mutable gets the counts back
+#: with the call (``serving/cache.py`` does, in both of its programs)
+COUNTERS = "counters"
+
+#: the cache leaves that are BIG: one row a cached position (per-head K and V,
+#: the int8 cache's scales, or ``models/latent_moe.py``'s one latent row)
+BIG_CACHE_LEAVES = ("cached_k", "cached_v", "scale_k", "scale_v", "cached_latent")
+
+
 def split_cache(cache):
     """Split a decode cache pytree into (big, small): the per-layer big K/V
-    caches vs everything else (rings, cursors, ring_base). The big part is
-    closed over as a CONSTANT by the blocked scan's inner loop — carrying it
+    (or latent-row) caches vs everything else (rings, cursors, ring_base).
+    The big part is closed over as a CONSTANT by the blocked scan's inner loop — carrying it
     would reintroduce the per-step full-cache copies the ring exists to
     avoid. Public: the serving slot pool (``serving/cache.py``) splits its
     stacked per-slot caches with the same name-based rule."""
@@ -266,7 +276,7 @@ def split_cache(cache):
                 big[name] = b
             if s:
                 small[name] = s
-        elif name in ("cached_k", "cached_v", "scale_k", "scale_v"):
+        elif name in BIG_CACHE_LEAVES:
             big[name] = val
         else:
             small[name] = val
@@ -569,6 +579,9 @@ def merge_ring_caches(big, small, live):
         out["cached_v"] = jax.lax.dynamic_update_slice(
             big["cached_v"], rv, (0, 0, live, 0))
         return out
+    if "cached_latent" in big:  # one shared row a position: ring over cache alike
+        return dict(big, cached_latent=jax.lax.dynamic_update_slice(
+            big["cached_latent"], small["ring_latent"], (0, 0, live, 0)))
     return {
         name: (merge_ring_caches(val, small.get(name, {}), live)
                if isinstance(val, dict) else val)

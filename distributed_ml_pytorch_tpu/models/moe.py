@@ -29,7 +29,10 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from jax.experimental.pallas.ops.tpu.megablox import gmm as _gmm
 
+from distributed_ml_pytorch_tpu.models.generate import COUNTERS
+from distributed_ml_pytorch_tpu.models.hybrid import GatedFFN
 from distributed_ml_pytorch_tpu.models.transformer import MultiHeadAttention
 
 
@@ -258,3 +261,175 @@ class MoETransformerLM(nn.Module):
             )(x, positions)
         x = nn.LayerNorm(dtype=self.dtype)(x)
         return nn.Dense(self.vocab_size, use_bias=False, dtype=self.dtype, name="lm_head")(x)
+
+
+# --------------------------------------------------------------------------
+# Dropless routed experts (sigmoid scores, a selection bias, top-k of many,
+# shared experts): the expert layer of ``models/latent_moe.py``.
+
+def route_topk_sigmoid(x, w_router, bias, k: int, scale: float):
+    """``(idx [n, k], weights [n, k] float32, scores [n, E])`` for rows ``x``
+    ``[n, d]``: scores are ``sigmoid(W_g x)`` in float32 on a float32 copy of
+    ``x``; the ``k`` largest of ``scores + bias`` are chosen; their weights are
+    the scores WITHOUT the bias, divided by their sum and times ``scale``."""
+    logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    weights = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20) * scale
+    return idx, weights, scores
+
+
+#: rows a tile of the TPU's grouped-matmul kernel holds: a group of fewer rows
+#: still costs a whole tile, and an admission's groups are 50-120 rows
+GROUP_TILE_ROWS = 128
+
+
+def _whole_or_tile(n: int, cap: int = 2048) -> int:
+    """``n`` if it fits a tile, else its largest divisor that is a multiple of
+    128 and at most ``cap``."""
+    return n if n <= cap else max(t for t in range(128, cap + 1, 128) if n % t == 0)
+
+
+def grouped_dot(rows, w, sizes, out_dtype):
+    """``rows`` ``[m, k]``, sorted by group, times each row's own group's
+    matrix of ``w`` ``[g, k, n]``; ``sizes`` ``[g]`` sums to ``m``. On a TPU,
+    for bfloat16 operands whose shapes tile, the Pallas grouped-matmul kernel
+    of ``jax.experimental`` (megablox ``gmm``) with row tiles of
+    ``GROUP_TILE_ROWS``: ``jax.lax.ragged_dot`` compiles there to a kernel with
+    tiles of 512 rows, which an admission's groups fill a fifth of (PR 35: 30
+    of an admission's 50 ms). Everywhere else ``jax.lax.ragged_dot``."""
+    (m, k), n = rows.shape, w.shape[-1]
+    w = w.astype(rows.dtype)
+    if (jax.default_backend() == "tpu" and rows.dtype == jnp.bfloat16
+            and m % GROUP_TILE_ROWS == 0 and k % 128 == 0 and n % 128 == 0):
+        return _gmm(rows, w, sizes, preferred_element_type=out_dtype,
+                    tiling=(GROUP_TILE_ROWS, _whole_or_tile(k), _whole_or_tile(n)))
+    return jax.lax.ragged_dot(rows, w, sizes, preferred_element_type=out_dtype)
+
+
+def grouped_experts(x, idx, weights, w_gate, w_up, w_down):
+    """``sum_j weights[:, j] * E_idx[:, j](x)`` for rows ``x`` ``[n, d]`` as
+    grouped products over the ``n * k`` (row, choice) pairs sorted by expert
+    (:func:`grouped_dot`: each expert's weights multiply its own rows and no
+    others, however uneven the groups; an expert nobody chose has an empty
+    group). No capacity and no drop. Returns float32 ``[n, d]``."""
+    n, k = idx.shape
+    e = w_gate.shape[0]
+    flat = idx.reshape(-1)
+    order = jnp.argsort(flat, stable=True)          # pairs by expert
+    rows = x[order // k]                            # the pair's token row
+    sizes = jnp.sum(jax.nn.one_hot(flat, e, dtype=jnp.int32), axis=0)
+    hidden = (nn.silu(grouped_dot(rows, w_gate, sizes, rows.dtype))
+              * grouped_dot(rows, w_up, sizes, rows.dtype))
+    out = grouped_dot(hidden, w_down, sizes, jnp.float32) * weights.reshape(-1)[order][:, None]
+    # back to token order: pair p of the sorted list is at argsort(order)[p]
+    return jnp.sum(out[jnp.argsort(order)].reshape(n, k, -1), axis=1)
+
+
+def stacked_experts(x, idx, weights, w_gate, w_up, w_down):
+    """The same sum as :func:`grouped_experts` by the stacked products over
+    ALL experts with the weights as a mask (``td,edf->tef``, ``tef,efd->td``).
+    For a decode step: ``SlotKVPool`` vmaps the model over its slots, so a
+    step sees ONE row here and a sort or a gather of that row's experts would
+    be batched into one copy of the weights a slot; these products batch into
+    one product that reads each expert's weights once for the whole pool."""
+    e = w_gate.shape[0]
+    mask = jnp.sum(jax.nn.one_hot(idx, e, dtype=jnp.float32) * weights[..., None], axis=1)
+    up = lambda w: jnp.einsum("td,edf->tef", x, w.astype(x.dtype))
+    hidden = nn.silu(up(w_gate)) * up(w_up) * mask[..., None].astype(x.dtype)
+    return jnp.einsum("tef,efd->td", hidden, w_down.astype(x.dtype),
+                      preferred_element_type=jnp.float32)
+
+
+class SigmoidTopKRouter(nn.Module):
+    """:func:`route_topk_sigmoid` with its two parameters: ``kernel`` (``W_g``)
+    and ``bias`` (float32; it moves the choice and never a weight; training
+    adjusts it and no gradient reaches it). A module of its own so that a
+    caller can read the choices of a pass (``capture_intermediates``).
+    Returns ``(idx [n, k], weights [n, k] float32)``."""
+
+    n_experts: int
+    top_k: int
+    scale: float = 1.0
+
+    @nn.compact
+    def __call__(self, rows):
+        d = rows.shape[-1]
+        kernel = self.param("kernel", nn.initializers.normal(1.0 / d ** 0.5),
+                            (d, self.n_experts))
+        bias = self.param("bias", nn.initializers.zeros, (self.n_experts,), jnp.float32)
+        idx, weights, _ = route_topk_sigmoid(rows, kernel, bias, self.top_k, self.scale)
+        return idx, weights
+
+
+class DroplessExperts(nn.Module):
+    """``y = sum_e w_e E_e(x) + S(x)``: ``top_k`` of ``n_experts`` gated SiLU
+    FFNs ``d_expert`` wide chosen per token by :class:`SigmoidTopKRouter`
+    (module ``router``), every chosen pair computed, plus ONE gated FFN
+    ``n_shared * d_expert`` wide for every token.
+
+    A call of more than one token takes :func:`grouped_experts`; a decode step
+    (``decode`` and one token) takes :func:`stacked_experts`. Both route a
+    padded or idle row like any other: no row competes with another for
+    anything, so such rows change no real row's result.
+
+    **Counts.** Where the caller makes the ``"counters"`` collection mutable
+    the layer writes ``expert_choices`` ``[n_experts]`` there: how often each
+    expert was chosen by this call's REAL rows. With ``decode`` it declares
+    the cache leaf ``prefill_len`` as ``models/hybrid.GatedDeltaNet`` does: a
+    caller that pads a prefill writes the true length there first
+    (``serving/cache._admit_jit`` does, by name) and rows at or past it are not
+    counted; left at 0 every row counts.
+    """
+
+    d_model: int
+    d_expert: int
+    n_experts: int
+    top_k: int
+    n_shared: int = 0
+    routed_scale: float = 1.0
+    dtype: jnp.dtype = jnp.float32
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, d = x.shape
+        e, f = self.n_experts, self.d_expert
+        rows = x.reshape(b * s, d)
+        stacked = nn.initializers.lecun_normal(batch_axis=(0,))
+        w_gate = self.param("w_gate", stacked, (e, d, f))
+        w_up = self.param("w_up", stacked, (e, d, f))
+        w_down = self.param("w_down", stacked, (e, f, d))
+
+        with jax.named_scope("moe/router"):
+            idx, weights = SigmoidTopKRouter(
+                e, self.top_k, self.routed_scale, name="router")(rows)
+            self._count(idx, b, s)
+        step = self.decode and s == 1
+        with jax.named_scope("moe/experts"):
+            routed = (stacked_experts if step else grouped_experts)(
+                rows, idx, weights, w_gate, w_up, w_down)
+        out = routed.astype(self.dtype).reshape(b, s, d)
+        if self.n_shared:
+            with jax.named_scope("moe/shared"):
+                out = out + GatedFFN(d, self.n_shared * f, self.dtype, name="shared")(x)
+        return out
+
+    def _count(self, idx, b, s):
+        n_valid = None
+        if self.decode:
+            prefill_len = self.variable(
+                "cache", "prefill_len", lambda: jnp.zeros((), jnp.int32))
+            if s != 1:
+                n_valid = jnp.where(prefill_len.value > 0, prefill_len.value, s)
+            prefill_len.value = jnp.zeros((), jnp.int32)
+        if not self.is_mutable_collection(COUNTERS):
+            return
+        chosen = jax.nn.one_hot(idx, self.n_experts, dtype=jnp.int32).sum(axis=1)
+        if n_valid is not None:
+            real = jnp.tile(jnp.arange(s) < n_valid, b)
+            chosen = jnp.where(real[:, None], chosen, 0)
+        self.variable(COUNTERS, "expert_choices",
+                      lambda: jnp.zeros((self.n_experts,), jnp.int32)).value = chosen.sum(axis=0)

@@ -49,19 +49,38 @@ garbage until decode merges overwrite it.
 
 **What a slot holds.** Whatever the model's ``"cache"`` collection declares
 for one sequence. For ``TransformerLM`` that is K/V rows (big cache and ring)
-and two cursors a layer. A model with recurrent layers (``models/hybrid.py``)
-adds leaves that are not K/V: a linear-attention layer's ``state`` (float32,
-fixed size however long the sequence is) and ``conv_tail``. Nothing here
-branches on the model's kind; the leaves are told apart by name:
-``split_cache`` puts everything that is not a big K/V cache with the small
-leaves the decode scan carries (right for a state: every step rewrites it
-whole), the lane scatter and ``slot_kv`` take every leaf, and a freed slot's
-state is simply overwritten by the next occupant's fresh lane. The padded
-prefill is the one place a recurrence needs help: it has no mask to hide
-padding behind, so ``_admit_jit`` writes the prompt's true length into every
-cache leaf called ``prefill_len`` before the prefill (a tree without that leaf,
-``TransformerLM``'s, is untouched and compiles to what it did), and the mixer
-that declared the leaf keeps padded positions out of its state.
+and two cursors a layer. A model with latent attention
+(``models/latent_moe.py``) holds, in place of per-head K and V, ONE row a
+position for all heads: the compressed latent its heads' keys and values are
+made from, followed by the one rotated key they share (``cached_latent`` and
+``ring_latent``: 576 values where K and V would be 10,240 at that model's
+published sizes). It rides the same protocol under its own leaf names: a big
+cache the decode scan closes over, a ring the steps append to by a select, one
+merge a block, the read bounded by ``kv_read/rows``. A model with recurrent
+layers (``models/hybrid.py``) adds leaves that are not rows at all: a
+linear-attention layer's ``state`` (float32, fixed size however long the
+sequence is) and ``conv_tail``. Nothing here branches on the model's kind; the
+leaves are told apart by name: ``split_cache`` puts the big row caches
+(``models/generate.BIG_CACHE_LEAVES``) on one side and everything else with
+the small leaves the decode scan carries (right for a state: every step
+rewrites it whole), ``merge_ring_caches`` merges the ring that goes with the
+big leaf it finds, the lane scatter and ``slot_kv`` take every leaf, and a
+freed slot's state is simply overwritten by the next occupant's fresh lane.
+The padded prefill is the one place a model needs help: a recurrence has no
+mask to hide padding behind, and an expert layer must not count the padding's
+rows, so ``_admit_jit`` writes the prompt's true length into every cache leaf
+called ``prefill_len`` before the prefill (a tree without that leaf,
+``TransformerLM``'s, is untouched and compiles to what it did), and the
+module that declared the leaf keeps padded positions out of its state or its
+counts.
+
+**What a model counts.** A model may write counts into a ``"counters"``
+collection (``models/generate.COUNTERS``: an expert layer's choices per expert).
+Both programs make that collection mutable and return it with their tokens:
+an admission's as the model wrote it, a decode block's summed over the ACTIVE
+slots inside the program, a row a step (under the vmap a counter is a slot
+each). A model that declares none returns an empty tree and compiles to what
+it did. ``SlotKVPool.last_counters`` holds the newest, fetched with the tokens.
 
 Exactness contract (CPU): a request decoded through the pool picks
 token-for-token what a standalone ``generate()`` picks for the same
@@ -82,6 +101,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from distributed_ml_pytorch_tpu.models.generate import (
+    BIG_CACHE_LEAVES,
+    COUNTERS,
     DECODE_BLOCK,
     _decode_model,
     _fuse_qkv_params,
@@ -95,8 +116,14 @@ from distributed_ml_pytorch_tpu.models.transformer import KV_READ, kv_read_chunk
 from distributed_ml_pytorch_tpu.utils.tracing import span
 
 
-#: the cache leaves that are K/V rows (big caches, rings, int8 scales)
-_KV_LEAVES = ("cached_k", "cached_v", "ring_k", "ring_v", "scale_k", "scale_v")
+#: the cache leaves that are a latent-attention layer's rows (big cache and
+#: ring): ONE row a position for all heads, the compressed latent the heads'
+#: keys and values are made from followed by the one rotated key they share
+#: (``models/latent_moe.py``); told apart from K/V rows by these names alone
+_LATENT_LEAVES = ("cached_latent", "ring_latent")
+#: the cache leaves that are rows of cached positions: per-head K and V (big
+#: caches, rings, int8 scales) and latent rows
+_KV_LEAVES = ("cached_k", "cached_v", "ring_k", "ring_v", "scale_k", "scale_v") + _LATENT_LEAVES
 
 
 def find_cache_leaf(tree, name: str):
@@ -136,14 +163,23 @@ def replace_cache_leaves(tree, mapping):
 
 def kv_read_hint(cache, rows):
     """The ``kv_read`` collection that tells every attention layer of
-    ``cache`` (a dict that holds a ``cached_k``) to read ``rows`` rows of its
-    big cache and no more."""
+    ``cache`` (a dict that holds a big cache leaf: ``cached_k``, or
+    ``cached_latent``) to read ``rows`` rows of its big cache and no more."""
     hint = {name: kv_read_hint(val, rows)
             for name, val in cache.items() if isinstance(val, dict)}
     hint = {name: val for name, val in hint.items() if val}
-    if "cached_k" in cache:
+    if any(name in BIG_CACHE_LEAVES for name in cache):
         hint["rows"] = rows
     return hint
+
+
+def flat_counters(tree, prefix: str = "") -> dict:
+    """A ``"counters"`` collection as ``{"layer_1/moe/expert_choices": leaf}``."""
+    out = {}
+    for name, val in tree.items():
+        path = f"{prefix}/{name}" if prefix else name
+        out.update(flat_counters(val, path) if isinstance(val, dict) else {path: val})
+    return out
 
 
 def kv_read_ladder(cache_size: int) -> tuple[int, ...]:
@@ -159,7 +195,9 @@ def _admit_jit(dec, params, pool, slot, prompt, real_len, seed,
                temperature, top_k, top_p, gen_offset):
     """Prefill ``prompt`` ([1, bucket] int32, right-padded past ``real_len``)
     on a fresh lane cache, sample the request's first token, and scatter the
-    lane into ``pool`` at ``slot``. Returns ``(pool, first_token)``.
+    lane into ``pool`` at ``slot``. Returns ``(pool, first_token, counters)``:
+    ``counters`` is what the model wrote into its ``"counters"`` collection
+    over the prompt's real rows (empty for a model that declares none).
 
     ``gen_offset`` is the request's position in its own sampling-key
     schedule: token ``g`` is always drawn with ``fold_in(key(seed), g)``,
@@ -175,7 +213,8 @@ def _admit_jit(dec, params, pool, slot, prompt, real_len, seed,
     bucket = prompt.shape[1]
     positions = jnp.arange(bucket)[None, :]
     logits, mutated = dec.apply(
-        {"params": params, "cache": lane}, prompt, positions, mutable=["cache"]
+        {"params": params, "cache": lane}, prompt, positions,
+        mutable=["cache", COUNTERS]
     )
     # rewind cursor/ring_base from the padded bucket end to the true prompt
     # length: the pad region's K/V is garbage the ``key_pos < ring_base``
@@ -192,7 +231,7 @@ def _admit_jit(dec, params, pool, slot, prompt, real_len, seed,
         lambda P, L: jax.lax.dynamic_update_slice(
             P, L[None], (slot,) + (0,) * L.ndim),
         pool, lane)
-    return pool, tok0.astype(jnp.int32)
+    return pool, tok0.astype(jnp.int32), mutated.get(COUNTERS, {})
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -228,7 +267,9 @@ def _decode_block_jit(dec, params, pool, tok, n_gen, seeds,
     the per-layer rings, and the merge amortizes the big-cache write to
     once per block. Every step reads the big caches as far as the longest
     ACTIVE slot reaches, in whole chunks (``kv_read_hint``; the block
-    returns the rows read beside its tokens). Slots where ``active`` is
+    returns the rows read beside its tokens, and whatever the model counted
+    (its ``"counters"`` collection, a slot each under the vmap) summed over the
+    ACTIVE slots, a row a step). Slots where ``active`` is
     False decode garbage from a zeroed state (their tokens are discarded by
     the scheduler) and are re-zeroed on exit so their cursors never creep
     toward the cache edge.
@@ -245,7 +286,7 @@ def _decode_block_jit(dec, params, pool, tok, n_gen, seeds,
     # the active slots bounds the big-cache rows any step of this block can
     # read (the block's own rows are in the rings): one scalar for the pool
     live = jnp.where(active, base, 0)
-    cache_size = find_cache_leaf(big, "cached_k").shape[-2]
+    cache_size = dec.cache_size
     chunk = kv_read_chunk(cache_size)
     with jax.named_scope("kv_read"):
         longest = jnp.max(live)
@@ -257,22 +298,25 @@ def _decode_block_jit(dec, params, pool, tok, n_gen, seeds,
         # the loop it bounds one loop over batched operands
         logits, mutated = dec.apply(
             {"params": params, "cache": lane_cache, KV_READ: hint},
-            tok1[None, None], pos1[None, None], mutable=["cache"],
+            tok1[None, None], pos1[None, None], mutable=["cache", COUNTERS],
         )
-        return logits[0, -1], mutated["cache"]
+        return logits[0, -1], mutated["cache"], mutated.get(COUNTERS, {})
 
     def step(carry, _):
         small, tok, g = carry
         cursor = find_cache_leaf(small, "cursor")  # (S,) = absolute position
-        logits, cache = jax.vmap(lane_apply)(join_cache(big, small), tok, cursor)
+        logits, cache, counted = jax.vmap(lane_apply)(join_cache(big, small), tok, cursor)
+        counted = jax.tree.map(
+            lambda c: jnp.sum(jnp.where(active.reshape((-1,) + (1,) * (c.ndim - 1)), c, 0),
+                              axis=0), counted)
         _, small = split_cache(cache)
         keys = jax.vmap(
             lambda s, i: jax.random.fold_in(jax.random.key(s), i))(seeds, g)
         nxt = sample_tokens_dynamic(
             logits, keys, temps, top_ks, top_ps, active).astype(jnp.int32)
-        return (small, nxt, g + 1), nxt
+        return (small, nxt, g + 1), (nxt, counted)
 
-    (small, _, _), toks = jax.lax.scan(
+    (small, _, _), (toks, counted) = jax.lax.scan(
         step, (small, tok, jnp.asarray(n_gen, jnp.int32)), None, length=T)
 
     big = jax.vmap(merge_ring_caches)(big, small, live)
@@ -281,7 +325,7 @@ def _decode_block_jit(dec, params, pool, tok, n_gen, seeds,
         "cursor": jnp.where(active, cursor, 0),
         "ring_base": jnp.where(active, base + T, 0),
     })
-    return join_cache(big, small), jnp.moveaxis(toks, 0, 1), read_rows  # [S, T]
+    return join_cache(big, small), jnp.moveaxis(toks, 0, 1), read_rows, counted  # [S, T]
 
 
 class SlotKVPool:
@@ -341,6 +385,9 @@ class SlotKVPool:
         #: and where the newest decode block's steps stopped (0: no slot was
         #: active, or no block yet)
         self.last_read_rows = 0
+        #: what the model counted in the newest admission or decode block
+        #: (``flat_counters``; a decode block's leaves have a row a step)
+        self.last_counters: dict = {}
 
     def admit(self, slot: int, prompt: np.ndarray, real_len: int, *,
               seed: int = 0, temperature: float = 0.0, top_k: int = 0,
@@ -358,7 +405,7 @@ class SlotKVPool:
             raise ValueError(
                 "admit() needs a prompt of length >= 2 — pad 1-token "
                 "prompts (a length-1 apply is a decode step, not a prefill)")
-        self.cache, tok0 = _admit_jit(
+        self.cache, tok0, counted = _admit_jit(
             self.dec, self.params, self.cache,
             jnp.asarray(slot, jnp.int32), prompt,
             jnp.asarray(real_len, jnp.int32),
@@ -367,6 +414,8 @@ class SlotKVPool:
             jnp.asarray(top_k, jnp.int32),
             jnp.asarray(top_p, jnp.float32),
             jnp.asarray(gen_offset, jnp.int32))
+        tok0, counted = jax.device_get((tok0, counted))  # one fetch
+        self.last_counters = flat_counters(counted)
         return int(tok0)
 
     def decode_block_step(self, tok, n_gen, seeds, temps, top_ks, top_ps,
@@ -377,7 +426,7 @@ class SlotKVPool:
         ``last_read_rows`` how many rows of each big cache the block's steps
         read, as the device counted them."""
         with span("serve.decode.dispatch"):  # enqueues the program
-            self.cache, toks, read_rows = _decode_block_jit(
+            self.cache, toks, read_rows, counted = _decode_block_jit(
                 self.dec, self.params, self.cache,
                 jnp.asarray(tok, jnp.int32), jnp.asarray(n_gen, jnp.int32),
                 jnp.asarray(seeds, jnp.uint32),
@@ -386,8 +435,9 @@ class SlotKVPool:
                 jnp.asarray(top_ps, jnp.float32),
                 jnp.asarray(active, bool))
         with span("serve.decode.fetch"):  # waits for the device
-            toks, read_rows = jax.device_get((toks, read_rows))
+            toks, read_rows, counted = jax.device_get((toks, read_rows, counted))
         self.last_read_rows = int(read_rows)
+        self.last_counters = flat_counters(counted)
         return toks
 
     def reset_slots(self, slot_indices) -> None:
@@ -413,17 +463,21 @@ class SlotKVPool:
 
     def slot_bytes(self) -> dict:
         """Bytes one slot holds, from the cache tree's leaves:
-        ``kv_bytes_per_slot`` (big caches, rings, int8 scales) and
+        ``kv_bytes_per_slot`` (rows of cached positions: big caches, rings,
+        int8 scales), ``latent_bytes_per_slot`` (those of them that are latent
+        rows: 0 for a model that caches per-head K and V) and
         ``state_bytes_per_slot`` (every other floating leaf: a recurrent
         layer's state and convolution tail; 0 for an attention-only model)."""
-        kv = state = 0
+        kv = latent = state = 0
         for path, leaf in jax.tree_util.tree_leaves_with_path(self.cache):
             size = leaf.dtype.itemsize * int(np.prod(leaf.shape[1:]))
             if path[-1].key in _KV_LEAVES:
                 kv += size
+                latent += size * (path[-1].key in _LATENT_LEAVES)
             elif jnp.issubdtype(leaf.dtype, jnp.floating):
                 state += size
-        return {"kv_bytes_per_slot": kv, "state_bytes_per_slot": state}
+        return {"kv_bytes_per_slot": kv, "latent_bytes_per_slot": latent,
+                "state_bytes_per_slot": state}
 
     def live_lengths(self) -> np.ndarray:
         """Per-slot live sequence length (prompt + generated), from the

@@ -11,7 +11,8 @@ over a messaging transport::
 
     # a model from a published configuration file (a Hugging Face
     # ``config.json``; ``model_type`` ``olmo_hybrid`` builds
-    # ``models/hybrid.HybridLM``), seeded random weights
+    # ``models/hybrid.HybridLM``, ``deepseek_v3``
+    # ``models/latent_moe.LatentMoELM``), seeded random weights
     python -m distributed_ml_pytorch_tpu.serving.cli --model-config config.json --demo 4
 
     # self-contained demo: an in-process client drives N mixed
@@ -52,7 +53,8 @@ def build_parser() -> argparse.ArgumentParser:
                    choices=["learned", "rope"])
     p.add_argument("--model-config", type=str, default="", metavar="PATH",
                    help="build the model from a published config.json "
-                        "(model_type olmo_hybrid -> models/hybrid.HybridLM) "
+                        "(model_type olmo_hybrid -> models/hybrid.HybridLM, "
+                        "deepseek_v3 -> models/latent_moe.LatentMoELM) "
                         "instead of the size flags above; --dtype applies")
     p.add_argument("--ckpt-dir", type=str, default="",
                    help="restore params from an examples/train_lm.py orbax "
@@ -185,8 +187,10 @@ def _model_from_config(path: str, dtype, parser):
     import json
 
     from distributed_ml_pytorch_tpu.models.hybrid import HybridLM
+    from distributed_ml_pytorch_tpu.models.latent_moe import LatentMoELM
 
-    builders = {"olmo_hybrid": HybridLM.from_config}
+    builders = {"olmo_hybrid": HybridLM.from_config,
+                "deepseek_v3": LatentMoELM.from_config}
     with open(path) as fh:
         cfg = json.load(fh)
     kind = cfg.get("model_type")

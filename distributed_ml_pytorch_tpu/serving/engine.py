@@ -25,7 +25,9 @@ decode block latency through a ``StepTimer``, queue depth and slot
 occupancy sampled every scheduling round, and the host time BETWEEN two
 decode blocks with the phase that held the longest one (``slo_summary()``:
 where a host stall shows), and how far into the allocation the decode
-steps' reads of the K/V caches reached (``kv_read``). Tokens stream at block
+steps' reads of the K/V caches reached (``kv_read``), and whatever the model
+counted on the device, returned with the tokens of an admission or a block
+(``model_counters``: an expert layer's choices per expert). Tokens stream at block
 granularity — per-token latency is the block time divided by the block's
 tokens.
 
@@ -33,7 +35,9 @@ Tracing: every round writes ``serve.*`` spans through
 ``utils/tracing.span`` (``serve.step`` > ``serve.prefill`` (one a request,
 with ``request_id``, ``queue_wait_us``, ``prompt_tokens`` and
 ``bucket_tokens``), ``serve.decode`` >
-``serve.decode.dispatch|fetch``, ``serve.emit``) into whatever profile is
+``serve.decode.dispatch|fetch``, ``serve.emit``; for a model that counts,
+``serve.prefill.counters`` and ``serve.decode.counters`` with each counter's
+sum and its entries that were not 0 as attributes) into whatever profile is
 being taken, on the device trace's clock, and costs an object construction
 each when none is. Each is read by a per-layer metric of the benchmark
 (``benchmarks/program_trace.py``). The flight recorder
@@ -145,11 +149,12 @@ def _round_up(n: int, multiple: int) -> int:
 
 
 class ServingEngine:
-    """Slot-based continuous-batching engine over one LM (``TransformerLM``
-    or ``models/hybrid.HybridLM``). A slot holds one request's whole state
-    for as long as it decodes: K/V rows in the attention layers and, in a
-    model that has them, each recurrent layer's fixed-size state; the schedule
-    is the same for both.
+    """Slot-based continuous-batching engine over one LM (``TransformerLM``,
+    ``models/hybrid.HybridLM`` or ``models/latent_moe.LatentMoELM``). A slot
+    holds one request's whole state for as long as it decodes: K/V rows (or one
+    latent row a position) in the attention layers and, in a model that has
+    them, each recurrent layer's fixed-size state; the schedule is the same
+    for all.
 
     ``on_tokens(request, new_tokens, done)`` is invoked from the scheduling
     thread every time a request's stream advances (admission's first token,
@@ -208,6 +213,9 @@ class ServingEngine:
         # decode blocks by the big-cache rows their steps read (the device's
         # count, ``pool.last_read_rows``)
         self._kv_read_blocks: collections.Counter = collections.Counter()
+        # whatever the model counts on the device (its ``"counters"``
+        # collection; ``pool.last_counters``), by phase and leaf
+        self._model_counters: dict = {"prefill": {}, "decode": {}}
 
     # ------------------------------------------------------------- submit
     def submit(self, prompt, max_new_tokens: int, *,
@@ -394,6 +402,7 @@ class ServingEngine:
                     temperature=sp.temperature, top_k=sp.top_k,
                     top_p=sp.top_p, gen_offset=req.gen_offset)
                 self._tok[slot] = tok0
+                self._count("prefill")
                 self._prefill_tokens["real"] += p
                 self._prefill_tokens["padded"] += bucket - p
                 # the per-slot sampling clock continues the request's OWN
@@ -435,6 +444,7 @@ class ServingEngine:
                 self._tok, self._n_gen, self._seeds, self._temps,
                 self._top_ks, self._top_ps, active)  # [S, T] host array (syncs)
             self._block_timer.tick()
+            self._count("decode")
         self._kv_read_blocks[self.pool.last_read_rows] += 1
         # the device has nothing queued from here to the next dispatch
         self._t_mark = time.perf_counter()
@@ -449,6 +459,32 @@ class ServingEngine:
                 remaining = req.max_new_tokens - len(req.tokens)
                 self._emit(req, toks[slot, :remaining].tolist())
         self._mark("emit")
+
+    def _count(self, phase: str) -> None:
+        """Add what the model counted in the pool's newest call to ``phase``:
+        per leaf its sum entry by entry, the calls or decode steps it covers
+        (``events``: a decode block's leaf has a row a step) and how many of
+        its entries were not 0 in them. A model that counts nothing costs one
+        look at an empty dictionary. The sums ride a child span too
+        (``serve.prefill.counters``, ``serve.decode.counters``), as
+        whole-number attributes, when a profile is being taken."""
+        counted = self.pool.last_counters
+        if not counted:
+            return
+        attrs = {}
+        for name, leaf in counted.items():
+            rows = np.asarray(leaf).reshape(-1, np.shape(leaf)[-1])
+            acc = self._model_counters[phase].setdefault(
+                name, {"sum": np.zeros(rows.shape[1], np.int64), "events": 0, "nonzero": 0})
+            nonzero = int((rows > 0).sum())
+            acc["sum"] += rows.sum(axis=0)
+            acc["events"] += len(rows)
+            acc["nonzero"] += nonzero
+            key = name.replace("/", "_")
+            attrs[key + "_sum"] = int(rows.sum())
+            attrs[key + "_nonzero"] = nonzero
+        with span(f"serve.{phase}.counters", **attrs):
+            pass
 
     def _mark(self, phase: str) -> None:
         """Charge the host time since the last mark to ``phase`` of the open
@@ -539,6 +575,7 @@ class ServingEngine:
         self._sampler_blocks = {
             "blocks": 0, "sampled_blocks": 0, "filtered_blocks": 0}
         self._kv_read_blocks.clear()
+        self._model_counters = {"prefill": {}, "decode": {}}
 
     def slo_summary(self) -> dict:
         """Percentile SLO report (milliseconds) over everything completed so
@@ -587,6 +624,18 @@ class ServingEngine:
                 "by_rows": {rows: self._kv_read_blocks[rows]
                             for rows in self.pool.read_ladder},
             },
-            # what one slot holds: K/V rows, and recurrent state beside them
+            # what one slot holds: K/V or latent rows, and recurrent state
+            # beside them
             "pool": self.pool.slot_bytes(),
+            # what the model counted on the device, by phase (admissions,
+            # decode steps) and counter: the sum entry by entry, the events
+            # counted over, and the mean number of entries that were not 0 in
+            # an event (for a router's choices per expert: its load, and the
+            # distinct experts a decode step touched); empty for a model that
+            # declares no counters
+            "model_counters": {
+                phase: {name: {"sum": acc["sum"].tolist(), "events": acc["events"],
+                               "nonzero_mean": acc["nonzero"] / max(1, acc["events"])}
+                        for name, acc in leaves.items()}
+                for phase, leaves in self._model_counters.items()},
         }

@@ -257,3 +257,53 @@ def test_bounded_cache_read_copies_no_cache_leaf(v5e, chip_dispatch, head_dim, r
     assert text.count(" conditional(") == 1
     # the scan, the merge's four scatter loops, and the read's one a layer
     assert text.count(" while(") == 1 + 4 + layers
+
+
+def test_latent_moe_pool_programs_compile_at_the_published_widths(v5e, chip_dispatch):
+    """``LatentMoELM``'s admission and decode block through the pool's two
+    programs, every width as ``kanana-2-30b-a3b`` publishes it (128 experts of
+    768, 32 heads, latent 512 + rope 64), one dense and one expert layer, a
+    small vocabulary and pool. The admission's routed experts are grouped
+    products by the Pallas grouped-matmul kernel (three a layer); the decode
+    block reads the experts where they lie: no temporary
+    of the order of a layer's routed weights (a gather of each slot's experts
+    would be 8 x 6 x 9.4 MB, a copy of the layer 1.2 GB), and one loop a layer
+    for the bounded read of the latent rows."""
+    import json
+    import os
+
+    from benchmarks.reference import deepseek_v3_mla_moe as ref
+    from distributed_ml_pytorch_tpu.models.generate import _decode_model, init_cache
+    from distributed_ml_pytorch_tpu.models.latent_moe import LatentMoELM
+    from distributed_ml_pytorch_tpu.serving.cache import _admit_jit, _decode_block_jit
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "benchmarks", "configs", "kanana-2-30b-a3b.json")
+    with open(path) as fh:
+        cfg = dict(json.load(fh), num_hidden_layers=2, vocab_size=1024)
+    one = SingleDeviceSharding(v5e[0])
+    slots, rows, bucket = 8, 512, 256
+    lm = LatentMoELM.from_config(cfg, dtype=jnp.bfloat16)
+    dec = _decode_model(lm, rows, decode_block=16)
+    placed = lambda tree, lead=(): jax.tree.map(
+        lambda a: on(one, lead + a.shape, a.dtype), tree)
+    params = placed(jax.eval_shape(lambda k: ref.make_params(k, cfg, jnp.bfloat16),
+                                   jax.random.key(0)))
+    pool = placed(jax.eval_shape(lambda: init_cache(lm, 1, rows, decode_block=16)), (slots,))
+    vec, scalar = (lambda dt: on(one, (slots,), dt)), (lambda dt: on(one, (), dt))
+    admit = _admit_jit.lower(
+        dec, params, pool, scalar(jnp.int32), on(one, (1, bucket), jnp.int32), scalar(jnp.int32),
+        scalar(jnp.uint32), scalar(jnp.float32), scalar(jnp.int32), scalar(jnp.float32),
+        scalar(jnp.int32)).compile()
+    # three grouped products an expert layer, the Pallas kernel and not the
+    # compiler's own rewrite of ragged_dot (tiles of 512 rows)
+    assert_kernels(admit.as_text(), 3)
+    assert "ragged-dot" not in admit.as_text()
+    decode = _decode_block_jit.lower(
+        dec, params, pool, vec(jnp.int32), vec(jnp.int32), vec(jnp.uint32), vec(jnp.float32),
+        vec(jnp.int32), vec(jnp.float32), vec(jnp.bool_)).compile()
+    expert = 2 * 3 * 2048 * 768  # one expert's weights, bytes
+    assert decode.memory_analysis().temp_size_in_bytes < 24 * expert
+    text = decode.as_text()
+    # the scan, the merge's scatter loop a layer, the bounded read's loop a layer
+    assert text.count(" while(") == 1 + 2 + 2

@@ -130,9 +130,11 @@ def test_step_timer_tick_n_drops_warmup_chunks():
     time.sleep(0.05)  # "compile" chunk: includes warmup steps → dropped whole
     t.tick_n(8)
     assert t.summary() is None
+    t0 = time.perf_counter()
     t.start()
     time.sleep(0.008)
     t.tick_n(4)  # steady chunk: all 4 recorded at dt/4 each
+    steady_ms = (time.perf_counter() - t0) * 1e3  # on a loaded host the sleep overruns
     s = t.summary()
     assert s["steps"] == 4
-    assert s["mean_ms"] < 10.0, "compile time leaked into steady-state stats"
+    assert s["mean_ms"] <= steady_ms / 4, "compile time leaked into steady-state stats"
