@@ -33,7 +33,7 @@ from jax.experimental.pallas.ops.tpu.megablox import gmm as _gmm
 
 from distributed_ml_pytorch_tpu.models.generate import COUNTERS
 from distributed_ml_pytorch_tpu.models.hybrid import GatedFFN
-from distributed_ml_pytorch_tpu.models.transformer import MultiHeadAttention
+from distributed_ml_pytorch_tpu.models.transformer import KV_READ, MultiHeadAttention
 
 
 def switch_route(
@@ -282,7 +282,9 @@ def route_topk_sigmoid(x, w_router, bias, k: int, scale: float):
 
 
 #: rows a tile of the TPU's grouped-matmul kernel holds: a group of fewer rows
-#: still costs a whole tile, and an admission's groups are 50-120 rows
+#: still costs a whole tile, and an admission's groups are 50-120 rows; a
+#: decode step's 1-3 rows an expert share tiles, and what a group costs there
+#: is the fetch of its expert (12.5 us at the published sizes, PR 36)
 GROUP_TILE_ROWS = 128
 
 
@@ -309,38 +311,64 @@ def grouped_dot(rows, w, sizes, out_dtype):
     return jax.lax.ragged_dot(rows, w, sizes, preferred_element_type=out_dtype)
 
 
-def grouped_experts(x, idx, weights, w_gate, w_up, w_down):
-    """``sum_j weights[:, j] * E_idx[:, j](x)`` for rows ``x`` ``[n, d]`` as
-    grouped products over the ``n * k`` (row, choice) pairs sorted by expert
-    (:func:`grouped_dot`: each expert's weights multiply its own rows and no
-    others, however uneven the groups; an expert nobody chose has an empty
-    group). No capacity and no drop. Returns float32 ``[n, d]``."""
+@jax.jit
+def grouped_experts(x, idx, weights, live, w_gate, w_up, w_down):
+    """``sum_j weights[:, j] * E_idx[:, j](x)`` for the rows of ``x`` ``[n, d]``
+    that ``live`` ``[n]`` marks, as grouped products over their (row, choice)
+    pairs sorted by expert (:func:`grouped_dot`: each expert's weights multiply
+    its own rows and no others, however uneven the groups; an expert no live
+    row chose has an empty group, and the TPU's kernel never fetches it). No
+    capacity and no drop. A row that is not live leaves the groups: its pairs
+    sort behind every group, count in no group's size, and its sum is exactly
+    0. The pairs are padded to whole tiles of ``GROUP_TILE_ROWS`` with pairs of
+    the same kind, so that a decode step's few rows take the kernel too.
+    Returns float32 ``[n, d]``. Under ``jax.jit`` so that a model's expert
+    layers share one trace: three kernel calls a layer traced anew cost the
+    serving programs seconds of set-up."""
     n, k = idx.shape
     e = w_gate.shape[0]
-    flat = idx.reshape(-1)
+    # a pair of a row that is not live, or of the padding, is in group ``e``: none
+    flat = jnp.where(jnp.repeat(live, k), idx.reshape(-1), e)
+    flat = jnp.pad(flat, (0, -flat.size % GROUP_TILE_ROWS), constant_values=e)
     order = jnp.argsort(flat, stable=True)          # pairs by expert
-    rows = x[order // k]                            # the pair's token row
+    rows = x[jnp.minimum(order // k, n - 1)]        # the pair's token row
     sizes = jnp.sum(jax.nn.one_hot(flat, e, dtype=jnp.int32), axis=0)
     hidden = (nn.silu(grouped_dot(rows, w_gate, sizes, rows.dtype))
               * grouped_dot(rows, w_up, sizes, rows.dtype))
-    out = grouped_dot(hidden, w_down, sizes, jnp.float32) * weights.reshape(-1)[order][:, None]
+    scale = jnp.pad(weights.reshape(-1), (0, flat.size - n * k))[order]
+    # the kernel leaves rows past the last group unwritten: a select, not a product
+    out = jnp.where((flat[order] < e)[:, None],
+                    grouped_dot(hidden, w_down, sizes, jnp.float32) * scale[:, None], 0)
     # back to token order: pair p of the sorted list is at argsort(order)[p]
-    return jnp.sum(out[jnp.argsort(order)].reshape(n, k, -1), axis=1)
+    return jnp.sum(out[jnp.argsort(order)[:n * k]].reshape(n, k, -1), axis=1)
 
 
-def stacked_experts(x, idx, weights, w_gate, w_up, w_down):
-    """The same sum as :func:`grouped_experts` by the stacked products over
-    ALL experts with the weights as a mask (``td,edf->tef``, ``tef,efd->td``).
-    For a decode step: ``SlotKVPool`` vmaps the model over its slots, so a
-    step sees ONE row here and a sort or a gather of that row's experts would
-    be batched into one copy of the weights a slot; these products batch into
-    one product that reads each expert's weights once for the whole pool."""
-    e = w_gate.shape[0]
-    mask = jnp.sum(jax.nn.one_hot(idx, e, dtype=jnp.float32) * weights[..., None], axis=1)
-    up = lambda w: jnp.einsum("td,edf->tef", x, w.astype(x.dtype))
-    hidden = nn.silu(up(w_gate)) * up(w_up) * mask[..., None].astype(x.dtype)
-    return jnp.einsum("tef,efd->td", hidden, w_down.astype(x.dtype),
-                      preferred_element_type=jnp.float32)
+@jax.custom_batching.custom_vmap
+def pooled_experts(x, idx, weights, live, w_gate, w_up, w_down):
+    """:func:`grouped_experts`, with a batching rule of its own: a caller that
+    maps a model over lanes which share the experts' weights (a pool of cache
+    slots decoding a token each) gets ONE set of grouped products over all
+    lanes' live pairs, which reads an expert once for the whole pool and only
+    if a live lane chose it. ``vmap``'s own rule would sort and gather inside
+    each lane and read a copy of every chosen expert a lane. Called plainly it
+    is :func:`grouped_experts` over the call's rows. (``custom_vmap`` has no
+    reverse-mode rule, so the layer takes this only where it decodes.)"""
+    return grouped_experts(x, idx, weights, live, w_gate, w_up, w_down)
+
+
+@pooled_experts.def_vmap
+def _pooled_experts_over_lanes(axis_size, in_batched, x, idx, weights, live, *experts):
+    if any(in_batched[4:]):
+        # a lane has experts of its own: nothing is shared, and a grouped product
+        # has no batching rule for its matrices, so the lanes go one by one
+        args = (x, idx, weights, live, *experts)
+        lane = lambda i: grouped_experts(*[a[i] if batched else a
+                                           for a, batched in zip(args, in_batched)])
+        return jax.lax.map(lane, jnp.arange(axis_size)), True
+    lanes = [a if batched else jnp.broadcast_to(a, (axis_size,) + a.shape)
+             for a, batched in zip((x, idx, weights, live), in_batched)]
+    out = pooled_experts(*[a.reshape((-1,) + a.shape[2:]) for a in lanes], *experts)
+    return out.reshape((axis_size, -1) + out.shape[1:]), True
 
 
 class SigmoidTopKRouter(nn.Module):
@@ -370,10 +398,18 @@ class DroplessExperts(nn.Module):
     (module ``router``), every chosen pair computed, plus ONE gated FFN
     ``n_shared * d_expert`` wide for every token.
 
-    A call of more than one token takes :func:`grouped_experts`; a decode step
-    (``decode`` and one token) takes :func:`stacked_experts`. Both route a
-    padded or idle row like any other: no row competes with another for
-    anything, so such rows change no real row's result.
+    Every call takes :func:`grouped_experts`: a prefill over its rows, a decode
+    step of ``generate()`` over its batch, and a decode step of a pool of cache
+    slots, which maps the model over its lanes, ONE set of grouped products
+    over the whole pool's pairs (:func:`pooled_experts`, the batching rule this
+    layer brings; the layer is never told that it is in a pool). A padded row
+    is routed like any other: no row competes with another for anything, so it
+    changes no real row's result. A lane that is nobody's is not computed: a
+    caller that maps the layer over lanes says which are live through the
+    read-only collection ``kv_read`` (``live``, a boolean a lane, beside the
+    attention layers' ``rows``); such a lane's pairs leave the groups, so an
+    expert only idle lanes chose is not read, and its routed sum is 0. A layer
+    that finds no such variable takes every row as live.
 
     **Counts.** Where the caller makes the ``"counters"`` collection mutable
     the layer writes ``expert_choices`` ``[n_experts]`` there: how often each
@@ -407,10 +443,11 @@ class DroplessExperts(nn.Module):
             idx, weights = SigmoidTopKRouter(
                 e, self.top_k, self.routed_scale, name="router")(rows)
             self._count(idx, b, s)
-        step = self.decode and s == 1
+        live = (jnp.broadcast_to(self.get_variable(KV_READ, "live"), (b * s,))
+                if self.has_variable(KV_READ, "live") else jnp.ones((b * s,), bool))
         with jax.named_scope("moe/experts"):
-            routed = (stacked_experts if step else grouped_experts)(
-                rows, idx, weights, w_gate, w_up, w_down)
+            routed = (pooled_experts if self.decode else grouped_experts)(
+                rows, idx, weights, live, w_gate, w_up, w_down)
         out = routed.astype(self.dtype).reshape(b, s, d)
         if self.n_shared:
             with jax.named_scope("moe/shared"):

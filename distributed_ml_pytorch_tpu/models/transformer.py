@@ -80,7 +80,9 @@ def quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
 
 
 #: the collection through which a caller tells a decoding attention layer
-#: how many rows of its big cache anybody can need (``rows``, a scalar)
+#: how many rows of its big cache anybody can need (``rows``, a scalar), and
+#: a layer it maps over lanes whether the lane is anybody's (``live``, a
+#: boolean a lane: ``models/moe.DroplessExperts`` reads it)
 KV_READ = "kv_read"
 
 

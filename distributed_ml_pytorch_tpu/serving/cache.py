@@ -35,6 +35,15 @@ existing ring-buffered blocked decode module over the slot axis**:
   reads a quarter of the allocation; one slot past three quarters of
   ``cache_size`` makes every slot's read whole again for as long as it is
   active.
+- a step computes nothing for a slot that is nobody's where a module can
+  tell: the same read-only collection carries ``kv_read/live``, one boolean a
+  LANE (the block's ``active``, an argument of the vmapped step), to every
+  module that declared ``prefill_len``, i.e. one that no mask shields from
+  rows that are not real. Under ``vmap`` a module sees one lane; one that
+  must see the pool brings a batching rule (``jax.custom_batching``) that is
+  handed every lane's rows and ``live`` at once. The routed experts of
+  ``models/moe.py`` do: one sort and one set of grouped products for the pool,
+  over the live lanes' choices alone. Nothing here names such a module.
 
 Admission (prefill) runs per request on a FRESH zeroed lane cache and is
 scattered into the pool at the target slot. That freshness is what makes
@@ -161,15 +170,21 @@ def replace_cache_leaves(tree, mapping):
     return out
 
 
-def kv_read_hint(cache, rows):
-    """The ``kv_read`` collection that tells every attention layer of
-    ``cache`` (a dict that holds a big cache leaf: ``cached_k``, or
-    ``cached_latent``) to read ``rows`` rows of its big cache and no more."""
-    hint = {name: kv_read_hint(val, rows)
+def kv_read_hint(cache, rows, live=None):
+    """The ``kv_read`` collection, built from the structure of ``cache``. It
+    tells every attention layer (a dict that holds a big cache leaf:
+    ``cached_k``, or ``cached_latent``) to read ``rows`` rows of its big cache
+    and no more, and every module that no mask shields from rows that are
+    nobody's (a dict that holds ``prefill_len``: it asked for a padded
+    prefill's true length for that reason) whether its lane is ``live``, if
+    the caller says."""
+    hint = {name: kv_read_hint(val, rows, live)
             for name, val in cache.items() if isinstance(val, dict)}
     hint = {name: val for name, val in hint.items() if val}
     if any(name in BIG_CACHE_LEAVES for name in cache):
         hint["rows"] = rows
+    if live is not None and "prefill_len" in cache:
+        hint["live"] = live
     return hint
 
 
@@ -272,7 +287,10 @@ def _decode_block_jit(dec, params, pool, tok, n_gen, seeds,
     ACTIVE slots, a row a step). Slots where ``active`` is
     False decode garbage from a zeroed state (their tokens are discarded by
     the scheduler) and are re-zeroed on exit so their cursors never creep
-    toward the cache edge.
+    toward the cache edge; each lane is told whether it is active
+    (``kv_read/live``, an argument of the vmapped step and so a value a lane),
+    for a module whose cost follows the rows it is given: one with a batching
+    rule of its own sees every lane's at once and leaves the idle ones out.
     Token ``g`` of a request is sampled with ``fold_in(key(seed), g)`` —
     the same per-step key schedule ``generate()`` uses, which is what makes
     engine output bit-match a standalone ``generate`` call on CPU. The
@@ -291,11 +309,12 @@ def _decode_block_jit(dec, params, pool, tok, n_gen, seeds,
     with jax.named_scope("kv_read"):
         longest = jnp.max(live)
         read_rows = jnp.minimum(-(-longest // chunk) * chunk, cache_size)
-    hint = kv_read_hint(pool, longest)
 
-    def lane_apply(lane_cache, tok1, pos1):
-        # ``hint`` is closed over, so under the vmap it stays one scalar and
-        # the loop it bounds one loop over batched operands
+    def lane_apply(lane_cache, tok1, pos1, live1):
+        # ``longest`` is closed over, so under the vmap it stays one scalar and
+        # the loop it bounds one loop over batched operands; ``live1`` is the
+        # lane's own, and a module that wants the pool's has a batching rule
+        hint = kv_read_hint(lane_cache, longest, live1)
         logits, mutated = dec.apply(
             {"params": params, "cache": lane_cache, KV_READ: hint},
             tok1[None, None], pos1[None, None], mutable=["cache", COUNTERS],
@@ -305,7 +324,8 @@ def _decode_block_jit(dec, params, pool, tok, n_gen, seeds,
     def step(carry, _):
         small, tok, g = carry
         cursor = find_cache_leaf(small, "cursor")  # (S,) = absolute position
-        logits, cache, counted = jax.vmap(lane_apply)(join_cache(big, small), tok, cursor)
+        logits, cache, counted = jax.vmap(lane_apply)(
+            join_cache(big, small), tok, cursor, active)
         counted = jax.tree.map(
             lambda c: jnp.sum(jnp.where(active.reshape((-1,) + (1,) * (c.ndim - 1)), c, 0),
                               axis=0), counted)

@@ -1,9 +1,10 @@
 """``LatentMoELM`` (latent attention, dropless routed experts) against its
 plain reference ``benchmarks/reference/deepseek_v3_mla_moe.py`` at a tiny size
 on the CPU, seeded float32 weights, logits and not tokens: the cached absorbed
-path through ``SlotKVPool``, absorbed against expanded, the grouped and the
-stacked routed sums against the reference's loop over experts, padding and
-idle slots, the selection bias, and the counts of the published sizes."""
+path through ``SlotKVPool``, absorbed against expanded, the grouped routed sum
+(a call's own rows, and a pool's live lanes under ``vmap``) against the
+reference's loop over experts, padding and idle slots, the selection bias, and
+the counts of the published sizes."""
 
 import json
 import os
@@ -101,7 +102,7 @@ def test_the_absorbed_step_equals_the_expanded_pass_on_the_same_rows(decode_bloc
     assert mut["cache"]["cached_latent"].shape == (1, 1, 32, 40)
 
 
-# ------------------------------------------ (c) the routed sum, both forms
+# ------------------------------- (c) the routed sum, of a call and of a pool
 def skewed(params):
     """A router under which expert 0 is chosen by nearly every row and expert
     5 by none (the bias moves the choice only)."""
@@ -125,12 +126,88 @@ def test_the_routed_sum_equals_the_references_loop_over_experts(lm_and_params, r
         assert load[5] == 0 and load[0] > rows // 2 and load.sum() == 2 * rows
 
 
-def test_the_stacked_form_is_the_grouped_form(lm_and_params):
+def routed_reference(x, p):
+    """The reference's loop over experts for rows ``x``, without its shared expert."""
+    whole, _ = ref._experts(x, p, CONFIG, False)
+    shared = jax.tree.map(lambda a: a.astype(jnp.float32), p["shared"])
+    return whole - ref._gated_ffn(x, shared["gate"]["kernel"], shared["up"]["kernel"],
+                                  shared["down"]["kernel"], False)
+
+
+LIVE = np.array([True, False, True, True, False, True, False, True])
+
+
+@pytest.mark.parametrize("how", ["plain call", "vmap over lanes", "vmap in scan under jit",
+                                 "vmap, nobody says who is live"])
+def test_the_routed_sum_of_a_pool_is_the_references_on_the_live_rows(lm_and_params, how):
+    """The expert layer without its shared expert, as a prefill calls it (eight
+    rows of one sequence) and as a pool does (eight lanes of one row under
+    ``vmap``, three of them idle, told through ``kv_read/live``): the
+    reference's loop over experts on the live rows, exactly 0 on the others."""
     p = skewed(lm_and_params[1]["layer_2"]["moe"])
-    x = jax.random.normal(jax.random.key(9), (40, 64))
-    idx, w, _ = moe.route_topk_sigmoid(x, p["router"]["kernel"], p["router"]["bias"], 2, 2.448)
-    args = (x, idx, w, p["w_gate"], p["w_up"], p["w_down"])
-    np.testing.assert_allclose(moe.stacked_experts(*args), moe.grouped_experts(*args), atol=1e-5)
+    routed = {k: v for k, v in p.items() if k != "shared"}
+    layer = moe.DroplessExperts(64, 32, 8, 2, 0, CONFIG["routed_scaling_factor"], decode=True)
+    x = jax.random.normal(jax.random.key(9), (8, 64))
+    cache = {"prefill_len": jnp.zeros((), jnp.int32)}
+
+    def lane(row, live):
+        hint = {} if live is None else {moe.KV_READ: {"live": live}}
+        return layer.apply({"params": routed, "cache": cache, **hint}, row[None, None],
+                           mutable=["cache"])[0][0, 0]
+
+    live = LIVE
+    if how == "plain call":
+        live = np.ones(8, bool)
+        got = layer.apply({"params": routed, "cache": cache}, x[None], mutable=["cache"])[0][0]
+    elif how == "vmap over lanes":
+        got = jax.vmap(lane)(x, jnp.asarray(live))
+    elif how == "vmap in scan under jit":
+        steps = jax.jit(lambda x, live: jax.lax.scan(
+            lambda c, _: (c, jax.vmap(lane)(c, live)), x, None, length=2)[1])
+        first, got = steps(x, jnp.asarray(live))
+        np.testing.assert_array_equal(first, got)
+    else:
+        live = np.ones(8, bool)
+        got = jax.vmap(lambda row: lane(row, None))(x)
+    np.testing.assert_allclose(got[live], routed_reference(x, p)[live], atol=1e-5)
+    assert not np.asarray(got[~live]).any()
+
+
+def test_an_expert_only_idle_lanes_chose_has_an_empty_group(monkeypatch):
+    """The group sizes the batching rule hands to ``grouped_dot`` count the
+    live lanes' pairs and no others, in one call for the whole pool: idle
+    lanes steered to expert 0 leave its group empty, so the TPU's kernel never
+    fetches it. With every lane live the same call counts them all."""
+    seen = []
+    dot = moe.grouped_dot
+    note = lambda n: lambda sizes: seen.append((n, np.asarray(sizes)))
+    monkeypatch.setattr(moe, "grouped_dot", lambda rows, w, sizes, dt: (
+        jax.debug.callback(note(rows.shape[0]), sizes), dot(rows, w, sizes, dt))[1])
+    k = jax.random.split(jax.random.key(2), 4)
+    x = jax.random.normal(k[0], (8, 1, 16))
+    w_gate, w_up = (jax.random.normal(kk, (8, 16, 32)) / 4 for kk in k[1:3])
+    w_down = jax.random.normal(k[3], (8, 32, 16)) / 6
+    idx = np.stack([[[1 + i % 7, 1 + (i + 3) % 7]] for i in range(8)])
+    idx[~LIVE] = [[0, 3]]
+    weights = jnp.full((8, 1, 2), 0.5)
+    pooled = lambda live: jax.vmap(moe.pooled_experts, in_axes=(0, 0, 0, 0, None, None, None))(
+        x, jnp.asarray(idx), weights, jnp.asarray(live)[:, None], w_gate, w_up, w_down)
+    got = pooled(LIVE)
+    jax.effects_barrier()
+    assert [rows for rows, _ in seen] == [moe.GROUP_TILE_ROWS] * 3   # one call, whole tiles
+    assert all(sizes.tolist() == np.bincount(idx[LIVE].ravel(), minlength=8).tolist()
+               for _, sizes in seen)
+    assert seen[0][1][0] == 0 and seen[0][1].sum() == 2 * LIVE.sum()
+    assert not np.asarray(got[~LIVE]).any() and np.asarray(got[LIVE]).all()
+    del seen[:]
+    pooled(np.ones(8, bool))
+    jax.effects_barrier()
+    assert seen[0][1][0] == 3 and seen[0][1].sum() == 16
+    # lanes with experts of their own share nothing: the rule maps the plain sum
+    own = jax.vmap(moe.pooled_experts, in_axes=(0, 0, 0, 0, 0, None, None))(
+        x, jnp.asarray(idx), weights, jnp.asarray(LIVE)[:, None],
+        jnp.broadcast_to(w_gate, (8,) + w_gate.shape), w_up, w_down)
+    np.testing.assert_allclose(own, got, atol=1e-6)
 
 
 # ----------------------------------------- (d) padding and idle slots

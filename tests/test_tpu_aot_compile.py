@@ -263,12 +263,17 @@ def test_latent_moe_pool_programs_compile_at_the_published_widths(v5e, chip_disp
     """``LatentMoELM``'s admission and decode block through the pool's two
     programs, every width as ``kanana-2-30b-a3b`` publishes it (128 experts of
     768, 32 heads, latent 512 + rope 64), one dense and one expert layer, a
-    small vocabulary and pool. The admission's routed experts are grouped
-    products by the Pallas grouped-matmul kernel (three a layer); the decode
-    block reads the experts where they lie: no temporary
-    of the order of a layer's routed weights (a gather of each slot's experts
-    would be 8 x 6 x 9.4 MB, a copy of the layer 1.2 GB), and one loop a layer
-    for the bounded read of the latent rows."""
+    small vocabulary and pool. The routed experts of both programs are grouped
+    products by the Pallas grouped-matmul kernel, three an expert layer: the
+    admission's over its bucket's pairs, the decode block's ONE set over the
+    whole pool's 24 x 6 pairs padded to two tiles of 128 (the expert layer's
+    batching rule), which reads the experts the live slots chose where they
+    lie and no others. So the decode block holds no temporary of the order of
+    a layer's routed weights (a gather of each slot's experts would be 24 x 6 x
+    9.4 MB, a copy of the layer 1.2 GB), no ``ragged-dot`` (the compiler's own
+    rewrite, which ``moe_group_roofline.history`` would read as an
+    admission's), no scatter loop beyond the merge's, and one loop a layer for
+    the bounded read of the latent rows."""
     import json
     import os
 
@@ -282,7 +287,7 @@ def test_latent_moe_pool_programs_compile_at_the_published_widths(v5e, chip_disp
     with open(path) as fh:
         cfg = dict(json.load(fh), num_hidden_layers=2, vocab_size=1024)
     one = SingleDeviceSharding(v5e[0])
-    slots, rows, bucket = 8, 512, 256
+    slots, rows, bucket = 24, 512, 256  # 24 x 6 pairs: two row tiles, as the cell's 64 x 6 are three
     lm = LatentMoELM.from_config(cfg, dtype=jnp.bfloat16)
     dec = _decode_model(lm, rows, decode_block=16)
     placed = lambda tree, lead=(): jax.tree.map(
@@ -305,5 +310,10 @@ def test_latent_moe_pool_programs_compile_at_the_published_widths(v5e, chip_disp
     expert = 2 * 3 * 2048 * 768  # one expert's weights, bytes
     assert decode.memory_analysis().temp_size_in_bytes < 24 * expert
     text = decode.as_text()
-    # the scan, the merge's scatter loop a layer, the bounded read's loop a layer
-    assert text.count(" while(") == 1 + 2 + 2
+    assert_kernels(text, 3)
+    assert "ragged-dot" not in text
+    # the scan, the merge's scatter loop a layer, the bounded read's loop a layer,
+    # and in the expert layer the kernel's own group metadata: the bisection of a
+    # 128-entry histogram over the row tiles (megablox ``make_group_metadata``,
+    # once for the three products; every admission has the same one)
+    assert text.count(" while(") == 1 + 2 + 2 + 1
