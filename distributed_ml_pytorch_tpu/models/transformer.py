@@ -22,15 +22,15 @@ capability extension, not parity). The design is shaped by how it trains:
 
 from __future__ import annotations
 
+from functools import cache, partial
 from typing import Callable, Optional
-
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
 from distributed_ml_pytorch_tpu.ops.attention import auto_attention
+from distributed_ml_pytorch_tpu.ops.slot_attention import kernel_runs_here, slot_rows_attention
 
 
 def default_attn_fn(q, k, v):
@@ -116,22 +116,22 @@ def bounded_cache_attention(bound, q, v, s_ring, s_self, scale, ring_base,
                             ring_v, cache_k, cache_v, scale_k, scale_v, *,
                             dtype, turned):
     """A single-token step's three-part attention (big cache, ring, self) with
-    the big cache read only as far as somebody needs it. ``bound`` (a scalar
-    the caller sets, at least every ``ring_base`` it will be asked about;
-    ``SlotKVPool`` gives the longest among its active slots) bounds a loop
-    over ``kv_read_chunk`` rows at a time, so the loop's trip count is data
-    and its body is in the program once. The softmax runs online over the
-    chunks (running maximum, sum and weighted V in float32), started from the
-    ring and self terms (``s_ring``, ``s_self``), which are never empty; per
-    sequence the ``key_pos < ring_base`` mask still hides what lies between
-    its own length and the bound. Rows past the bound weigh ``exp(-inf) = 0``
-    in the whole read, so leaving them out drops no term of any sum; what
-    differs is the order of float32 additions. ``scale_k`` / ``scale_v`` are
-    the int8 cache's per-row scales, or None. ``turned`` says that the device
-    keeps the caches with their rows minor (``_tpu_keeps_rows_minor``): a loop
-    takes its operands in the layout their shape has by default, whatever the
-    buffer's own, so it is handed such caches transposed (a bitcast), or it
-    copies both, whole, every step."""
+    the big cache read only as far as somebody needs it: called plainly, as
+    far as ``bound`` (:func:`_bounded_read`); mapped over a pool's lanes, each
+    lane as far as its own ``ring_base`` (:func:`_pooled_read`)."""
+    return _pooled_read(dtype, turned)(bound, q, v, s_ring, s_self, scale, ring_base,
+                                       ring_v, cache_k, cache_v, scale_k, scale_v)
+
+
+def _bounded_read(bound, q, v, s_ring, s_self, scale, ring_base, ring_v,
+                  cache_k, cache_v, scale_k, scale_v, *, dtype, turned, lengths=None):
+    """The read in a loop over ``kv_read_chunk`` rows at a time, as many as
+    hold ``bound`` (at least every ``ring_base``): a trip count that is data.
+    Given each sequence's own ``lengths``, one kernel over the rows they hold
+    (``ops/slot_attention``). The softmax runs online, in float32, from the
+    ring and self terms; the ``key_pos < ring_base`` mask hides a sequence's
+    rows past its length. ``turned``: the device keeps the caches rows minor
+    (``_tpu_keeps_rows_minor``), so they are handed over transposed, a bitcast."""
     rows = cache_k.shape[2]
     chunk = kv_read_chunk(rows)
     T = ring_v.shape[2]
@@ -145,11 +145,11 @@ def bounded_cache_attention(bound, q, v, s_ring, s_self, scale, ring_base,
     m = jnp.max(s_rest, axis=-1)  # finite: the self term
     p = jnp.exp(s_rest - m[..., None])
     total = jnp.sum(p, axis=-1)
-    acc = (
-        jnp.einsum("bht,bhtd->bhd", p[..., :T].astype(dtype), ring_v,
-                   preferred_element_type=jnp.float32)
-        + p[..., T:] * v[:, :, 0]
-    )
+    acc = (jnp.einsum("bht,bhtd->bhd", p[..., :T].astype(dtype), ring_v,
+                      preferred_element_type=jnp.float32) + p[..., T:] * v[:, :, 0])
+    if lengths is not None:
+        return slot_rows_attention(qf, m, total, acc, lengths, cache_k, cache_v, scale,
+                                   turned=turned)[:, :, None]
     # what the mask adds to a row's score, for the loop to cut chunks of
     unseen = jnp.where(jnp.arange(rows) < ring_base, 0.0, -jnp.inf)
     # the last chunk of an allocation that is no multiple of the chunk starts
@@ -564,3 +564,42 @@ class TransformerLM(nn.Module):
         if not self.head:
             return x
         return nn.Dense(self.vocab_size, use_bias=False, dtype=self.dtype, name="lm_head")(x)
+
+
+@cache
+def _pooled_read(dtype, turned):
+    """:func:`_bounded_read` with a batching rule of its own, built once for
+    each static pair, so that the ``jax.jit`` of ``bounded_cache_attention``
+    keeps one trace for all of a model's layers.
+
+    ``serving/cache.SlotKVPool`` maps the model over its slots, so a layer
+    sees one lane, and a loop over chunks can stop at one bound for the pool
+    alone: its longest active slot's, for every slot, idle ones too. Mapped
+    over lanes, the rule is handed every lane's operands at once, the
+    ``ring_base`` of each among them, and reads each slot's big cache as far
+    as its own ring base (rounded up to the kernel's row block) and no
+    further, by one kernel for the pool: a slot of length 0, as the pool
+    leaves an idle one, reads nothing, and ``bound`` is not read. Where the
+    kernel cannot run (no TPU, outside the tests' interpret mode) or would not
+    fit the caches (int8 ones: it takes no scales), the rule maps the loop
+    over the lanes as ``vmap``'s own rule would. (``custom_vmap`` has no
+    reverse-mode rule; nobody differentiates a decode step.)"""
+    loop = partial(_bounded_read, dtype=dtype, turned=turned)
+    read = jax.custom_batching.custom_vmap(loop)
+
+    @read.def_vmap
+    def over_lanes(lanes, in_batched, *args):
+        scale, ring_base, scale_k = args[5], args[6], args[10]
+        if scale_k is not None or in_batched[5] or not kernel_runs_here():
+            return jax.vmap(loop, in_axes=[0 if b else None for b in in_batched])(*args), True
+        q, v, s_ring, s_self, ring_v, cache_k, cache_v = (
+            a if batched else jnp.broadcast_to(a, (lanes,) + a.shape)
+            for a, batched in zip(args[1:5] + args[7:10], in_batched[1:5] + in_batched[7:10]))
+        b = q.shape[1]  # sequences a lane
+        flat = lambda a: a.reshape((lanes * b,) + a.shape[2:])
+        lengths = jnp.repeat(jnp.broadcast_to(ring_base, (lanes,)), b)
+        out = loop(args[0], *map(flat, (q, v, s_ring, s_self)), scale, None,
+                   *map(flat, (ring_v, cache_k, cache_v)), None, None, lengths=lengths)
+        return out.reshape((lanes, b) + out.shape[1:]), True
+
+    return read

@@ -20,21 +20,20 @@ existing ring-buffered blocked decode module over the slot axis**:
   the big caches at PER-SLOT offsets (``merge_ring_caches`` vmapped with a
   traced ``live``), and ``ring_base`` advances — the same amortization
   that removed the full-cache copies from the decode scan (DESIGN.md §5b);
-- a step reads the K/V rows the pool holds live, not the allocation: slot
-  lengths differ, so no one static length serves them all, but the longest
-  ``ring_base`` among the ACTIVE slots bounds every one of them and does not
-  change inside a block. ``_decode_block_jit`` takes that maximum once a
-  block and hands it to every attention layer as ``kv_read/rows`` (one
-  scalar for the pool, closed over by the vmapped step, so it is not
-  batched), and the layer reads its big cache a chunk at a time, in a loop
-  whose trip count is that bound (``models/transformer.
-  bounded_cache_attention``): a quarter, a half, three quarters or all of
-  ``cache_size``. The loop's body is in the program once, so the program is
-  no larger for it; the ``key_pos < ring_base`` mask hides, per slot, what
-  lies between a slot's own length and the bound. A pool of short requests
-  reads a quarter of the allocation; one slot past three quarters of
-  ``cache_size`` makes every slot's read whole again for as long as it is
-  active.
+- a step reads the K/V rows each slot holds, not the allocation: the
+  attention's read (``models/transformer.bounded_cache_attention``) has a
+  batching rule of its own, so under this pool's ``vmap`` it is handed every
+  lane's ``ring_base`` at once and reads slot ``i``'s big cache as far as
+  ``ring_base[i]``, in blocks of ``slot_block_rows`` rows, by one kernel for
+  the pool (``ops/slot_attention``); an idle slot, whose ``ring_base`` is 0,
+  reads nothing. ``_decode_block_jit`` still takes the longest ACTIVE
+  ``ring_base`` once a block and hands it to every attention layer as
+  ``kv_read/rows`` (one scalar for the pool, closed over by the vmapped
+  step): the latent read, int8 caches and any read off a TPU stop there, in
+  whole quarters of ``cache_size`` and every slot alike, by a loop whose trip
+  count is that bound (``last_read_rows`` counts it). Kernel and loop are
+  each in the program once, with a work list or a trip count that is data:
+  one program serves every mix of lengths.
 - a step computes nothing for a slot that is nobody's where a module can
   tell: the same read-only collection carries ``kv_read/live``, one boolean a
   LANE (the block's ``active``, an argument of the vmapped step), to every
@@ -122,6 +121,7 @@ from distributed_ml_pytorch_tpu.models.generate import (
     split_cache,
 )
 from distributed_ml_pytorch_tpu.models.transformer import KV_READ, kv_read_chunk
+from distributed_ml_pytorch_tpu.ops.slot_attention import kv_block_rows
 from distributed_ml_pytorch_tpu.utils.tracing import span
 
 
@@ -353,9 +353,8 @@ class SlotKVPool:
     blocked decode module. A slot holds one sequence's whole state: up to
     ``cache_size`` K/V rows in every attention layer and, for a model with
     recurrent layers, each such layer's fixed-size state beside them
-    (:meth:`slot_bytes`). A decode step reads, of every slot's K/V rows, as
-    many as the longest ACTIVE slot holds, rounded up to a member of
-    ``read_ladder`` (``last_read_rows``; the module docstring has the rule).
+    (:meth:`slot_bytes`). A decode step reads each slot's own K/V rows
+    (``slot_block_rows``; the module docstring has the rule).
 
     The pool is the compiled data plane; the scheduler
     (``serving/engine.py``) owns which slot belongs to which request. All
@@ -405,6 +404,7 @@ class SlotKVPool:
         #: and where the newest decode block's steps stopped (0: no slot was
         #: active, or no block yet)
         self.last_read_rows = 0
+        self.slot_block_rows = _slot_block_rows(self.cache, self.cache_size, self.kv_quant)
         #: what the model counted in the newest admission or decode block
         #: (``flat_counters``; a decode block's leaves have a row a step)
         self.last_counters: dict = {}
@@ -519,3 +519,14 @@ class SlotKVPool:
         sequence's length."""
         decoded = self.blocks_needed(max_new_tokens) * self.decode_block
         return max(bucket_len, prompt_len + decoded)
+
+
+def _slot_block_rows(cache, cache_size: int, kv_quant: bool):
+    """Rows of its K/V caches a slot reads at a time where a decode step reads
+    each slot's own rows (``ops/slot_attention.kv_block_rows`` of the cache's
+    heads and head size), or None for a pool whose steps do not: latent rows,
+    int8 caches."""
+    leaf = find_cache_leaf(cache, "cached_k")  # (slots, 1, heads, rows, head_dim)
+    if leaf is None or kv_quant:
+        return None
+    return kv_block_rows(cache_size, leaf.shape[2], leaf.shape[4], leaf.dtype.itemsize)

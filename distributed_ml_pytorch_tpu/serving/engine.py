@@ -210,9 +210,9 @@ class ServingEngine:
         # sampler draw (a temperature) or sort (top-k / top-p besides)
         self._sampler_blocks = {
             "blocks": 0, "sampled_blocks": 0, "filtered_blocks": 0}
-        # decode blocks by the big-cache rows their steps read (the device's
-        # count, ``pool.last_read_rows``)
+        # decode blocks by rows read, and the share each slot's own rows covered
         self._kv_read_blocks: collections.Counter = collections.Counter()
+        self._slot_rows_share = 0.0
         # whatever the model counts on the device (its ``"counters"``
         # collection; ``pool.last_counters``), by phase and leaf
         self._model_counters: dict = {"prefill": {}, "decode": {}}
@@ -446,6 +446,7 @@ class ServingEngine:
             self._block_timer.tick()
             self._count("decode")
         self._kv_read_blocks[self.pool.last_read_rows] += 1
+        self._slot_rows_share += self._slot_rows_read(active)
         # the device has nothing queued from here to the next dispatch
         self._t_mark = time.perf_counter()
         self._gap = dict.fromkeys(_GAP_PHASES, 0.0)
@@ -459,6 +460,22 @@ class ServingEngine:
                 remaining = req.max_new_tokens - len(req.tokens)
                 self._emit(req, toks[slot, :remaining].tolist())
         self._mark("emit")
+
+    def _slot_rows_read(self, active: np.ndarray) -> float:
+        """Share of the pool's rows (slots x ``cache_size``) that a decode step
+        of the block just dispatched reads when each slot reads its own rows in
+        whole blocks of ``pool.slot_block_rows``: an active slot's ring base is
+        its prompt and the tokens its earlier blocks fed. 0 for a pool whose
+        steps do not read so (no such block)."""
+        block, size = self.pool.slot_block_rows, self.pool.cache_size
+        if not block:
+            return 0.0
+        rows = 0
+        for slot, req in enumerate(self._slot_req):
+            if req is not None and active[slot]:
+                base = req.prompt.size + int(self._n_gen[slot]) - req.gen_offset - 1
+                rows += min(-(-base // block) * block, size)
+        return rows / (self.pool.slots * size)
 
     def _count(self, phase: str) -> None:
         """Add what the model counted in the pool's newest call to ``phase``:
@@ -575,6 +592,7 @@ class ServingEngine:
         self._sampler_blocks = {
             "blocks": 0, "sampled_blocks": 0, "filtered_blocks": 0}
         self._kv_read_blocks.clear()
+        self._slot_rows_share = 0.0
         self._model_counters = {"prefill": {}, "decode": {}}
 
     def slo_summary(self) -> dict:
@@ -623,6 +641,11 @@ class ServingEngine:
                     if blocks else 0.0),
                 "by_rows": {rows: self._kv_read_blocks[rows]
                             for rows in self.pool.read_ladder},
+                # the mean share of ALL slots' rows their steps read where
+                # each slot reads its own, in whole row blocks: what the pool's
+                # per-slot read covers on a TPU, against the bound's share
+                "slot_rows_share_mean": (
+                    self._slot_rows_share / blocks if blocks else 0.0),
             },
             # what one slot holds: K/V or latent rows, and recurrent state
             # beside them

@@ -368,3 +368,24 @@ def test_the_counts_ride_spans_of_their_own_in_a_profile(lm_and_params, tmp_path
     inside = lambda c, p: p[1] <= c[1] and c[1] + c[2] <= p[1] + p[2]
     assert inside(admitted, by_name["serve.prefill"][0])
     assert all(any(inside(b, d) for d in by_name["serve.decode"]) for b in blocks)
+
+
+def test_a_latent_pools_decode_block_lowers_to_the_text_it_had(lm_and_params):
+    """The latent read (``bounded_latent_attention``) is not the per-slot read
+    of per-head K/V caches: a latent pool's decode block lowers to the text it
+    lowered to before that read existed (sha256 of ``Lowered.as_text()``,
+    commit 2813c68), so nothing of it moves."""
+    import hashlib
+
+    from distributed_ml_pytorch_tpu.serving.cache import _decode_block_jit
+
+    lm, params = lm_and_params
+    pool = SlotKVPool(lm, params, slots=3, cache_size=96, decode_block=BLOCK)
+    shapes = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+    vec = lambda dt: jax.ShapeDtypeStruct((3,), dt)
+    lowered = _decode_block_jit.lower(
+        pool.dec, shapes(pool.params), shapes(pool.cache), vec(jnp.int32), vec(jnp.int32),
+        vec(jnp.uint32), vec(jnp.float32), vec(jnp.int32), vec(jnp.float32), vec(jnp.bool_))
+    assert pool.slot_block_rows is None
+    assert hashlib.sha256(lowered.as_text().encode()).hexdigest() == (
+        "ad330e8ade0015b60b15eb9063c0a3333f43c717f5733ac7a643d948de4a5436")

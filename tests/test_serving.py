@@ -753,9 +753,12 @@ def test_a_request_decoded_across_a_ladder_edge_matches_generate(read_engine):
     req = eng.submit(prompt, 21)
     eng.run_until_idle()
     assert req.tokens == read_engine.oracle(prompt, 21)
+    # an allocation of 96 rows is one block of the per-slot read (no multiple of
+    # 128 divides it), which an int8 pool's steps do not take
     assert eng.slo_summary()["kv_read"] == {
         "blocks": 5, "rows_share_mean": (3 * 48 + 2 * 72) / (5 * 96),
-        "by_rows": {24: 0, 48: 3, 72: 2, 96: 0}}
+        "by_rows": {24: 0, 48: 3, 72: 2, 96: 0},
+        "slot_rows_share_mean": 0.0 if eng.pool.kv_quant else 1.0}
 
 
 @pytest.mark.parametrize("read_engine", READ_KINDS, indirect=True)
@@ -841,7 +844,7 @@ def test_kv_read_counter_follows_the_requests_lengths(lm_and_params):
     ladder = eng.pool.read_ladder
     empty = eng.slo_summary()["kv_read"]
     assert empty == {"blocks": 0, "rows_share_mean": 0.0,
-                     "by_rows": dict.fromkeys(ladder, 0)}
+                     "by_rows": dict.fromkeys(ladder, 0), "slot_rows_share_mean": 0.0}
     want = []
     for p, new in ((5, 10), (70, 9)):  # short, then long, one at a time
         eng.submit(prompts_rng(p).integers(0, VOCAB, size=p), new)
@@ -878,6 +881,35 @@ def test_kv_read_counter_is_the_devices_count_by_the_hosts_rule(lm_and_params):
     read = eng.slo_summary()["kv_read"]
     assert read["blocks"] == len(seen) and set(seen) == set(ladder)
     assert read["by_rows"] == {m: seen.count(m) for m in ladder}
+
+
+def test_slot_rows_counter_follows_each_active_slots_own_length(lm_and_params):
+    """``kv_read.slot_rows_share_mean``: a block's share of ALL slots' rows is,
+    over the slots ACTIVE at its dispatch, each one's ring base rounded up to
+    the pool's row block (``slot_block_rows``), over slots x ``cache_size``;
+    the engine reckons it from its requests' lengths, and it agrees with the
+    lengths the pool reports after every block. Never more than the bound's
+    share; a pool whose steps read no other way counts 0."""
+    eng = make_engine(lm_and_params)
+    assert eng.pool.slot_block_rows == 96  # no multiple of 128 divides 96
+    eng.pool.slot_block_rows = 24  # the arithmetic at blocks a tiny cache has not
+    rng = prompts_rng(51)
+    for p, new in ((70, 12), (6, 25), (50, 9), (30, 5), (3, 14)):
+        eng.submit(rng.integers(0, VOCAB, size=p), new)
+    T, shares = eng.pool.decode_block, []
+    while eng.step():
+        if eng.slo_summary()["kv_read"]["blocks"] == len(shares):
+            continue  # a round without a decode block
+        active = [r is not None for r in eng._slot_req]
+        bases = eng.pool.live_lengths()[active] - T
+        shares.append(sum(min(-(-int(b) // 24) * 24, 96) for b in bases) / (3 * 96))
+    read = eng.slo_summary()["kv_read"]
+    assert read["blocks"] == len(shares) > 5
+    assert read["slot_rows_share_mean"] == pytest.approx(np.mean(shares))
+    assert 0 < read["slot_rows_share_mean"] < read["rows_share_mean"]
+    eng.reset_metrics()
+    assert eng.slo_summary()["kv_read"]["slot_rows_share_mean"] == 0.0
+    assert make_engine(lm_and_params, kv_quant=True).pool.slot_block_rows is None
 
 
 # sha256 of ``Lowered.as_text()`` on the tree before the bounded read (commit
